@@ -50,10 +50,11 @@ debugger:
 
     python -m poisson_tpu geometry SPEC [--M 64 --N 64] [--render|--json]
 
-Both entry points honor ``POISSON_TPU_COMPILE_CACHE=<dir>`` (the JAX
-persistent compilation cache, ``utils.compile_cache``): traced programs
-persist across processes, and cache hits/misses land in the metrics
-snapshot next to ``time.compile_seconds``.
+Every entry point keeps the JAX persistent compilation cache
+(``utils.compile_cache``) in ``$JAX_COMPILATION_CACHE_DIR`` when set,
+else in ``<repo>/.jax_cache``: traced programs persist across processes,
+and cache hits/misses land in the metrics snapshot next to
+``time.compile_seconds``.
 
 Instrumentation (stage4's ``MPI_Wtime`` bracketing + timer table, SURVEY §5):
 - phase wall-clock: setup / compile+first-solve / solve (best of --repeat);
@@ -76,7 +77,6 @@ from typing import Optional
 import numpy as np
 
 from poisson_tpu.config import Problem
-from poisson_tpu.utils.platform import honor_jax_platforms_env
 from poisson_tpu.utils.timing import PhaseTimer, fence, solve_report
 
 
@@ -844,11 +844,10 @@ def _main_solve_batched(argv) -> int:
         raise SystemExit(f"--batch must be >= 1, got {args.batch}")
     if args.repeat < 1:
         raise SystemExit(f"--repeat must be >= 1, got {args.repeat}")
-    honor_jax_platforms_env()
     from poisson_tpu import obs
-    from poisson_tpu.utils.compile_cache import enable_from_env
+    from poisson_tpu.utils import compile_cache
 
-    enable_from_env()
+    compile_cache.enable()
     if args.trace_dir or args.metrics_out:
         obs.configure(trace_dir=args.trace_dir,
                       metrics_path=args.metrics_out)
@@ -1153,11 +1152,10 @@ def _main_serve(argv) -> int:
         raise SystemExit(f"--workers must be >= 1, got {args.workers}")
     if args.recover and not args.journal:
         raise SystemExit("--recover needs --journal PATH to replay")
-    honor_jax_platforms_env()
     from poisson_tpu import obs
-    from poisson_tpu.utils.compile_cache import enable_from_env
+    from poisson_tpu.utils import compile_cache
 
-    enable_from_env()
+    compile_cache.enable()
     if args.metrics_out or args.prom_out or args.trace_dir:
         obs.configure(metrics_path=args.metrics_out,
                       prom_path=args.prom_out,
@@ -1534,7 +1532,6 @@ def build_geometry_parser() -> argparse.ArgumentParser:
 
 def _main_geometry(argv) -> int:
     args = build_geometry_parser().parse_args(argv)
-    honor_jax_platforms_env()
     import numpy as _np
 
     from poisson_tpu.geometry import (build_geometry_fields,
@@ -1597,7 +1594,6 @@ def build_chaos_parser() -> argparse.ArgumentParser:
 
 def _main_chaos(argv) -> int:
     args = build_chaos_parser().parse_args(argv)
-    honor_jax_platforms_env()
     from poisson_tpu.testing import chaos
 
     if args.list:
@@ -1758,14 +1754,13 @@ def _main_session(argv) -> int:
     if args.kill_after is not None and not args.journal:
         raise SystemExit("--kill-after without --journal would lose the "
                          "stream — the drill needs the journal")
-    honor_jax_platforms_env()
     import jax
 
     jax.config.update("jax_enable_x64", True)
     from poisson_tpu import obs
-    from poisson_tpu.utils.compile_cache import enable_from_env
+    from poisson_tpu.utils import compile_cache
 
-    enable_from_env()
+    compile_cache.enable()
     if args.metrics_out or args.trace_dir:
         obs.configure(metrics_path=args.metrics_out,
                       trace_dir=args.trace_dir)
@@ -1897,13 +1892,10 @@ def main(argv=None) -> int:
             raise SystemExit(f"missing grid size {axis} (positional or "
                              f"--{axis})")
         setattr(args, axis, pos if pos is not None else opt)
-    # After parse_args so --help and argv errors stay jax-import-free; see
-    # utils.platform for why the env var needs re-asserting (config beats
-    # env — the round-2 driver post-mortem).
-    honor_jax_platforms_env()
-    from poisson_tpu.utils.compile_cache import enable_from_env
+    # After parse_args so --help and argv errors stay jax-import-free.
+    from poisson_tpu.utils import compile_cache
 
-    enable_from_env()
+    compile_cache.enable()
     problem = _problem(args)
     bitflip_at = None
     if args.fault_bitflip_at:

@@ -121,10 +121,6 @@ def main(argv=None) -> int:
     ap.add_argument("--root", default=None,
                     help="checkout root to lint (default: this one)")
     args = ap.parse_args(argv)
-    if not args.lint_only:
-        from poisson_tpu.utils.platform import honor_jax_platforms_env
-
-        honor_jax_platforms_env()
     report = run_contracts(args.root, ledger=not args.lint_only,
                            update_ledger=args.update_ledger)
     if args.json:

@@ -37,12 +37,6 @@ from poisson_tpu.contracts.manifest import (
     POLICY_COVERAGE_EXEMPT,
 )
 
-# Detail keys regress.py copies into the record envelope outside the
-# det.get() pattern (platform_fallback is read with a default through
-# the same helper, but spelled as a bool coercion).
-_ENVELOPE_KEYS = {"platform_fallback"}
-
-
 def bench_detail_keys(bench_source: str) -> dict:
     """Every literal key of every ``"detail": {...}`` dict in bench.py,
     mapped to the first line it appears on."""
@@ -66,7 +60,7 @@ def cohort_detail_fields(regress_source: str) -> set:
     record (the fields eligible for ``cohort_key``), read off the
     ``det.get("...")`` calls in its body."""
     tree = ast.parse(regress_source)
-    fields: set = set(_ENVELOPE_KEYS)
+    fields: set = set()
     for node in ast.walk(tree):
         if (isinstance(node, ast.FunctionDef)
                 and node.name == "record_from_result"):

@@ -45,6 +45,12 @@ MG_MARKERS = ("dot_general", "dot-general")
 _METADATA_RE = re.compile(r", metadata=\{[^}]*\}")
 _LOC_INLINE_RE = re.compile(r"\s*loc\([^()]*(?:\([^()]*\)[^()]*)*\)")
 _LOC_LINE_RE = re.compile(r"^#loc.*$", re.MULTILINE)
+# Compiled HLO text carries a source-location table after the module
+# header (FileNames / FunctionNames / FileLocations / StackFrames, one
+# block each up to a blank line): debug info, like ``metadata``.
+_DEBUG_TABLE_RE = re.compile(
+    r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n(?:.+\n)*",
+    re.MULTILINE)
 # A host callback's backend_config is the host-side callable's ADDRESS
 # (``xla_python_cpu_callback`` carries the pointer as a decimal string)
 # — process-lifetime identity, not program structure. Left in place it
@@ -60,11 +66,13 @@ _CALLBACK_PTR_RE = re.compile(r'backend_config = "(\d+)"')
 
 
 def strip_hlo_metadata(text: str) -> str:
-    """Canonicalize program text: drop ``metadata={...}`` annotations
-    (compiled HLO), inline ``loc(...)`` attributes and ``#loc`` lines
-    (StableHLO), and normalize host-callback pointer identities. The
-    historical test-pin strip, now in one place."""
+    """Canonicalize program text: drop ``metadata={...}`` annotations and
+    the source-location tables (compiled HLO), inline ``loc(...)``
+    attributes and ``#loc`` lines (StableHLO), and normalize host-callback
+    pointer identities. The historical test-pin strip, now in one
+    place."""
     text = _METADATA_RE.sub("", text)
+    text = _DEBUG_TABLE_RE.sub("", text)
     text = _LOC_INLINE_RE.sub("", text)
     text = _LOC_LINE_RE.sub("", text)
     ptrs = set(_CALLBACK_PTR_RE.findall(text))

@@ -102,9 +102,6 @@ def main(argv=None) -> int:
         prog="python -m poisson_tpu.mg.selfcheck",
         description=__doc__.splitlines()[0],
     ).parse_args(argv)
-    from poisson_tpu.utils.platform import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
     return run_selfcheck()
 
 

@@ -9,14 +9,15 @@ oracle for the TPU paths and the framework's shared-memory CPU backend
 (thread count = the reference's ``omp_set_num_threads`` loop,
 ``stage1-openmp/Withopenmp1.cpp:205-229``).
 
-Build is hermetic and cached: the ``.so`` lives next to the source and is
-rebuilt only when the source is newer. ``make -C poisson_tpu/native`` does
-the same build explicitly.
+Build is hermetic and cached: the ``.so`` lives next to the source under a
+name keyed on the hash of the source and the compile command, so a copied
+tree carrying a library built elsewhere from other source never loads it.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -28,7 +29,6 @@ from poisson_tpu.config import Problem
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "poisson_oracle.cpp")
-_LIB = os.path.join(_DIR, "_poisson_oracle.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -43,27 +43,33 @@ class NativeResult(NamedTuple):
     residual_dot: float
 
 
+def _build_command() -> list[str]:
+    # CXX/CXXFLAGS are overridable; the flags the shared library cannot
+    # link or load without are not.
+    cxx = os.environ.get("CXX", "g++")
+    cxxflags = os.environ.get("CXXFLAGS", "-O2").split()
+    return [cxx, *cxxflags, "-std=c++17", "-fPIC", "-fopenmp", "-shared",
+            _SRC]
+
+
+def library_path() -> str:
+    """Where the library built from this source and command lives."""
+    key = hashlib.sha256(open(_SRC, "rb").read())
+    key.update("\0".join(_build_command()).encode())
+    return os.path.join(_DIR, f"_poisson_oracle-{key.hexdigest()[:16]}.so")
+
+
 def build(force: bool = False) -> str:
-    """Compile the oracle library if missing or stale; returns its path."""
+    """Compile the oracle library unless this source's build exists;
+    returns its path."""
+    lib = library_path()
     with _lock:
-        stale = (
-            force
-            or not os.path.exists(_LIB)
-            or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)
-        )
-        if stale:
+        if force or not os.path.exists(lib):
             # Unique temp name: concurrent processes (pytest-xdist, parallel
             # CI) may compile simultaneously; each writes its own file and
             # the os.replace is atomic.
-            tmp = f"{_LIB}.{os.getpid()}.tmp"
-            # CXX/CXXFLAGS are overridable; the flags the shared library
-            # cannot link or load without are not.
-            cxx = os.environ.get("CXX", "g++")
-            cxxflags = os.environ.get("CXXFLAGS", "-O2").split()
-            cmd = [
-                cxx, *cxxflags, "-std=c++17", "-fPIC", "-fopenmp",
-                "-shared", _SRC, "-o", tmp,
-            ]
+            tmp = f"{lib}.{os.getpid()}.tmp"
+            cmd = [*_build_command(), "-o", tmp]
             try:
                 proc = subprocess.run(cmd, capture_output=True, text=True)
                 if proc.returncode != 0:
@@ -71,11 +77,11 @@ def build(force: bool = False) -> str:
                         f"native oracle build failed "
                         f"({' '.join(cmd)}):\n{proc.stderr}"
                     )
-                os.replace(tmp, _LIB)
+                os.replace(tmp, lib)
             finally:
                 if os.path.exists(tmp):
                     os.remove(tmp)
-    return _LIB
+    return lib
 
 
 def _load() -> ctypes.CDLL:

@@ -14,7 +14,7 @@ compiled-iteration cost introspection against the analytic stencil
 model (``obs.costs``, agreement within ±25%), a Prometheus exposition
 round trip (``obs.export`` render → parse, live ``/metrics`` endpoint),
 and the regression sentinel (``benchmarks/regress.py``) on a synthetic
-history that must classify a platform fallback as such and flag a 2×
+history that must keep a CPU record out of the TPU cohort and flag a 2×
 slowdown. Steps 11–14 run LAST (each resets the metrics registry): the
 solve-service → chaos → exposition smoke, the continuous-batching
 smoke — an open-loop refill drive, the refill-poison-splice race, and
@@ -230,8 +230,8 @@ def run_selfcheck(out_dir: str) -> int:
     finally:
         export.stop_http_server(server)
 
-    # 10. Regression sentinel end to end on a synthetic history: a
-    # platform fallback must classify as such (not page), a genuine 2x
+    # 10. Regression sentinel end to end on a synthetic history: a CPU
+    # record must stay out of the TPU cohort (not page), a genuine 2x
     # slowdown must page.
     import sys as _sys
 
@@ -244,24 +244,23 @@ def run_selfcheck(out_dir: str) -> int:
     except ImportError as e:
         return _fail(f"benchmarks.regress not importable: {e}")
 
-    def _rec(value, platform, fallback=False):
+    def _rec(value, platform):
         return regress.record_from_result(
             {"metric": "mlups", "value": value,
              "detail": {"grid": [40, 40], "dtype": "float32",
                         "backend": "xla", "devices": 1,
-                        "platform": platform,
-                        "platform_fallback": fallback}},
+                        "platform": platform}},
             source=f"selfcheck:{platform}:{value}",
         )
     history = [_rec(24000.0, "tpu"), _rec(23800.0, "tpu"),
-               _rec(23900.0, "tpu"), _rec(160.0, "cpu", fallback=True)]
+               _rec(23900.0, "tpu"), _rec(160.0, "cpu")]
     verdict = regress.evaluate(history)
     if verdict["verdict"] != "ok":
-        return _fail(f"sentinel paged on a platform fallback: {verdict}")
-    fallback_cls = [v["classification"] for v in verdict["records"]
-                    if v["platform"] == "cpu"]
-    if fallback_cls != ["platform_fallback"]:
-        return _fail(f"fallback misclassified: {fallback_cls}")
+        return _fail(f"sentinel paged on a CPU record: {verdict}")
+    cpu_cls = [v["classification"] for v in verdict["records"]
+               if v["platform"] == "cpu"]
+    if cpu_cls != ["no_baseline"]:
+        return _fail(f"CPU record judged against the TPU cohort: {cpu_cls}")
     slowed = regress.evaluate(history + [_rec(11900.0, "tpu")])
     if slowed["verdict"] != "regression":
         return _fail(f"sentinel missed a 2x slowdown: {slowed}")
@@ -1080,9 +1079,6 @@ def main(argv=None) -> int:
                     help="write (and keep) the artifacts here instead of "
                          "a removed temp dir")
     args = ap.parse_args(argv)
-    from poisson_tpu.utils.platform import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
     if args.dir:
         os.makedirs(args.dir, exist_ok=True)
         return run_selfcheck(args.dir)

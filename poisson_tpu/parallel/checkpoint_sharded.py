@@ -62,7 +62,7 @@ from poisson_tpu.solvers.pcg import (
     resolve_dtype,
     resolve_scaled,
 )
-from poisson_tpu.utils.compat import shard_map
+from jax import shard_map
 
 _STACKED = P((X_AXIS, Y_AXIS))   # (P, m̂+2, n̂+2) field blocks, mesh order
 _BLOCKED = P(X_AXIS, Y_AXIS)     # (Px·m̂, Py·n̂) padded-global state arrays

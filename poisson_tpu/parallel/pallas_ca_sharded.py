@@ -73,7 +73,7 @@ from poisson_tpu.ops.pallas_cg import (
 from poisson_tpu.parallel.halo import _shift_down, _shift_up
 from poisson_tpu.parallel.mesh import X_AXIS, Y_AXIS
 from poisson_tpu.solvers.pcg import PCGResult
-from poisson_tpu.utils.compat import shard_map
+from jax import shard_map
 
 _AXES = (X_AXIS, Y_AXIS)
 _RING = 2          # halo ring width (the s=2 stencil depth)

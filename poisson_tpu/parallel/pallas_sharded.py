@@ -65,7 +65,7 @@ from poisson_tpu.ops.pallas_cg import (
 )
 from poisson_tpu.parallel.mesh import X_AXIS, Y_AXIS
 from poisson_tpu.solvers.pcg import PCGResult, _DENOM_TOL
-from poisson_tpu.utils.compat import shard_map
+from jax import shard_map
 
 _AXES = (X_AXIS, Y_AXIS)
 

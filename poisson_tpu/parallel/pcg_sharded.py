@@ -56,7 +56,7 @@ from poisson_tpu.solvers.pcg import (
     resolve_dtype,
     resolve_scaled,
 )
-from poisson_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 def _owned_mask(problem: Problem, m_blk: int, n_blk: int, dtype):

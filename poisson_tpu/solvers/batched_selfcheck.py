@@ -71,9 +71,6 @@ def main(argv=None) -> int:
         description=__doc__.splitlines()[0],
     )
     ap.parse_args(argv)
-    from poisson_tpu.utils.platform import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
     return run_selfcheck()
 
 

@@ -1,13 +1,18 @@
-"""JAX persistent compilation cache, env-driven, with hit/miss counters.
+"""JAX persistent compilation cache, placed from outside, with hit/miss
+counters.
 
 Compile time is the dominant fixed cost of every cold start in this stack
 (the flagship solve compiles in seconds; the solve itself runs in under
 one) — and the batched driver multiplies the stakes: one bucket executable
 serves hundreds of solves, so persisting it across processes turns every
-warm start into pure execute time. ``POISSON_TPU_COMPILE_CACHE=<dir>``
-points JAX's persistent compilation cache at ``<dir>``; both entry points
-(``poisson_tpu.cli`` and ``bench.py``) call :func:`enable_from_env` before
-their first trace.
+warm start into pure execute time.
+
+Where the cache lives is the deployment's decision: when
+``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this module
+sets no directory; otherwise the cache is the fixed ``<repo>/.jax_cache``
+(the path is part of the cache's key, so it must not move between runs).
+Every entry point (``poisson_tpu.cli``, ``bench.py``, ``chip_smoke.py``)
+calls :func:`enable` before its first trace.
 
 Cache traffic is surfaced through the unified telemetry counters
 (``obs.metrics``): JAX publishes ``/jax/compilation_cache/cache_hits`` /
@@ -21,8 +26,10 @@ pay for its compiles or reuse them?".
 from __future__ import annotations
 
 import os
+import pathlib
 
-ENV_VAR = "POISSON_TPU_COMPILE_CACHE"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = str(pathlib.Path(__file__).resolve().parents[2] / ".jax_cache")
 
 _LISTENER_INSTALLED = False
 
@@ -42,50 +49,30 @@ def _listener(event: str, **kwargs) -> None:
         metrics.inc(name)
 
 
-def install_counters() -> bool:
-    """Register the monitoring listener (idempotent). Separate from
-    :func:`enable_from_env` so tests can exercise the counter wiring
-    without touching the process-wide cache config. Returns False when
-    this JAX build has no monitoring bus."""
-    global _LISTENER_INSTALLED
-    if _LISTENER_INSTALLED:
-        return True
-    try:
-        from jax import monitoring
-    except ImportError:
-        return False
-    monitoring.register_event_listener(_listener)
-    _LISTENER_INSTALLED = True
-    return True
+def enable() -> str:
+    """Turn the persistent compilation cache on; returns its directory.
 
-
-def enable_from_env() -> bool:
-    """Enable the persistent compilation cache when ``ENV_VAR`` is set.
-
-    Points ``jax_compilation_cache_dir`` at the directory (created if
-    missing) and zeroes the persistence thresholds so even the small/fast
-    programs this stack compiles are persisted (the defaults skip entries
-    below a minimum size and compile time). Installs the hit/miss
-    counters whenever the env var is set, even if the config update then
-    fails (the counters are how that failure gets noticed). Returns True
-    iff the cache was enabled; unset env or a failing config update (an
-    exotic JAX build) degrades to False, never to an exception — a cache
-    problem must not take the solve down.
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX already points the cache there
+    and no directory is set here. Unset: the cache is :data:`DEFAULT_DIR`.
+    Either way the persistence thresholds drop to zero so even the
+    small/fast programs this stack compiles are persisted (the defaults
+    skip entries below a minimum size and compile time), and the hit/miss
+    counters are installed.
     """
+    global _LISTENER_INSTALLED
+    import jax
+    from jax import monitoring
+
+    if not _LISTENER_INSTALLED:
+        monitoring.register_event_listener(_listener)
+        _LISTENER_INSTALLED = True
     path = os.environ.get(ENV_VAR)
     if not path:
-        return False
-    import jax
-
-    install_counters()
-    try:
-        os.makedirs(path, exist_ok=True)
+        path = DEFAULT_DIR
         jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:
-        return False
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     from poisson_tpu.obs import metrics
 
     metrics.gauge("compile_cache.dir", path)
-    return True
+    return path

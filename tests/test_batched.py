@@ -303,48 +303,86 @@ def test_cli_solve_batched_table(capsys):
     assert "batch=2" in out and "solves/s" in out
 
 
-def test_compile_cache_counters_wiring(tmp_path, monkeypatch):
-    """POISSON_TPU_COMPILE_CACHE enables the persistent cache and the
+_CACHE_CONFIG = ("jax_compilation_cache_dir",
+                 "jax_persistent_cache_min_entry_size_bytes",
+                 "jax_persistent_cache_min_compile_time_secs")
+
+
+@pytest.fixture()
+def restore_cache_config():
+    """The cache settings are process-global jax config: put them back so
+    later tests never persist into a vanished or unexpected directory."""
+    import jax
+
+    saved = {name: getattr(jax.config, name) for name in _CACHE_CONFIG}
+    yield
+    for name, value in saved.items():
+        jax.config.update(name, value)
+
+
+def test_compile_cache_counters_wiring(tmp_path, monkeypatch,
+                                       restore_cache_config):
+    """With JAX_COMPILATION_CACHE_DIR set, enable() sets no cache
+    directory of its own (JAX reads the variable itself) and the
     monitoring listener folds JAX's cache events into obs counters."""
     import jax
 
     from poisson_tpu.utils import compile_cache
 
-    saved = (jax.config.jax_compilation_cache_dir,
-             jax.config.jax_persistent_cache_min_entry_size_bytes,
-             jax.config.jax_persistent_cache_min_compile_time_secs)
     monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path / "cc"))
-    try:
-        assert compile_cache.enable_from_env() is True
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "cc")
-        metrics.reset()
-        # The listener is wired to the jax.monitoring bus: a cache event
-        # on the bus must land in the counters (platform-independent,
-        # unlike provoking a real persistent-cache round trip on every
-        # backend).
-        from jax import monitoring
+    jax.config.update("jax_compilation_cache_dir", None)
+    assert compile_cache.enable() == str(tmp_path / "cc")
+    assert jax.config.jax_compilation_cache_dir is None
+    metrics.reset()
+    # The listener is wired to the jax.monitoring bus: a cache event on
+    # the bus must land in the counters (platform-independent, unlike
+    # provoking a real persistent-cache round trip on every backend).
+    from jax import monitoring
 
-        monitoring.record_event("/jax/compilation_cache/cache_hits")
-        monitoring.record_event("/jax/compilation_cache/cache_misses")
-        monitoring.record_event("/jax/unrelated/event")
-        assert metrics.get("compile_cache.hits") == 1
-        assert metrics.get("compile_cache.misses") == 1
-    finally:
-        # The cache dir is process-global jax config and tmp_path is
-        # about to vanish — restore so later tests never persist into a
-        # deleted directory.
-        jax.config.update("jax_compilation_cache_dir", saved[0])
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          saved[1])
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          saved[2])
+    monitoring.record_event("/jax/compilation_cache/cache_hits")
+    monitoring.record_event("/jax/compilation_cache/cache_misses")
+    monitoring.record_event("/jax/unrelated/event")
+    assert metrics.get("compile_cache.hits") == 1
+    assert metrics.get("compile_cache.misses") == 1
 
 
-def test_compile_cache_disabled_without_env(monkeypatch):
+def test_compile_cache_defaults_to_repo_dir(monkeypatch,
+                                            restore_cache_config):
+    import pathlib
+
+    import jax
+
     from poisson_tpu.utils import compile_cache
 
     monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
-    assert compile_cache.enable_from_env() is False
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    assert compile_cache.enable() == str(repo / ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == str(repo / ".jax_cache")
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+
+def test_compile_cache_env_dir_used_by_a_fresh_process(tmp_path):
+    """End to end: a process started with JAX_COMPILATION_CACHE_DIR keeps
+    what it compiles there, and enable() leaves that directory alone."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    cache = tmp_path / "cc"
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(cache),
+               JAX_PLATFORMS="cpu")
+    code = ("import jax, jax.numpy as jnp\n"
+            "from poisson_tpu.utils import compile_cache\n"
+            "print(compile_cache.enable())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n"
+            "jax.jit(lambda x: x * 2 + 1)(jnp.ones(8)).block_until_ready()\n")
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          cwd=pathlib.Path(__file__).resolve().parents[1],
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.split() == [str(cache), str(cache)]
+    assert any(cache.iterdir())
 
 
 def test_bench_batched_record_shape():

@@ -1,0 +1,127 @@
+"""The Pallas kernels of the main path compiled for a described TPU v5e.
+
+No chip is attached: the TPU compiler is installed and compiles for a
+``v5e:2x2`` topology it is only told about. Each test lowers one solve at
+a real size with shapes (not arrays) and asserts the compiled program
+holds a Mosaic kernel (``tpu_custom_call``) — i.e. the kernel lowered for
+the chip instead of the interpreter. Nothing runs, so nothing here says
+anything about results or times.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and under pytest-xdist
+every worker imports this file. All these compiles stay in this one file
+for the same reason.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from poisson_tpu.config import Problem
+from poisson_tpu.ops import pallas_ca, pallas_cg, pallas_resident
+from poisson_tpu.parallel import pallas_ca_sharded, pallas_sharded
+from poisson_tpu.parallel.mesh import X_AXIS, Y_AXIS
+
+KERNEL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topology = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep the cache out of it.
+    # And compile as the chip runs, in 32-bit mode: under conftest's x64
+    # the kernels' index maps return i64, which Mosaic cannot legalize.
+    saved = {name: getattr(jax.config, name) for name in
+             ("jax_enable_compilation_cache", "jax_enable_x64")}
+    for name in saved:
+        jax.config.update(name, False)
+    compilation_cache.reset_cache()
+    yield topology
+    for name, value in saved.items():
+        jax.config.update(name, value)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh(topo):
+    return Mesh(np.asarray(topo.devices).reshape(2, 2), (X_AXIS, Y_AXIS))
+
+
+def _canvases(cv, sharding, n=5):
+    shape = jax.ShapeDtypeStruct((cv.rows, cv.cols), jnp.float32,
+                                 sharding=sharding)
+    return [shape] * n
+
+
+def _assert_kernel(lowered):
+    assert KERNEL in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("M,N,serial", [
+    (800, 1200, False), (800, 1200, True), (2400, 3200, False),
+])
+def test_fused(one_chip, M, N, serial):
+    problem = Problem(M=M, N=N)
+    cv = pallas_cg.canvas_spec(problem)
+    _assert_kernel(pallas_cg._fused_solve.lower(
+        problem, cv, False, False, serial, *_canvases(cv, one_chip)))
+
+
+@pytest.mark.parametrize("serial", [False, True])
+def test_ca(one_chip, serial):
+    problem = Problem(M=800, N=1200)
+    cv = pallas_cg.canvas_spec(problem, pallas_ca.pick_bm_ca(problem), 0)
+    _assert_kernel(pallas_ca._ca_solve.lower(
+        problem, cv, False, False, serial, *_canvases(cv, one_chip)))
+
+
+def test_resident(one_chip):
+    problem = Problem(M=400, N=600)
+    cv = pallas_resident.resident_canvas(problem)
+    _assert_kernel(pallas_resident._resident_solve.lower(
+        problem, cv, False, *_canvases(cv, one_chip)))
+
+
+def _stacked_args(spec, mesh):
+    cv, shards = spec.cv, mesh.devices.size
+    stacked = NamedSharding(mesh, P((X_AXIS, Y_AXIS)))
+    canvas = jax.ShapeDtypeStruct((shards, cv.rows, cv.cols), jnp.float32,
+                                  sharding=stacked)
+    sc_int = jax.ShapeDtypeStruct((shards, spec.m_blk, spec.n_blk),
+                                  jnp.float32, sharding=stacked)
+    colmask = jax.ShapeDtypeStruct((1, cv.cols), jnp.float32,
+                                   sharding=NamedSharding(mesh, P()))
+    return [canvas] * 5 + [sc_int, colmask]
+
+
+def test_fused_sharded(mesh):
+    problem = Problem(M=2400, N=3200)
+    spec = pallas_sharded.shard_spec(problem, 2, 2)
+    _assert_kernel(pallas_sharded._solve.lower(
+        problem, mesh, spec, False, *_stacked_args(spec, mesh)))
+
+
+def test_ca_sharded(mesh):
+    problem = Problem(M=2400, N=3200)
+    spec = pallas_ca_sharded.ca_shard_spec(problem, 2, 2)
+    _assert_kernel(pallas_ca_sharded._ca_solve_sharded.lower(
+        problem, mesh, spec, False, *_stacked_args(spec, mesh)))

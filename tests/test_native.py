@@ -19,6 +19,22 @@ def test_build_produces_library():
     assert path.endswith(".so")
 
 
+def test_library_is_keyed_on_source_and_command(tmp_path, monkeypatch):
+    """A library built from other source (say, one copied in with the
+    tree) or with other flags is never the one loaded."""
+    import poisson_tpu.native as native
+
+    base = native.library_path()
+    assert build() == base
+    src = tmp_path / "poisson_oracle.cpp"
+    src.write_text(open(native._SRC).read() + "\n// edited\n")
+    monkeypatch.setattr(native, "_SRC", str(src))
+    assert native.library_path() != base
+    monkeypatch.undo()
+    monkeypatch.setenv("CXXFLAGS", "-O1")
+    assert native.library_path() != base
+
+
 @pytest.mark.parametrize(
     "M,N,weighted,expected",
     [

@@ -278,8 +278,6 @@ def test_serial_kahan_reduce_layout_matches_partials():
 
     code = r"""
 import json
-from poisson_tpu.utils.platform import honor_jax_platforms_env
-honor_jax_platforms_env()   # config beats env: re-assert JAX_PLATFORMS=cpu
 from poisson_tpu.config import Problem
 from poisson_tpu.ops.pallas_cg import pallas_cg_solve, SERIAL_REDUCE
 from poisson_tpu.analysis import l2_error_host
