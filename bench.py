@@ -119,7 +119,7 @@ def _batched_bench(problem, batch: int, devices, platform: str) -> int:
     B = batch
     ones = [1.0] * B
 
-    with obs.span("bench.batched_warmup", fence=False, batch=B):
+    with obs.span("bench.batched_warmup", batch=B):
         t0 = time.perf_counter()
         bat = solve_batched(problem, rhs_gates=ones, dtype=dtype)
         fence(bat)
@@ -158,7 +158,7 @@ def _batched_bench(problem, batch: int, devices, platform: str) -> int:
     # the reps, THEN difference — pairing individual noisy runs can make
     # a single difference ≤ 0 (one scheduler stall in a chain(1) run) and
     # min() would pick it, printing a negative or infinite throughput.
-    with obs.span("bench.batched_timed", fence=False, batch=B):
+    with obs.span("bench.batched_timed", batch=B):
         tb = (min(batched_chain(2) for _ in range(2))
               - min(batched_chain(1) for _ in range(2)))
         ts = (min(seq_chain(2) for _ in range(2))
@@ -374,7 +374,7 @@ def _serve_geometry_mix_bench(problem, requests: int, mix: int, rate,
     families = _geometry_families(mix)
     schedule = _poisson_schedule(requests, rate)
 
-    with obs.span("bench.serve_warmup", fence=False, requests=requests,
+    with obs.span("bench.serve_warmup", requests=requests,
                   geometry_mix=mix):
         t0 = time.time()
         warmed = _warm_serve_buckets(problem, "float32", max_batch,
@@ -391,7 +391,7 @@ def _serve_geometry_mix_bench(problem, requests: int, mix: int, rate,
     obs.inc("time.compile_seconds", warm_seconds)
 
     svc = SolveService(policy, seed=0)
-    with obs.span("bench.serve_geometry_mix", fence=False,
+    with obs.span("bench.serve_geometry_mix",
                   requests=requests, geometry_mix=mix):
         stats, makespan = _drive_open_loop(svc, schedule, problem,
                                            geometries=families)
@@ -485,7 +485,7 @@ def _krylov_block_bench(problem, block_b: int, devices, platform: str) -> int:
         return solve_batched(problem, rhs_stack=fs, dtype=dtype,
                              mode=mode)
 
-    with obs.span("bench.krylov_block_warmup", fence=False,
+    with obs.span("bench.krylov_block_warmup",
                   batch=block_b):
         t0 = time.perf_counter()
         ri = run("independent")
@@ -500,7 +500,7 @@ def _krylov_block_bench(problem, block_b: int, devices, platform: str) -> int:
         fence(run(mode).iterations)
         return time.perf_counter() - t0
 
-    with obs.span("bench.krylov_block_timed", fence=False):
+    with obs.span("bench.krylov_block_timed"):
         ti = min(timed("independent") for _ in range(3))
         tb = min(timed("block") for _ in range(3))
 
@@ -630,7 +630,7 @@ def _session_bench(problem, steps: int, devices, platform: str) -> int:
     # warm step at a spec far off the measured schedule (the moving
     # ellipse changes canvases, never shapes, so one compile serves
     # every step).
-    with obs.span("bench.session_warmup", fence=False, steps=steps):
+    with obs.span("bench.session_warmup", steps=steps):
         t0 = time.perf_counter()
         r0 = pcg_solve(problem, geometry=Ellipse(cx=-0.3))
         fence(r0.iterations)
@@ -820,7 +820,7 @@ def _serve_repeat_fp_bench(problem, requests: int, families: int, rate,
     schedule = _poisson_schedule(requests, rate)
     reset_krylov_cache()
 
-    with obs.span("bench.serve_warmup", fence=False, requests=requests,
+    with obs.span("bench.serve_warmup", requests=requests,
                   repeat_fingerprint=families):
         t0 = time.time()
         # Pre-build every family's canvases AND compile the harvest/
@@ -858,7 +858,7 @@ def _serve_repeat_fp_bench(problem, requests: int, families: int, rate,
     svc = SolveService(policy, seed=0)
     t0 = time.perf_counter()
     i = 0
-    with obs.span("bench.serve_repeat_fingerprint", fence=False,
+    with obs.span("bench.serve_repeat_fingerprint",
                   requests=requests, repeat_fingerprint=families):
         while True:
             now = time.perf_counter() - t0
@@ -1131,17 +1131,17 @@ def _serve_openloop_bench(problem, requests: int, rate: float, devices,
         stats, makespan = _drive_open_loop(svc, schedule, problem)
         return stats, makespan, svc
 
-    with obs.span("bench.serve_warmup", fence=False, requests=requests):
+    with obs.span("bench.serve_warmup", requests=requests):
         t0 = time.time()
         warmed = _warm_serve_buckets(problem, "float32", max_batch,
                                      requests, refill_chunk=refill_chunk)
         warm_seconds = time.time() - t0
     obs.inc("time.compile_seconds", warm_seconds)
 
-    with obs.span("bench.serve_openloop", fence=False, mode="drain",
+    with obs.span("bench.serve_openloop", mode="drain",
                   requests=requests):
         drain_stats, drain_span, _ = run(SCHED_DRAIN)
-    with obs.span("bench.serve_openloop", fence=False, mode="continuous",
+    with obs.span("bench.serve_openloop", mode="continuous",
                   requests=requests):
         cont_stats, cont_span, cont_svc = run(SCHED_CONTINUOUS)
 
@@ -1276,7 +1276,7 @@ def _serve_tenants_bench(problem, requests: int, rate, spec, devices,
     tenants = random.Random(1).choices(names, weights=weights,
                                        k=requests)
 
-    with obs.span("bench.serve_warmup", fence=False, requests=requests):
+    with obs.span("bench.serve_warmup", requests=requests):
         t0 = time.time()
         warmed = _warm_serve_buckets(problem, "float32", max_batch,
                                      requests, refill_chunk=refill_chunk)
@@ -1284,7 +1284,7 @@ def _serve_tenants_bench(problem, requests: int, rate, spec, devices,
     obs.inc("time.compile_seconds", warm_seconds)
 
     svc = SolveService(policy, seed=0)
-    with obs.span("bench.serve_tenants", fence=False, requests=requests,
+    with obs.span("bench.serve_tenants", requests=requests,
                   tenant_mix=mix):
         stats, makespan = _drive_open_loop(svc, schedule, problem,
                                            tenants=tenants)
@@ -1439,7 +1439,7 @@ def _serve_fleet_bench(problem, requests: int, workers: int,
         # recompiles out of its first real dispatches.
         warm_devices = tuple(devices[i % len(devices)]
                              for i in range(fleet_devices))
-    with obs.span("bench.serve_warmup", fence=False, requests=requests):
+    with obs.span("bench.serve_warmup", requests=requests):
         t0 = time.time()
         warmed = _warm_serve_buckets(problem, "float32", max_batch,
                                      requests, refill_chunk=refill_chunk,
@@ -1463,7 +1463,7 @@ def _serve_fleet_bench(problem, requests: int, workers: int,
     else:
         worker_fault = injectors[0] if injectors else None
     svc = SolveService(policy, seed=0, worker_fault=worker_fault)
-    with obs.span("bench.serve_fleet", fence=False, requests=requests,
+    with obs.span("bench.serve_fleet", requests=requests,
                   workers=workers):
         stats, makespan = _drive_open_loop(svc, schedule, problem,
                                            t0=t_bench)
@@ -1618,7 +1618,7 @@ def _serve_bench(problem, requests: int, devices, platform: str,
         svc.drain()
         return svc
 
-    with obs.span("bench.serve_warmup", fence=False, requests=requests):
+    with obs.span("bench.serve_warmup", requests=requests):
         t0 = time.time()
         # Every ladder bucket the batch former can produce, THEN a full
         # campaign: the campaign alone only warms the shapes its own
@@ -1640,7 +1640,7 @@ def _serve_bench(problem, requests: int, devices, platform: str,
         first_run = time.time() - t0
     obs.inc("time.compile_seconds", first_run)
 
-    with obs.span("bench.serve_timed", fence=False, requests=requests):
+    with obs.span("bench.serve_timed", requests=requests):
         t0 = time.time()
         svc = load(build())
         wall = time.time() - t0
@@ -1716,7 +1716,7 @@ def _verify_bench(problem, verify_every: int, devices, platform: str) -> int:
         return pcg_solve(problem, dtype=dtype, rhs_gate=gate,
                          verify_every=verify_every)
 
-    with obs.span("bench.verify_warmup", fence=False,
+    with obs.span("bench.verify_warmup",
                   verify_every=verify_every):
         t0 = time.perf_counter()
         base = base_run()
@@ -1735,7 +1735,7 @@ def _verify_bench(problem, verify_every: int, devices, platform: str) -> int:
         fence(res.iterations)
         return time.perf_counter() - t0
 
-    with obs.span("bench.verify_timed", fence=False,
+    with obs.span("bench.verify_timed",
                   verify_every=verify_every):
         tb = (min(chain(base_run, K_HI) for _ in range(3))
               - min(chain(base_run, K_LO) for _ in range(3)))
@@ -1838,7 +1838,7 @@ def _preconditioner_bench(problem, preconditioner: str, devices,
         return pcg_solve(problem, dtype=dtype, rhs_gate=gate,
                          preconditioner="mg")
 
-    with obs.span("bench.preconditioner_warmup", fence=False,
+    with obs.span("bench.preconditioner_warmup",
                   preconditioner=preconditioner):
         t0 = time.perf_counter()
         rj = jac_run()
@@ -1857,7 +1857,7 @@ def _preconditioner_bench(problem, preconditioner: str, devices,
         fence(res.iterations)
         return time.perf_counter() - t0
 
-    with obs.span("bench.preconditioner_timed", fence=False):
+    with obs.span("bench.preconditioner_timed"):
         tj = (min(chain(jac_run, K_HI) for _ in range(3))
               - min(chain(jac_run, K_LO) for _ in range(3)))
         tm = (min(chain(mg_run, K_HI) for _ in range(3))
@@ -2370,7 +2370,7 @@ def main() -> int:
     # Warm-up: trace + compile (cached for the timed runs), and the golden
     # iteration check — a backend that mis-iterates fails the run.
     golden = GOLDEN_ITERS.get((problem.M, problem.N))
-    with obs.span("bench.warmup_compile", fence=False,
+    with obs.span("bench.warmup_compile",
                   grid=f"{problem.M}x{problem.N}"):
         t0 = time.perf_counter()
         result = run()
@@ -2399,7 +2399,7 @@ def main() -> int:
         fence(res.iterations)
         return time.perf_counter() - t0
 
-    with obs.span("bench.timed_chains", fence=False,
+    with obs.span("bench.timed_chains",
                   k_lo=K_LO, k_hi=K_HI) as timed_span:
         t_lo = min(timed_chain(K_LO) for _ in range(3))
         t_hi = min(timed_chain(K_HI) for _ in range(3))

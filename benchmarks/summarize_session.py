@@ -282,7 +282,7 @@ def telemetry_report(tdir: pathlib.Path) -> int:
     print(f"\n{len(events)} events, {len(traces)} rank trace(s)"
           + (f" — open in https://ui.perfetto.dev" if traces else ""))
 
-    # Phases: span_end records carry the fenced duration.
+    # Phases: span_end records carry the span's duration.
     spans: dict[str, list[float]] = {}
     for e in events:
         if e.get("kind") == "span_end" and "seconds" in e:

@@ -16,7 +16,8 @@ the fictitious-domain method — see SURVEY.md):
                  ``ppermute`` halo exchange, ``psum`` reductions — the TPU-native
                  equivalent of the reference's MPI decomposition (§2.3-2.4).
 - ``utils``    — instrumentation, timing, reporting (reference layer 7, §5).
-- ``obs``      — unified telemetry: fenced spans (Chrome/Perfetto traces +
+- ``obs``      — unified telemetry: spans on the profiler's clock (and
+                 Chrome/Perfetto traces +
                  JSONL event logs, per-rank mergeable), always-on counters,
                  and opt-in streamed convergence out of the fused loop —
                  the production observability layer the reference's five

@@ -673,7 +673,7 @@ def _run_jax(args, problem: Problem, backend: str, watchdog=None,
         best = timer.times["compile_and_first_solve"]
     else:
         best = None
-        with obs.span("timed_solves", fence=False,
+        with obs.span("timed_solves",
                       repeat=max(1, args.repeat)):
             for _ in range(max(1, args.repeat)):
                 t0 = time.perf_counter()
@@ -925,7 +925,7 @@ def _main_solve_batched(argv) -> int:
         result = run()
         fence(result)
     best = None
-    with obs.span("timed_batched_solves", fence=False, repeat=args.repeat):
+    with obs.span("timed_batched_solves", repeat=args.repeat):
         for _ in range(args.repeat):
             t0 = time.perf_counter()
             result = run()
@@ -962,7 +962,7 @@ def _main_solve_batched(argv) -> int:
                                        rhs_gate=g, geometry=geo,
                                        preconditioner=args.preconditioner)
         fence(seq(gates[0], geos[0]))  # compile once outside the timing
-        with obs.span("timed_sequential_solves", fence=False, batch=B):
+        with obs.span("timed_sequential_solves", batch=B):
             t0 = time.perf_counter()
             seq_iters = []
             for g, geo in zip(gates, geos):

@@ -4,10 +4,12 @@ One subsystem replaces the four ad-hoc sinks that grew around the solve
 stack (PhaseTimer dicts, watchdog heartbeat JSON, restart history inside
 ``DivergenceError``, bench session.jsonl):
 
-- **spans** (:mod:`poisson_tpu.obs.trace`) — nestable fenced timed
-  regions, emitted as Chrome/Perfetto trace JSON plus a structured JSONL
-  event log, with rank attribution so multihost runs merge into one
-  timeline;
+- **spans** (:mod:`poisson_tpu.obs.trace`) — nestable timed regions of
+  host time. Every span is a ``jax.profiler.TraceAnnotation``, so under
+  a profiler session it sits in the ``.xplane.pb`` on the device trace's
+  clock. When configured, spans are also emitted as Chrome/Perfetto
+  trace JSON plus a structured JSONL event log, with rank attribution so
+  multihost runs merge into one timeline;
 - **counters** (:mod:`poisson_tpu.obs.metrics`) — an always-on process-
   wide registry (restarts, CRC failures, watchdog beats, iterations by
   stop-flag, …) snapshotted to JSON at exit and merged per rank;
@@ -44,16 +46,16 @@ Usage (the CLI wires this from ``--trace-dir``/``--metrics-out``/
     obs.finalize()
 
 Everything degrades to near-zero-cost no-ops when unconfigured:
-``obs.span`` becomes an un-fenced null context, ``obs.event`` drops the
-record, counters still count (a locked dict add), streaming is not even
-traced into the program. ``python -m poisson_tpu.obs.selfcheck`` smoke-
+``obs.span`` is a bare profiler annotation (a check while no profiler
+session runs; it writes no file), ``obs.event`` drops the record,
+counters still count (a locked dict add), streaming is not even traced
+into the program. ``python -m poisson_tpu.obs.selfcheck`` smoke-
 tests the whole round trip.
 """
 
 from __future__ import annotations
 
 import atexit
-import contextlib
 from typing import Optional
 
 from poisson_tpu.obs import metrics, profile, stream, trace
@@ -176,12 +178,14 @@ def stream_every() -> int:
     return _STREAM_EVERY
 
 
-def span(name: str, fence: bool = True, **args):
-    """A span on the active recorder, or a null context when telemetry
-    is unconfigured (so call sites never need to guard)."""
+def span(name: str, **args):
+    """A span on the profiler's clock (``jax.profiler.TraceAnnotation``
+    of ``name``; ``args`` stay off it), also recorded to the active
+    recorder when telemetry is configured. Call sites never guard. Spans
+    belong in host code, never inside a jitted function."""
     if _RECORDER is not None:
-        return _RECORDER.span(name, fence=fence, **args)
-    return contextlib.nullcontext()
+        return _RECORDER.span(name, **args)
+    return trace.annotation(name)
 
 
 def event(name: str, **fields) -> None:

@@ -91,7 +91,7 @@ def capture(name: str, profile_dir: Optional[str] = None):
                   error=metrics_note)
         yield None
         return
-    span = obs.span(f"profile.{name}", fence=False, dir=out)
+    span = obs.span(f"profile.{name}", dir=out)
     span.__enter__()
     try:
         yield out

@@ -142,7 +142,7 @@ def run_selfcheck(out_dir: str) -> int:
     if not {"selfcheck", "selfcheck.solve", "selfcheck.done"} <= names:
         return _fail(f"expected spans/events absent from trace: {names}")
 
-    # 3. Event log: every line parses, spans carry fenced durations
+    # 3. Event log: every line parses, spans carry their durations
     # (normalize_event folds the v2 attrs block flat — the same loader
     # tolerance load_events applies to v1 and v2 lines alike).
     from poisson_tpu.obs.trace import normalize_event

@@ -6,7 +6,7 @@ This framework's equivalents were scattered across four sinks with four
 schemas (PhaseTimer dicts, watchdog heartbeat JSON, restart history
 inside ``DivergenceError``, bench session.jsonl) — no way to reconstruct
 what a long solve actually did. This module replaces them with ONE
-nestable, fenced span API that emits two views of the same record:
+nestable span API that emits two views of the same record:
 
 - ``trace-rank{R}.trace.json`` — Chrome/Perfetto trace-event JSON
   (``{"traceEvents": [{"ph": "X", "ts": …, "dur": …, "name": …,
@@ -22,11 +22,14 @@ nestable, fenced span API that emits two views of the same record:
   cross-host alignment, monotonic for stall arithmetic a clock jump
   cannot fake.
 
-Span exit fences outstanding device work (``jax.effects_barrier``) by
-default — the ``MPI_Barrier``+``MPI_Wtime`` idiom — so span boundaries
-are real, not dispatch points. The recorder holds no JAX state and all
-jax use is lazy: importing this module (e.g. from ``bench.py`` before
-its backend probe) must not initialize a backend.
+Every span is also a ``jax.profiler.TraceAnnotation`` of its name, so
+that under a profiler session it lands in the ``.xplane.pb`` on the
+device trace's clock, nested under the caller's annotations on the same
+thread. With no session running an annotation costs a check. Span exit
+does not fence device work: a span marks what the host did, and the
+profiler's device lines say when the device did it. The recorder holds
+no JAX state and all jax use is lazy: importing this module (e.g. from
+``bench.py`` before its backend probe) must not initialize a backend.
 """
 
 from __future__ import annotations
@@ -49,15 +52,20 @@ from typing import Optional
 EVENTS_SCHEMA = 2
 
 
-def _device_fence() -> None:
-    """Best-effort fence of outstanding device work (lazy jax import: a
-    recorder must be usable before — or entirely without — a backend)."""
-    try:
-        import jax
+_TRACE_ANNOTATION = None
 
-        jax.effects_barrier()
-    except Exception:
-        pass
+
+def annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``, imported on first use (a
+    recorder must be usable before — or entirely without — a backend).
+    No per-call arguments: formatting them would cost time on the hot
+    path, and the profiler would fold them into the name."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION(name)
 
 
 def default_rank() -> int:
@@ -82,16 +90,18 @@ def default_rank() -> int:
 class _Span:
     """Context manager for one span; created via :meth:`TraceRecorder.span`."""
 
-    __slots__ = ("_rec", "name", "args", "fence", "_t0", "_wall0", "seconds")
+    __slots__ = ("_rec", "name", "args", "_annotation", "_t0", "_wall0",
+                 "seconds")
 
-    def __init__(self, rec: "TraceRecorder", name: str, fence: bool, args):
+    def __init__(self, rec: "TraceRecorder", name: str, args):
         self._rec = rec
         self.name = name
         self.args = args
-        self.fence = fence
         self.seconds: Optional[float] = None
 
     def __enter__(self) -> "_Span":
+        self._annotation = annotation(self.name)
+        self._annotation.__enter__()
         self._rec._push(self.name)
         self._t0 = time.perf_counter()
         self._wall0 = time.time()
@@ -99,9 +109,8 @@ class _Span:
         return self
 
     def __exit__(self, *exc) -> None:
-        if self.fence:
-            _device_fence()
         self.seconds = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
         path = self._rec._pop()
         self._rec._add_trace_event({
             "ph": "X",
@@ -161,11 +170,10 @@ class TraceRecorder:
 
     # -- public API ----------------------------------------------------
 
-    def span(self, name: str, fence: bool = True, **args) -> _Span:
-        """Nestable timed region. ``fence=True`` (default) runs
-        ``jax.effects_barrier`` at exit so the recorded duration covers
-        the device work dispatched inside, not just the host time."""
-        return _Span(self, name, fence, args)
+    def span(self, name: str, **args) -> _Span:
+        """Nestable timed region of host time, recorded here and in the
+        profiler's trace (see the module docstring)."""
+        return _Span(self, name, args)
 
     def event(self, name: str, **fields) -> None:
         """Instant event: a point on the timeline plus a JSONL record."""

@@ -86,6 +86,7 @@ from poisson_tpu.ops.pallas_cg import (
     _strip_in_spec,
     build_canvases,
     canvas_cols,
+    named,
     strip_height,
     _shift_col_minus,
     _shift_col_plus,
@@ -333,6 +334,7 @@ def basis_sweep(cv: Canvas, beta, pprev, r, cs, cw, g, sc2, *,
             [pltpu.SMEM((N_GRAM,), jnp.float32)] if serial else []
         ),
         interpret=interpret,
+        **named("basis_sweep"),
         **_grid_params(parallel),
     )(*operands)
 
@@ -380,6 +382,7 @@ def pair_update(cv: Canvas, coefs, pn, t1, t2, t3, x, r, *,
         input_output_aliases={x_idx: 0, x_idx + 1: 1},   # x → x', r → r'
         scratch_shapes=([pltpu.SMEM((1,), jnp.float32)] if serial else []),
         interpret=interpret,
+        **named("pair_update"),
         **_grid_params(parallel),
     )(*operands)
 

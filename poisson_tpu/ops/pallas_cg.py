@@ -77,6 +77,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from poisson_tpu import obs
 from poisson_tpu.config import Problem
 from poisson_tpu.solvers.pcg import (
     PCGResult,
@@ -618,6 +619,16 @@ def _partial_out_spec():
     return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
+def named(kernel: str) -> dict:
+    """``pallas_call`` keywords that give a kernel its stable name:
+    ``name`` for the Mosaic kernel, and ``metadata``, which the custom
+    call carries as ``frontend_attributes={kernel_metadata={"kernel":
+    "<kernel>"}}`` into the compiled program and into the name of each
+    of its op events in a TPU profile. A refactor renames HLO
+    instructions (``body.6``); it leaves this name alone."""
+    return {"name": kernel, "metadata": {"kernel": kernel}}
+
+
 def _canvas_shape(cv: Canvas, dtype):
     return jax.ShapeDtypeStruct((cv.rows, cv.cols), dtype)
 
@@ -715,6 +726,7 @@ def direction_and_stencil(cv: Canvas, beta, z, p, cs, cw, g, *,
                 [pltpu.SMEM((1,), jnp.float32)] if serial else []
             ),
             interpret=interpret,
+            **named("direction_and_stencil"),
             **_grid_params(parallel, 2),
         )(beta, z, p, cs, cw, g)
     masked = colmask is not None
@@ -747,6 +759,7 @@ def direction_and_stencil(cv: Canvas, beta, z, p, cs, cw, g, *,
         ],
         scratch_shapes=([pltpu.SMEM((1,), jnp.float32)] if serial else []),
         interpret=interpret,
+        **named("direction_and_stencil"),
         **_grid_params(parallel),
     )(*operands)
 
@@ -783,6 +796,7 @@ def fused_update(cv: Canvas, alpha, p, ap, sc2, w, r, *, interpret: bool,
                 [pltpu.SMEM((2,), jnp.float32)] if serial else []
             ),
             interpret=interpret,
+            **named("fused_update"),
             **_grid_params(parallel, 2),
         )(alpha, p, ap, sc2, w, r)
     masked = colmask is not None
@@ -821,6 +835,7 @@ def fused_update(cv: Canvas, alpha, p, ap, sc2, w, r, *, interpret: bool,
         input_output_aliases={w_idx: 0, w_idx + 1: 1},  # w → w', r → r'
         scratch_shapes=([pltpu.SMEM((2,), jnp.float32)] if serial else []),
         interpret=interpret,
+        **named("fused_update"),
         **_grid_params(parallel),
     )(*operands)
 
@@ -955,20 +970,29 @@ def pallas_cg_solve(problem: Problem, bm: int | None = None,
     grids too wide for a sane full-width strip height. ``serial`` selects
     the reduction-partial layout (None = the ``POISSON_TPU_SERIAL_REDUCE``
     env default; see the module constant).
+
+    Runs under the span ``pallas_cg_solve`` with the children
+    ``.prepare`` (canvases, gate), ``.launch`` and ``.finish`` (slice,
+    unscale, pad): see :func:`poisson_tpu.obs.span`.
     """
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-    cv, cs, cw, g, rhs, sc2, sc_int = build_canvases(
-        problem, bm, dtype_name, bn
-    )
-    if rhs_gate is not None:
-        rhs = rhs * jnp.asarray(rhs_gate, rhs.dtype)
-    s = _fused_solve(problem, cv, interpret, parallel,
-                     _resolve_serial(serial, parallel), cs, cw, g, rhs, sc2)
-    # Canvas → full-grid solution, unscaled: w = sc · y.
-    M, N = problem.M, problem.N
-    y = s.w[HALO : HALO + M - 1, cv.cg + 1 : cv.cg + N]
-    w = jnp.pad(y * sc_int, 1)
+    with obs.span("pallas_cg_solve"):
+        with obs.span("pallas_cg_solve.prepare"):
+            if interpret is None:
+                interpret = jax.devices()[0].platform != "tpu"
+            cv, cs, cw, g, rhs, sc2, sc_int = build_canvases(
+                problem, bm, dtype_name, bn
+            )
+            if rhs_gate is not None:
+                rhs = rhs * jnp.asarray(rhs_gate, rhs.dtype)
+            serial = _resolve_serial(serial, parallel)
+        with obs.span("pallas_cg_solve.launch"):
+            s = _fused_solve(problem, cv, interpret, parallel, serial,
+                             cs, cw, g, rhs, sc2)
+        with obs.span("pallas_cg_solve.finish"):
+            # Canvas → full-grid solution, unscaled: w = sc · y.
+            M, N = problem.M, problem.N
+            y = s.w[HALO : HALO + M - 1, cv.cg + 1 : cv.cg + N]
+            w = jnp.pad(y * sc_int, 1)
     return PCGResult(w=w, iterations=s.k, diff=s.diff, residual_dot=s.zr)
 
 

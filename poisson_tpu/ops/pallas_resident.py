@@ -46,6 +46,7 @@ from poisson_tpu.ops.pallas_cg import (
     _shift_col_minus,
     _shift_col_plus,
     build_canvases,
+    named,
 )
 from poisson_tpu.solvers.pcg import PCGResult, _DENOM_TOL
 
@@ -166,6 +167,7 @@ def _resident_solve(problem: Problem, cv: Canvas, interpret: bool,
             pltpu.VMEM((cv.rows, cv.cols), jnp.float32),
         ],
         interpret=interpret,
+        **named("resident_solve"),
     )(cs, cw, g, rhs, sc2)
 
 

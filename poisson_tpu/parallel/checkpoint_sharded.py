@@ -294,7 +294,7 @@ def pcg_solve_sharded_checkpointed(problem: Problem, mesh: Mesh,
         # span it so slow checkpoints are visible on the timeline.
         from poisson_tpu import obs
 
-        with obs.span("checkpoint.gather", fence=False,
+        with obs.span("checkpoint.gather",
                       mesh=f"{px_size}x{py_size}"):
             return _to_full_grid(_fetchable(s, mesh), problem)
 

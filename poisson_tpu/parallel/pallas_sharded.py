@@ -50,6 +50,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from poisson_tpu import obs
 from poisson_tpu.config import Problem
 from poisson_tpu.ops.pallas_cg import (
     HALO,
@@ -283,9 +284,10 @@ def _run_shard(problem: Problem, spec: ShardSpec, px: int, py: int,
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 11, 12))
-def _solve(problem: Problem, mesh: Mesh, spec: ShardSpec, interpret: bool,
-           cs, cw, g, rhs, sc2, sc_int, colmask,
-           parallel: bool = False, serial: bool = False) -> PCGResult:
+def _fused_solve_sharded(problem: Problem, mesh: Mesh, spec: ShardSpec,
+                         interpret: bool, cs, cw, g, rhs, sc2, sc_int,
+                         colmask, parallel: bool = False,
+                         serial: bool = False) -> PCGResult:
     px = mesh.shape[X_AXIS]
     py = mesh.shape[Y_AXIS]
 
@@ -322,20 +324,28 @@ def pallas_cg_solve_sharded(problem: Problem, mesh: Mesh,
     (and are tested) on the virtual CPU mesh. ``rhs_gate`` as in
     ``pallas_cg_solve``; ``parallel`` marks each shard's strip grid
     parallel (megacore TensorCore split within a chip).
+
+    Runs under the span ``pallas_cg_solve_sharded`` with the children
+    ``.prepare`` (shard canvases, gate) and ``.launch``: see
+    :func:`poisson_tpu.obs.span`.
     """
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-    px = mesh.shape[X_AXIS]
-    py = mesh.shape[Y_AXIS]
-    spec = shard_spec(problem, px, py, bm)
-    cs, cw, g, rhs, sc2, sc_int, colmask = _shard_canvases(
-        problem, px, py, spec, dtype_name
-    )
-    if rhs_gate is not None:
-        rhs = rhs * jnp.asarray(rhs_gate, rhs.dtype)
-    return _solve(problem, mesh, spec, interpret,
-                  cs, cw, g, rhs, sc2, sc_int, colmask, parallel,
-                  _resolve_serial(serial, parallel))
+    with obs.span("pallas_cg_solve_sharded"):
+        with obs.span("pallas_cg_solve_sharded.prepare"):
+            if interpret is None:
+                interpret = jax.devices()[0].platform != "tpu"
+            px = mesh.shape[X_AXIS]
+            py = mesh.shape[Y_AXIS]
+            spec = shard_spec(problem, px, py, bm)
+            cs, cw, g, rhs, sc2, sc_int, colmask = _shard_canvases(
+                problem, px, py, spec, dtype_name
+            )
+            if rhs_gate is not None:
+                rhs = rhs * jnp.asarray(rhs_gate, rhs.dtype)
+            serial = _resolve_serial(serial, parallel)
+        with obs.span("pallas_cg_solve_sharded.launch"):
+            return _fused_solve_sharded(
+                problem, mesh, spec, interpret,
+                cs, cw, g, rhs, sc2, sc_int, colmask, parallel, serial)
 
 
 # ---------------------------------------------------------------------------

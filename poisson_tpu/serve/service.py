@@ -1697,7 +1697,7 @@ class SolveService:
         did = self._flight.next_dispatch_id()
         t_step = self._clock()
         try:
-            with obs.span("serve.refill.step", fence=False,
+            with obs.span("serve.refill.step",
                           cohort=table.cohort, active=len(occupants),
                           worker=worker.id):
                 if self._worker_fault is not None:
@@ -1963,7 +1963,7 @@ class SolveService:
                 epoch=self._registry.epoch)
         t_disp = self._clock()
         try:
-            with obs.span("serve.dispatch", fence=False, cohort=cohort,
+            with obs.span("serve.dispatch", cohort=cohort,
                           batch=len(batch), level=level,
                           worker=worker.id):
                 if self._worker_fault is not None:

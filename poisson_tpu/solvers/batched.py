@@ -41,7 +41,7 @@ are rejected loudly when combined with ``mesh=``.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Sequence
+from typing import Any, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -455,7 +455,56 @@ def solve_batched(problems=None, *, rhs_stack=None, rhs_gates=None,
     ``geometries`` do not co-batch with MG yet — each member would
     need its own level hierarchy — and are rejected loudly (the solve
     service dispatches geometry+MG requests solo).
+
+    Each call runs under the span ``solve_batched`` with the children
+    ``solve_batched.prepare`` (argument checks, gate upload, set-up
+    lookup, padding), ``solve_batched.launch`` (the dispatch) and
+    ``solve_batched.finish`` (slicing the padding off): see
+    :func:`poisson_tpu.obs.span`.
     """
+    with obs.span("solve_batched"):
+        with obs.span("solve_batched.prepare"):
+            prep = _prepare_batch(
+                problems, rhs_stack=rhs_stack, rhs_gates=rhs_gates,
+                dtype=dtype, scaled=scaled, mesh=mesh, buckets=buckets,
+                bucket=bucket, member_ids=member_ids, geometries=geometries,
+                verify_every=verify_every, verify_tol=verify_tol,
+                preconditioner=preconditioner, mode=mode)
+        with obs.span("solve_batched.launch"):
+            result = _launch_batch(prep, mesh, mg_config)
+        with obs.span("solve_batched.finish"):
+            return _finish_batch(prep, result)
+
+
+class _Prepared(NamedTuple):
+    """What ``solve_batched``'s host preparation hands its dispatch."""
+
+    problem: Problem
+    jit_problem: Problem      # f_val normalized away (see _prepare_batch)
+    dtype_name: str
+    use_scaled: bool
+    use_block: bool
+    use_mg: bool
+    geo: Optional[list]       # parsed per-member geometries, or None
+    setups: Optional[list]    # per-member (a, b, rhs, aux) on that path
+    a: Any
+    b: Any
+    aux: Any
+    rhs_stack: Any            # padded to ``size`` members
+    batch: int
+    size: int
+    origin: tuple
+    verify_every: int
+    v_tol: float
+    verify_key: Optional[tuple]
+
+
+def _prepare_batch(problems, *, rhs_stack, rhs_gates, dtype, scaled, mesh,
+                   buckets, bucket, member_ids, geometries, verify_every,
+                   verify_tol, preconditioner, mode) -> _Prepared:
+    """``solve_batched``'s host preparation: every argument check, the
+    gate upload, the ``host_setup`` lookup and the padded ``rhs_stack``
+    (see :func:`solve_batched` for the arguments)."""
     from poisson_tpu.krylov import KRYLOV_BLOCK, KRYLOV_MODES
 
     if mode not in KRYLOV_MODES:
@@ -550,6 +599,7 @@ def solve_batched(problems=None, *, rhs_stack=None, rhs_gates=None,
     else:
         use_mg = False
     geo = setups = None
+    a = b = aux = None
     if geometries is not None:
         from poisson_tpu.geometry.dsl import parse_geometry
 
@@ -695,6 +745,18 @@ def solve_batched(problems=None, *, rhs_stack=None, rhs_gates=None,
     # flag-off key keeps its historical shape and counter arithmetic.
     verify_key = (("verify", verify_every, v_tol)
                   if verify_every > 0 else None)
+    return _Prepared(problem, jit_problem, dtype_name, use_scaled, use_block,
+                     use_mg, geo, setups, a, b, aux, rhs_stack, batch, size,
+                     origin, verify_every, v_tol, verify_key)
+
+
+def _launch_batch(prep: _Prepared, mesh, mg_config) -> PCGResult:
+    """``solve_batched``'s dispatch: the bucket-cache bookkeeping and the
+    jitted call of whichever executable family runs (the mesh and MG
+    families also look up their shard blocks or level hierarchy here)."""
+    (problem, jit_problem, dtype_name, use_scaled, use_block, use_mg, geo,
+     setups, a, b, aux, rhs_stack, batch, size, _, verify_every, v_tol,
+     verify_key) = prep
     if use_block:
         from poisson_tpu.krylov.block import _solve_block
 
@@ -708,9 +770,8 @@ def solve_batched(problems=None, *, rhs_stack=None, rhs_gates=None,
             key = key + ("geo",)
         _count_bucket(key, batch, size)
         obs.inc("krylov.block.solves", batch)
-        result = _solve_block(jit_problem, use_scaled, a, b, rhs_stack,
-                              aux)
-        return result._replace(origin=origin)
+        return _solve_block(jit_problem, use_scaled, a, b, rhs_stack,
+                            aux)
     if mesh is not None:
         from poisson_tpu.parallel.mesh import X_AXIS, Y_AXIS, block_size
         from poisson_tpu.parallel.pcg_sharded import (
@@ -784,6 +845,13 @@ def solve_batched(problems=None, *, rhs_stack=None, rhs_gates=None,
         _count_bucket(key, batch, size)
         result = _solve_batched(jit_problem, use_scaled, verify_every,
                                 v_tol, a, b, rhs_stack, aux)
+    return result
+
+
+def _finish_batch(prep: _Prepared, result: PCGResult) -> PCGResult:
+    """The dispatch's result with its padding members sliced off and
+    ``origin`` attached."""
+    batch, size, origin = prep.batch, prep.size, prep.origin
     if size == batch:
         return result._replace(origin=origin)
     # Slice padding members off every batched field; max_iterations is

@@ -354,7 +354,7 @@ def save_state(path: str, state: PCGState, fingerprint: str,
     # suffixed so the atomic-replace source path is what savez wrote.
     tmp = f"{path}.{os.getpid()}.tmp.npz"
     try:
-        with obs.span("checkpoint.write", fence=False, path=path):
+        with obs.span("checkpoint.write", path=path):
             np.savez(
                 tmp,
                 fingerprint=np.asarray(fingerprint),

@@ -64,7 +64,7 @@ class PhaseTimer:
             def __enter__(self):
                 from poisson_tpu import obs
 
-                self._span = obs.span(name, fence=False)
+                self._span = obs.span(name)
                 self._span.__enter__()
                 self._t0 = time.perf_counter()
                 return self

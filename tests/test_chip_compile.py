@@ -5,7 +5,10 @@ No chip is attached: the TPU compiler is installed and compiles for a
 a real size with shapes (not arrays) and asserts the compiled program
 holds a Mosaic kernel (``tpu_custom_call``) — i.e. the kernel lowered for
 the chip instead of the interpreter. Nothing runs, so nothing here says
-anything about results or times.
+anything about results or times. Each kernel also carries its stable
+name into the compiled program (``ops.pallas_cg.named``): the custom
+call's ``kernel_metadata``, which a TPU profile shows in the name of
+each of the kernel's op events.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and under pytest-xdist
@@ -14,6 +17,7 @@ for the same reason.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +32,13 @@ from poisson_tpu.parallel import pallas_ca_sharded, pallas_sharded
 from poisson_tpu.parallel.mesh import X_AXIS, Y_AXIS
 
 KERNEL = "tpu_custom_call"
+# The name a Mosaic kernel's custom call carries (the JSON of its
+# kernel_metadata spans lines; the operands' tuple elements carry it too,
+# so only the custom call's own attribute counts).
+KERNEL_NAME = re.compile(r'custom_call_target="tpu_custom_call"[^\n]*?'
+                         r'kernel_metadata=\{\s*"kernel"\s*:\s*"([^"]+)"')
+# Compiled text of each lowering, by key, within this module.
+_TEXTS = {}
 
 
 @pytest.fixture(scope="module")
@@ -72,33 +83,31 @@ def _canvases(cv, sharding, n=5):
     return [shape] * n
 
 
-def _assert_kernel(lowered):
-    assert KERNEL in lowered.compile().as_text()
+def _compiled(key, lower):
+    if key not in _TEXTS:
+        _TEXTS[key] = lower().compile().as_text()
+    return _TEXTS[key]
+
+
+def _assert_kernel(key, lower):
+    assert KERNEL in _compiled(key, lower)
 
 
 @pytest.mark.parametrize("M,N,serial", [
     (800, 1200, False), (800, 1200, True), (2400, 3200, False),
 ])
 def test_fused(one_chip, M, N, serial):
-    problem = Problem(M=M, N=N)
-    cv = pallas_cg.canvas_spec(problem)
-    _assert_kernel(pallas_cg._fused_solve.lower(
-        problem, cv, False, False, serial, *_canvases(cv, one_chip)))
+    _assert_kernel(("fused", M, N, serial), lambda: _lower_fused(
+        one_chip, M, N, serial))
 
 
 @pytest.mark.parametrize("serial", [False, True])
 def test_ca(one_chip, serial):
-    problem = Problem(M=800, N=1200)
-    cv = pallas_cg.canvas_spec(problem, pallas_ca.pick_bm_ca(problem), 0)
-    _assert_kernel(pallas_ca._ca_solve.lower(
-        problem, cv, False, False, serial, *_canvases(cv, one_chip)))
+    _assert_kernel(("ca", serial), lambda: _lower_ca(one_chip, serial))
 
 
 def test_resident(one_chip):
-    problem = Problem(M=400, N=600)
-    cv = pallas_resident.resident_canvas(problem)
-    _assert_kernel(pallas_resident._resident_solve.lower(
-        problem, cv, False, *_canvases(cv, one_chip)))
+    _assert_kernel(("resident",), lambda: _lower_resident(one_chip))
 
 
 def _stacked_args(spec, mesh):
@@ -113,15 +122,76 @@ def _stacked_args(spec, mesh):
     return [canvas] * 5 + [sc_int, colmask]
 
 
-def test_fused_sharded(mesh):
+def _lower_fused(one_chip, M, N, serial):
+    problem = Problem(M=M, N=N)
+    cv = pallas_cg.canvas_spec(problem)
+    return pallas_cg._fused_solve.lower(
+        problem, cv, False, False, serial, *_canvases(cv, one_chip))
+
+
+def _lower_ca(one_chip, serial):
+    problem = Problem(M=800, N=1200)
+    cv = pallas_cg.canvas_spec(problem, pallas_ca.pick_bm_ca(problem), 0)
+    return pallas_ca._ca_solve.lower(
+        problem, cv, False, False, serial, *_canvases(cv, one_chip))
+
+
+def _lower_resident(one_chip):
+    problem = Problem(M=400, N=600)
+    cv = pallas_resident.resident_canvas(problem)
+    return pallas_resident._resident_solve.lower(
+        problem, cv, False, *_canvases(cv, one_chip))
+
+
+def _lower_fused_sharded(mesh):
     problem = Problem(M=2400, N=3200)
     spec = pallas_sharded.shard_spec(problem, 2, 2)
-    _assert_kernel(pallas_sharded._solve.lower(
-        problem, mesh, spec, False, *_stacked_args(spec, mesh)))
+    return pallas_sharded._fused_solve_sharded.lower(
+        problem, mesh, spec, False, *_stacked_args(spec, mesh))
+
+
+def _lower_ca_sharded(mesh):
+    problem = Problem(M=2400, N=3200)
+    spec = pallas_ca_sharded.ca_shard_spec(problem, 2, 2)
+    return pallas_ca_sharded._ca_solve_sharded.lower(
+        problem, mesh, spec, False, *_stacked_args(spec, mesh))
+
+
+def test_fused_sharded(mesh):
+    _assert_kernel(("fused_sharded",), lambda: _lower_fused_sharded(mesh))
 
 
 def test_ca_sharded(mesh):
-    problem = Problem(M=2400, N=3200)
-    spec = pallas_ca_sharded.ca_shard_spec(problem, 2, 2)
-    _assert_kernel(pallas_ca_sharded._ca_solve_sharded.lower(
-        problem, mesh, spec, False, *_stacked_args(spec, mesh)))
+    _assert_kernel(("ca_sharded",), lambda: _lower_ca_sharded(mesh))
+
+
+# Each lowering above, by the key its test compiles it under, and the
+# stable names of the kernels it holds: the fused pair serves the
+# one-chip and the sharded solve alike.
+NAMED = [
+    (("fused", 800, 1200, False), "one_chip",
+     lambda chip: _lower_fused(chip, 800, 1200, False),
+     {"direction_and_stencil", "fused_update"}),
+    (("fused", 2400, 3200, False), "one_chip",
+     lambda chip: _lower_fused(chip, 2400, 3200, False),
+     {"direction_and_stencil", "fused_update"}),
+    (("ca", False), "one_chip", lambda chip: _lower_ca(chip, False),
+     {"basis_sweep", "pair_update"}),
+    (("resident",), "one_chip", _lower_resident, {"resident_solve"}),
+    (("fused_sharded",), "mesh", _lower_fused_sharded,
+     {"direction_and_stencil", "fused_update"}),
+    (("ca_sharded",), "mesh", _lower_ca_sharded,
+     {"basis_sweep", "pair_update"}),
+]
+
+
+@pytest.mark.parametrize("key,where,lower,names", NAMED,
+                         ids=["-".join(map(str, n[0])) for n in NAMED])
+def test_kernels_carry_their_names(request, key, where, lower, names):
+    target = request.getfixturevalue(where)
+    text = _compiled(key, lambda: lower(target))
+    # Every Mosaic kernel of the program carries one name, and the names
+    # are the lowering's own.
+    assert text.count(f'custom_call_target="{KERNEL}"') == len(
+        KERNEL_NAME.findall(text)) > 0
+    assert set(KERNEL_NAME.findall(text)) == names

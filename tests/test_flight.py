@@ -446,7 +446,7 @@ def test_load_events_tolerates_v1_lines(tmp_path):
 
 def test_merge_trace_dir_tolerates_corrupt_rank_and_keeps_kinds(tmp_path):
     obs.configure(trace_dir=str(tmp_path), rank=0)
-    with obs.span("phase", fence=False):
+    with obs.span("phase"):
         obs.event("marker", k=1)
     obs.finalize()
     obs.shutdown()

@@ -134,7 +134,7 @@ def test_sharded_masked_lowers(grid, serial):
     )
     _export_tpu(
         lambda cs, cw, g, rhs, sc2, sc_int, colmask:
-        pallas_sharded._solve(
+        pallas_sharded._fused_solve_sharded(
             p, mesh, spec, False, cs, cw, g, rhs, sc2, sc_int, colmask,
             False, serial,
         ),

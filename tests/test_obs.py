@@ -63,7 +63,7 @@ def _load_trace(path) -> list[dict]:
 def test_trace_file_is_valid_chrome_trace(tmp_path):
     rec = obs.configure(trace_dir=str(tmp_path))
     with obs.span("outer", grid="40x40"):
-        with obs.span("inner", fence=False):
+        with obs.span("inner"):
             pass
     obs.event("marker", k=7)
     obs.finalize()
@@ -82,7 +82,7 @@ def test_trace_file_is_valid_chrome_trace(tmp_path):
 def test_event_log_schema_and_span_nesting(tmp_path):
     obs.configure(trace_dir=str(tmp_path))
     with obs.span("phase"):
-        with obs.span("step", fence=False):
+        with obs.span("step"):
             obs.event("tick", k=1)
     obs.finalize()
     records = load_events(str(tmp_path))
@@ -236,7 +236,7 @@ def test_sharded_solve_produces_mergeable_per_rank_logs(tmp_path):
     # A second rank's recorder, as another host of the same run would
     # write it (same dir, different rank).
     other = TraceRecorder(trace_dir=tdir, rank=1)
-    with other.span("sharded_solve", fence=False):
+    with other.span("sharded_solve"):
         other.event("checkpoint.write", k=10)
     other.close()
 
