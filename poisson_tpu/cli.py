@@ -957,10 +957,26 @@ def _main_solve_batched(argv) -> int:
         record["geometries"] = sorted({g.fingerprint for g in geometries})
 
     if args.compare_sequential:
+        import jax
+
+        from poisson_tpu.solvers.batched import uses_fused_kernels
+        from poisson_tpu.solvers.pcg import resolve_scaled
+
         geos = geometries or [None] * B
         seq = lambda g, geo: pcg_solve(problem, dtype=args.dtype,
                                        rhs_gate=g, geometry=geo,
                                        preconditioner=args.preconditioner)
+        if uses_fused_kernels(
+                jax.devices()[0].platform, record["dtype"],
+                resolve_scaled(None, record["dtype"]), mesh=mesh,
+                geometries=geometries, mg=args.preconditioner == "mg",
+                verify_every=args.verify_every):
+            # The batch ran on the member-axis kernels: its one-RHS
+            # counterpart is the fused solve on the same canvas.
+            from poisson_tpu.ops.pallas_cg import batched_bm, pallas_cg_solve
+
+            seq = lambda g, geo: pallas_cg_solve(
+                problem, bm=batched_bm(problem), rhs_gate=g)
         fence(seq(gates[0], geos[0]))  # compile once outside the timing
         with obs.span("timed_sequential_solves", batch=B):
             t0 = time.perf_counter()

@@ -55,6 +55,11 @@ Naming convention (dotted, low cardinality):
   multi-RHS driver traffic (``solvers.batched``): members solved, padding
   overhead, and whether ragged batch sizes are reusing bucket
   executables;
+- ``batched.fused.dispatches`` / ``batched.fused.members`` — batched
+  dispatches that ran on the member-axis Pallas kernels
+  (``ops.pallas_cg._fused_solve_batched``: TPU, fp32, scaled, no mesh/
+  geometries/MG/block/verify) and the members they carried: how often
+  the fused path engages beside the XLA families;
 - ``geom.cache.hits`` / ``geom.cache.misses`` — the geometry canvas
   cache (``poisson_tpu.geometry.canvas.geometry_setup``), keyed by
   (fingerprint, grid box, f_val, dtype, scaled) the way the jit cache

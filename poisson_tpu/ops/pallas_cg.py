@@ -80,6 +80,10 @@ from jax.experimental.pallas import tpu as pltpu
 from poisson_tpu import obs
 from poisson_tpu.config import Problem
 from poisson_tpu.solvers.pcg import (
+    FLAG_BREAKDOWN,
+    FLAG_CONVERGED,
+    FLAG_NONE,
+    FLAG_NONFINITE,
     PCGResult,
     PCGState,
     _DENOM_TOL,
@@ -373,6 +377,32 @@ def _shift_col_plus(u):
     return jnp.concatenate([u[:, 1:], jnp.zeros_like(u[:, :1])], axis=1)
 
 
+def _direction_stencil(cv: Canvas, band: tuple[int, int], beta,
+                       z_ref, p_ref, cs_ref, cw_ref, g_ref):
+    """Kernel A's arithmetic on strip ``program_id(0)``: the new
+    direction's center rows and their Ãp (see the kernel factory)."""
+    h = HALO
+    band_lo, band_hi = band
+    off = pl.program_id(0) * cv.bm
+    rows = off + lax.broadcasted_iota(jnp.int32, (cv.bm + 2 * h, 1), 0)
+    in_band = (rows >= band_lo) & (rows < band_hi)
+    pn = jnp.where(in_band, z_ref[:] + beta * p_ref[:], 0.0)
+    c = pn[h:-h, :]                            # center rows
+    cs_c = cs_ref[h:-h, :]                     # south-edge coeff at center
+    cs_n = cs_ref[h + 1 : -h + 1, :]           # north edge = cS shifted down
+    cw_c = cw_ref[:]                           # block-spec'd: center rows only
+    # Difference form: adjacent-value differences keep fp32 cancellation
+    # benign on smooth modes (see diagonal_residual_canvas).
+    ap = (
+        cs_n * (c - pn[h + 1 : -h + 1, :])
+        + cs_c * (c - pn[h - 1 : -h - 1, :])
+        + _shift_col_plus(cw_c) * (c - _shift_col_plus(c))
+        + cw_c * (c - _shift_col_minus(c))
+        + g_ref[:] * c
+    )
+    return c, ap
+
+
 def _make_direction_stencil_kernel(cv: Canvas, band: tuple[int, int],
                                    masked: bool, serial: bool = False):
     """Kernel A: p ← z + β·p, Ap ← Ãp, accumulate ⟨Ap, p⟩.
@@ -401,9 +431,6 @@ def _make_direction_stencil_kernel(cv: Canvas, band: tuple[int, int],
     garbage, but not NaN/Inf, so the strip is explicitly zeroed outside the
     live band right where it is computed.
     """
-    h = HALO
-    band_lo, band_hi = band
-
     def kernel(beta_ref, z_ref, p_ref, cs_ref, cw_ref, g_ref, *rest):
         comp_ref = None
         if serial:
@@ -413,26 +440,8 @@ def _make_direction_stencil_kernel(cv: Canvas, band: tuple[int, int],
         else:
             pn_ref, ap_ref, denom_ref = rest
         i = pl.program_id(0)
-        beta = beta_ref[0, 0]
-        off = i * cv.bm
-        rows = off + lax.broadcasted_iota(
-            jnp.int32, (cv.bm + 2 * h, 1), 0
-        )
-        in_band = (rows >= band_lo) & (rows < band_hi)
-        pn = jnp.where(in_band, z_ref[:] + beta * p_ref[:], 0.0)
-        c = pn[h:-h, :]                            # center rows
-        cs_c = cs_ref[h:-h, :]                     # south-edge coeff at center
-        cs_n = cs_ref[h + 1 : -h + 1, :]           # north edge = cS shifted down
-        cw_c = cw_ref[:]                           # block-spec'd: center rows only
-        # Difference form: adjacent-value differences keep fp32 cancellation
-        # benign on smooth modes (see diagonal_residual_canvas).
-        ap = (
-            cs_n * (c - pn[h + 1 : -h + 1, :])
-            + cs_c * (c - pn[h - 1 : -h - 1, :])
-            + _shift_col_plus(cw_c) * (c - _shift_col_plus(c))
-            + cw_c * (c - _shift_col_minus(c))
-            + g_ref[:] * c
-        )
+        c, ap = _direction_stencil(cv, band, beta_ref[0, 0], z_ref, p_ref,
+                                   cs_ref, cw_ref, g_ref)
         pn_ref[:] = c
         ap_ref[:] = ap
 
@@ -533,6 +542,16 @@ def _make_blocked_stencil_kernel(cv: Canvas, band: tuple[int, int],
     return kernel
 
 
+def _update(alpha, p_ref, ap_ref, w_ref, r_ref, w_out_ref, r_out_ref):
+    """Kernel B's update of one block: w ← w + α·p, r ← r − α·Ap.
+    Returns (p, r_new) for the partials."""
+    p = p_ref[:]
+    r_new = r_ref[:] - alpha * ap_ref[:]
+    w_out_ref[:] = w_ref[:] + alpha * p
+    r_out_ref[:] = r_new
+    return p, r_new
+
+
 def _make_update_kernel(masked: bool, serial: bool = False, ndims: int = 1):
     """Kernel B: w ← w + α·p, r ← r − α·Ap, accumulate Σp²·sc² and Σr².
 
@@ -549,11 +568,8 @@ def _make_update_kernel(masked: bool, serial: bool = False, ndims: int = 1):
             colmask_ref, w_ref, r_ref, w_out_ref, r_out_ref, diff_ref, zr_ref = rest
         else:
             w_ref, r_ref, w_out_ref, r_out_ref, diff_ref, zr_ref = rest
-        alpha = alpha_ref[0, 0]
-        p = p_ref[:]
-        r_new = r_ref[:] - alpha * ap_ref[:]
-        w_out_ref[:] = w_ref[:] + alpha * p
-        r_out_ref[:] = r_new
+        p, r_new = _update(alpha_ref[0, 0], p_ref, ap_ref, w_ref, r_ref,
+                           w_out_ref, r_out_ref)
         rr = r_new * r_new
         if masked:
             rr = rr * colmask_ref[:]
@@ -580,7 +596,7 @@ def _strip_in_spec(cv: Canvas):
     granules = cv.bm // SUBLANE
     return pl.BlockSpec(
         (pl.Element(cv.bm + 2 * HALO), pl.Element(cv.cols)),
-        lambda i: (SUBLANE * (i * granules), 0),
+        lambda i, *_: (SUBLANE * (i * granules), 0),
     )
 
 
@@ -590,7 +606,7 @@ def _block_spec(cv: Canvas):
     granules = cv.bm // SUBLANE
     return pl.BlockSpec(
         (pl.Element(cv.bm), pl.Element(cv.cols)),
-        lambda i: (SUBLANE * (i * granules + 1), 0),
+        lambda i, *_: (SUBLANE * (i * granules + 1), 0),
     )
 
 
@@ -840,6 +856,113 @@ def fused_update(cv: Canvas, alpha, p, ap, sc2, w, r, *, interpret: bool,
     )(*operands)
 
 
+# --- the member axis: B right-hand sides on one operator --------------------
+#
+# State is a (B, R, C) stack of canvases and the grid is (nb, B): strip
+# outer, member inner, so each coefficient operand (cs, cw, g, sc2) keeps
+# one block index across a strip's B consecutive steps and the pipeline
+# fetches it once per strip, not once per member. α and β arrive as (B,)
+# SMEM windows read at program_id(1); member m's strip partials land at
+# m·nb + i of a flat (B·nb,) SMEM output. Not ``jax.vmap`` of the one-RHS
+# calls: its batching rule puts the batch axis outermost, which re-reads
+# the coefficients for every member and shifts the program_id(0) the
+# partial layout indexes.
+
+
+def _member_spec(cv: Canvas, rows: int, granule0: int):
+    """Member m's ``rows``-row window at canvas row SUBLANE·(i·bm/SUBLANE
+    + granule0): the halo-inclusive strip (granule0 0) or the center
+    block (granule0 1)."""
+    granules = cv.bm // SUBLANE
+    return pl.BlockSpec(
+        (pl.squeezed, pl.Element(rows), pl.Element(cv.cols)),
+        lambda i, m: (m, SUBLANE * (i * granules + granule0), 0),
+    )
+
+
+def _stack_shape(cv: Canvas, members: int, dtype):
+    return jax.ShapeDtypeStruct((members, cv.rows, cv.cols), dtype)
+
+
+def _make_batched_direction_stencil_kernel(cv: Canvas):
+    band = (HALO, cv.rows - HALO)
+
+    def kernel(beta_ref, z_ref, p_ref, cs_ref, cw_ref, g_ref,
+               pn_ref, ap_ref, denom_ref):
+        i, m = pl.program_id(0), pl.program_id(1)
+        c, ap = _direction_stencil(cv, band, beta_ref[m], z_ref, p_ref,
+                                   cs_ref, cw_ref, g_ref)
+        pn_ref[:] = c
+        ap_ref[:] = ap
+        denom_ref[m * cv.nb + i] = jnp.sum(ap * c, dtype=jnp.float32)
+
+    return kernel
+
+
+def _make_batched_update_kernel(cv: Canvas):
+    def kernel(alpha_ref, p_ref, ap_ref, sc2_ref, w_ref, r_ref,
+               w_out_ref, r_out_ref, diff_ref, zr_ref):
+        i, m = pl.program_id(0), pl.program_id(1)
+        p, r_new = _update(alpha_ref[m], p_ref, ap_ref, w_ref, r_ref,
+                           w_out_ref, r_out_ref)
+        rr = r_new * r_new
+        diff_ref[m * cv.nb + i] = jnp.sum(p * p * sc2_ref[:],
+                                          dtype=jnp.float32)
+        zr_ref[m * cv.nb + i] = jnp.sum(rr, dtype=jnp.float32)
+
+    return kernel
+
+
+def batched_direction_and_stencil(cv: Canvas, beta, z, p, cs, cw, g, *,
+                                  interpret: bool):
+    """Kernel A over a (B, R, C) stack with per-member β of shape (B,):
+    p_new, Ap and the flat (B·nb,) ⟨Ap, p_new⟩ strip partials."""
+    members = z.shape[0]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    strip = _member_spec(cv, cv.bm + 2 * HALO, 0)
+    block = _member_spec(cv, cv.bm, 1)
+    return pl.pallas_call(
+        _make_batched_direction_stencil_kernel(cv),
+        grid=(cv.nb, members),
+        in_specs=[smem, strip, strip, _strip_in_spec(cv), _block_spec(cv),
+                  _block_spec(cv)],
+        out_specs=[block, block, smem],
+        out_shape=[
+            _stack_shape(cv, members, p.dtype),
+            _stack_shape(cv, members, p.dtype),
+            jax.ShapeDtypeStruct((members * cv.nb,), jnp.float32),
+        ],
+        interpret=interpret,
+        **named("batched_direction_and_stencil"),
+    )(beta, z, p, cs, cw, g)
+
+
+def batched_fused_update(cv: Canvas, alpha, p, ap, sc2, w, r, *,
+                         interpret: bool):
+    """Kernel B over a (B, R, C) stack with per-member α of shape (B,):
+    w', r' (aliasing w, r) and the flat (B·nb,) Σ p²·sc² and Σ r'²
+    strip partials."""
+    members = w.shape[0]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    block = _member_spec(cv, cv.bm, 1)
+    partial = jax.ShapeDtypeStruct((members * cv.nb,), jnp.float32)
+    return pl.pallas_call(
+        _make_batched_update_kernel(cv),
+        grid=(cv.nb, members),
+        in_specs=[smem, block, block, _block_spec(cv), block, block],
+        out_specs=[block, block, smem, smem],
+        out_shape=[
+            _stack_shape(cv, members, w.dtype),
+            _stack_shape(cv, members, w.dtype),
+            partial,
+            partial,
+        ],
+        input_output_aliases={4: 0, 5: 1},  # w → w', r → r'
+        interpret=interpret,
+        **named("batched_fused_update"),
+    )(alpha, p, ap, sc2, w, r)
+
+
 class _FusedState(NamedTuple):
     k: jnp.ndarray
     done: jnp.ndarray
@@ -994,6 +1117,126 @@ def pallas_cg_solve(problem: Problem, bm: int | None = None,
             y = s.w[HALO : HALO + M - 1, cv.cg + 1 : cv.cg + N]
             w = jnp.pad(y * sc_int, 1)
     return PCGResult(w=w, iterations=s.k, diff=s.diff, residual_dot=s.zr)
+
+
+def batched_bm(problem: Problem) -> int:
+    """Strip height of the member stacks: :func:`pick_bm`'s strip count
+    with the strips cut to the interior, ⌈(M−1)/nb⌉ rounded up to the
+    sublane granule. At 400×600 that is 104 rows (416 canvas rows for 399
+    interior ones) where ``pick_bm``'s 128 lays out 512; a stack pays
+    that slack on every member."""
+    interior = problem.M - 1
+    nb = -(-interior // pick_bm(problem))
+    per_strip = -(-interior // nb)
+    return -(-per_strip // SUBLANE) * SUBLANE
+
+
+def grid_to_canvas(problem: Problem, cv: Canvas, stack):
+    """A (B, M+1, N+1) stack of full grids with a zero Dirichlet ring → the
+    (B, R, C) canvas stack (full-width layout)."""
+    M, N = problem.M, problem.N
+    return jnp.pad(stack[:, 1:M, :], (
+        (0, 0), (HALO, cv.rows - HALO - (M - 1)), (0, cv.cols - (N + 1))))
+
+
+class _BatchedState(NamedTuple):
+    """Per-member (B,) scalars and (B, R, C) canvas stacks."""
+
+    k: jnp.ndarray
+    done: jnp.ndarray
+    w: jnp.ndarray
+    r: jnp.ndarray
+    p: jnp.ndarray
+    zr: jnp.ndarray
+    beta: jnp.ndarray
+    diff: jnp.ndarray
+    flag: jnp.ndarray
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _fused_solve_batched(problem: Problem, cv: Canvas, interpret: bool,
+                         cs, cw, g, rhs, sc2, sc_int) -> PCGResult:
+    """B right-hand sides (a (B, R, C) canvas stack) on one operator in one
+    ``while_loop`` over the member-axis kernels.
+
+    Each member runs :func:`_make_fused_body`'s arithmetic on its own
+    scalars. A member that has stopped (done, or at the cap) is frozen
+    without any canvas select: it gets α = β = 0, so kernel A forms p ← r
+    and kernel B leaves its w and r unchanged, and its scalars keep their
+    values. The loop runs while any member can advance. Flags follow the
+    XLA batched loop: breakdown (⟨Ap, p⟩ under the guard: zero right-hand
+    sides and padding stop at k = 1), nonfinite (‖Δw‖ or ζ not finite),
+    converged, and FLAG_NONE at the cap."""
+    members = rhs.shape[0]
+    dtype = rhs.dtype
+    h1h2 = jnp.float32(problem.h1 * problem.h2)
+    norm_w = h1h2 if problem.weighted_norm else jnp.float32(1.0)
+    cap = problem.iteration_cap
+
+    def member_sums(parts):
+        # Each member's strip partials, summed as the one-RHS loop sums
+        # its (nb, 1) partials.
+        return jax.vmap(jnp.sum)(parts.reshape(members, cv.nb, 1))
+
+    def body(s: _BatchedState) -> _BatchedState:
+        frozen = s.done | (s.k >= cap)
+        pn, ap, denom_part = batched_direction_and_stencil(
+            cv, jnp.where(frozen, 0.0, s.beta).astype(dtype), s.r, s.p,
+            cs, cw, g, interpret=interpret,
+        )
+        denom = member_sums(denom_part) * h1h2
+        degenerate = jnp.abs(denom) < _DENOM_TOL
+        alpha32 = jnp.where(degenerate, 0.0,
+                            s.zr / jnp.where(degenerate, 1.0, denom))
+        w, r, diff_part, zr_part = batched_fused_update(
+            cv, jnp.where(frozen, 0.0, alpha32).astype(dtype), pn, ap, sc2,
+            s.w, s.r, interpret=interpret,
+        )
+        diff = jnp.abs(alpha32) * jnp.sqrt(member_sums(diff_part) * norm_w)
+        zr = member_sums(zr_part) * h1h2
+        converged = diff < problem.delta
+        nonfinite = ~(jnp.isfinite(diff) & jnp.isfinite(zr))
+        flag = jnp.where(
+            degenerate, FLAG_BREAKDOWN,
+            jnp.where(nonfinite, FLAG_NONFINITE,
+                      jnp.where(converged, FLAG_CONVERGED, FLAG_NONE)),
+        ).astype(jnp.int32)
+
+        def keep(old, new):
+            return jnp.where(frozen, old, new)
+
+        return _BatchedState(
+            k=keep(s.k, s.k + 1),
+            done=keep(s.done, degenerate | converged | nonfinite),
+            w=w, r=r, p=pn,
+            zr=keep(s.zr, zr),
+            beta=zr / jnp.where(s.zr == 0.0, 1.0, s.zr),
+            diff=keep(s.diff, diff),
+            flag=keep(s.flag, flag),
+        )
+
+    def cond(s: _BatchedState):
+        return jnp.any((~s.done) & (s.k < cap))
+
+    zeros = jnp.zeros_like(rhs)
+    init = _BatchedState(
+        k=jnp.zeros((members,), jnp.int32),
+        done=jnp.zeros((members,), bool),
+        w=zeros, r=rhs, p=zeros,
+        zr=jax.vmap(lambda x: jnp.sum(x.astype(jnp.float32) ** 2))(rhs)
+        * h1h2,
+        beta=jnp.zeros((members,), jnp.float32),
+        diff=jnp.full((members,), jnp.inf, jnp.float32),
+        flag=jnp.full((members,), FLAG_NONE, jnp.int32),
+    )
+    s = lax.while_loop(cond, body, init)
+    M, N = problem.M, problem.N
+    y = s.w[:, HALO : HALO + M - 1, 1:N]
+    return PCGResult(
+        w=jnp.pad(y * sc_int, ((0, 0), (1, 1), (1, 1))),
+        iterations=s.k, diff=s.diff, residual_dot=s.zr, flag=s.flag,
+        max_iterations=jnp.max(s.k),
+    )
 
 
 # ---------------------------------------------------------------------------

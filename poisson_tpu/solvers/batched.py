@@ -36,6 +36,16 @@ whole batch. ``mesh=None`` (the default) keeps the single-device
 programs byte-for-byte. Executable families that have no sharded
 program yet (per-member geometries, MG, the in-loop integrity probe)
 are rejected loudly when combined with ``mesh=``.
+
+On a TPU, a plain batch on one operator (fp32 on the scaled system; no
+mesh, geometries, MG, block mode or integrity probe — see
+:func:`uses_fused_kernels`) runs instead on the member-axis Pallas
+kernels (``ops.pallas_cg._fused_solve_batched``): one ``while_loop`` of
+two fused sweeps over the (B, R, C) canvas stack, a stopped member
+frozen by α = β = 0 rather than by selects over its canvases. Member i
+is then the one-RHS fused solve (``pallas_cg_solve``) bit for bit, and
+the ``batched.fused.*`` counters say how often the path engages. Every
+other request, and every request off a TPU, keeps the XLA programs.
 """
 
 from __future__ import annotations
@@ -372,7 +382,10 @@ def solve_batched(problems=None, *, rhs_stack=None, rhs_gates=None,
     leading batch axis (``iterations`` is the per-member truth) plus the
     scalar ``max_iterations`` the fused loop actually ran.
 
-    ``dtype``/``scaled`` follow ``pcg_solve``'s precision policy.
+    ``dtype``/``scaled`` follow ``pcg_solve``'s precision policy. On a
+    TPU the plain fp32 scaled forms run on the member-axis Pallas
+    kernels (see the module docstring); members then reproduce
+    ``pallas_cg_solve`` rather than ``pcg_solve``.
 
     ``mesh`` (a :class:`jax.sharding.Mesh` from
     ``parallel.mesh.make_solver_mesh``) runs the whole bucket as ONE
@@ -497,6 +510,46 @@ class _Prepared(NamedTuple):
     verify_every: int
     v_tol: float
     verify_key: Optional[tuple]
+    # The fused path's (cv, cs, cw, g, sc2, sc_int); rhs_stack then holds
+    # (size, R, C) canvases. None on every XLA family.
+    canvases: Optional[tuple]
+
+
+def uses_fused_kernels(platform: str, dtype_name: str, scaled: bool, *,
+                       mesh=None, geometries=None, mg: bool = False,
+                       block: bool = False, verify_every: int = 0) -> bool:
+    """Whether a batch runs on the member-axis Pallas kernels
+    (``ops.pallas_cg._fused_solve_batched``) rather than the vmapped XLA
+    loop: on a TPU, in fp32 on the scaled system, with no mesh,
+    per-member geometries, MG preconditioner, block mode or in-loop
+    integrity probe — ``cli._pick_backend``'s rule for one solve. Every
+    input form (gates, a stack, a list of problems) qualifies."""
+    return (platform == "tpu" and dtype_name == "float32" and scaled
+            and mesh is None and geometries is None and not mg
+            and not block and verify_every == 0)
+
+
+def _platform() -> str:
+    return jax.devices()[0].platform
+
+
+def _fused_operands(problem: Problem, rhs_stack, gates):
+    """The fused path's coefficient canvases and its (B, R, C) right-hand
+    side canvases: the gated RHS canvas, exactly ``pallas_cg_solve
+    (rhs_gate=)``'s multiply, or a full-grid stack laid onto canvases."""
+    from poisson_tpu.ops.pallas_cg import (
+        batched_bm,
+        build_canvases,
+        grid_to_canvas,
+    )
+
+    cv, cs, cw, g, rhs, sc2, sc_int = build_canvases(problem,
+                                                     batched_bm(problem))
+    if gates is not None:
+        stack = rhs[None] * gates[:, None, None]
+    else:
+        stack = grid_to_canvas(problem, cv, rhs_stack)
+    return (cv, cs, cw, g, sc2, sc_int), stack
 
 
 def _prepare_batch(problems, *, rhs_stack, rhs_gates, dtype, scaled, mesh,
@@ -598,8 +651,13 @@ def _prepare_batch(problems, *, rhs_stack, rhs_gates, dtype, scaled, mesh,
         use_mg = True
     else:
         use_mg = False
+    fused = uses_fused_kernels(
+        _platform(), dtype_name, use_scaled, mesh=mesh,
+        geometries=geometries, mg=use_mg, block=use_block,
+        verify_every=int(verify_every))
     geo = setups = None
     a = b = aux = None
+    gates = None
     if geometries is not None:
         from poisson_tpu.geometry.dsl import parse_geometry
 
@@ -654,7 +712,7 @@ def _prepare_batch(problems, *, rhs_stack, rhs_gates, dtype, scaled, mesh,
                                    for p in member_problems])
             batch = len(member_problems)
     elif rhs_gates is not None:
-        if geo is None:
+        if geo is None and not fused:
             a, b, rhs, aux = host_setup(problem, dtype_name, use_scaled)
         gate_dt = jnp.dtype(dtype_name)
         if hasattr(rhs_gates, "ndim"):
@@ -675,7 +733,7 @@ def _prepare_batch(problems, *, rhs_stack, rhs_gates, dtype, scaled, mesh,
             setups = _geo_setups(problem, batch)
             rhs_stack = jnp.stack([s[2] for s in setups]
                                   ) * gates[:, None, None]
-        else:
+        elif not fused:
             # Per-member rhs * gate — elementwise, exactly pcg_solve's
             # rhs_gate multiply, so gated members stay bit-identical to
             # the sequential gated solve.
@@ -708,6 +766,13 @@ def _prepare_batch(problems, *, rhs_stack, rhs_gates, dtype, scaled, mesh,
             )
     else:
         origin = tuple(range(batch))
+
+    canvases = None
+    if fused:
+        # The gate form multiplies the problem's own RHS canvas; the other
+        # forms carry their RHS already, so only the operator is looked up.
+        canvases, rhs_stack = _fused_operands(
+            problem if gates is not None else jit_problem, rhs_stack, gates)
 
     if use_block:
         # Block dispatches compile at the EXACT batch size: a zero-RHS
@@ -747,7 +812,7 @@ def _prepare_batch(problems, *, rhs_stack, rhs_gates, dtype, scaled, mesh,
                   if verify_every > 0 else None)
     return _Prepared(problem, jit_problem, dtype_name, use_scaled, use_block,
                      use_mg, geo, setups, a, b, aux, rhs_stack, batch, size,
-                     origin, verify_every, v_tol, verify_key)
+                     origin, verify_every, v_tol, verify_key, canvases)
 
 
 def _launch_batch(prep: _Prepared, mesh, mg_config) -> PCGResult:
@@ -756,7 +821,18 @@ def _launch_batch(prep: _Prepared, mesh, mg_config) -> PCGResult:
     families also look up their shard blocks or level hierarchy here)."""
     (problem, jit_problem, dtype_name, use_scaled, use_block, use_mg, geo,
      setups, a, b, aux, rhs_stack, batch, size, _, verify_every, v_tol,
-     verify_key) = prep
+     verify_key, canvases) = prep
+    if canvases is not None:
+        from poisson_tpu.ops.pallas_cg import _fused_solve_batched
+
+        _count_bucket((size, jit_problem, dtype_name, use_scaled,
+                       ("fused",)), batch, size)
+        obs.inc("batched.fused.dispatches")
+        obs.inc("batched.fused.members", batch)
+        cv, cs, cw, g, sc2, sc_int = canvases
+        interpret = jax.devices()[0].platform != "tpu"
+        return _fused_solve_batched(jit_problem, cv, interpret, cs, cw, g,
+                                    rhs_stack, sc2, sc_int)
     if use_block:
         from poisson_tpu.krylov.block import _solve_block
 

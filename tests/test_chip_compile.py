@@ -110,6 +110,10 @@ def test_resident(one_chip):
     _assert_kernel(("resident",), lambda: _lower_resident(one_chip))
 
 
+def test_batched_fused(one_chip):
+    _assert_kernel(("batched",), lambda: _lower_batched(one_chip))
+
+
 def _stacked_args(spec, mesh):
     cv, shards = spec.cv, mesh.devices.size
     stacked = NamedSharding(mesh, P((X_AXIS, Y_AXIS)))
@@ -143,6 +147,20 @@ def _lower_resident(one_chip):
         problem, cv, False, *_canvases(cv, one_chip))
 
 
+def _lower_batched(one_chip):
+    """The batch cell's program: 64 right-hand sides at 400×600 on the
+    member-axis kernels, with the batched path's tight strips."""
+    problem = Problem(M=400, N=600)
+    cv = pallas_cg.canvas_spec(problem, pallas_cg.batched_bm(problem))
+    canvas, _, _, _, _ = _canvases(cv, one_chip)
+    stack = jax.ShapeDtypeStruct((64, cv.rows, cv.cols), jnp.float32,
+                                 sharding=one_chip)
+    sc_int = jax.ShapeDtypeStruct((problem.M - 1, problem.N - 1),
+                                  jnp.float32, sharding=one_chip)
+    return pallas_cg._fused_solve_batched.lower(
+        problem, cv, False, canvas, canvas, canvas, stack, canvas, sc_int)
+
+
 def _lower_fused_sharded(mesh):
     problem = Problem(M=2400, N=3200)
     spec = pallas_sharded.shard_spec(problem, 2, 2)
@@ -167,7 +185,7 @@ def test_ca_sharded(mesh):
 
 # Each lowering above, by the key its test compiles it under, and the
 # stable names of the kernels it holds: the fused pair serves the
-# one-chip and the sharded solve alike.
+# one-chip and the sharded solve alike, the batched pair the batches.
 NAMED = [
     (("fused", 800, 1200, False), "one_chip",
      lambda chip: _lower_fused(chip, 800, 1200, False),
@@ -178,6 +196,8 @@ NAMED = [
     (("ca", False), "one_chip", lambda chip: _lower_ca(chip, False),
      {"basis_sweep", "pair_update"}),
     (("resident",), "one_chip", _lower_resident, {"resident_solve"}),
+    (("batched",), "one_chip", _lower_batched,
+     {"batched_direction_and_stencil", "batched_fused_update"}),
     (("fused_sharded",), "mesh", _lower_fused_sharded,
      {"direction_and_stencil", "fused_update"}),
     (("ca_sharded",), "mesh", _lower_ca_sharded,
