@@ -279,7 +279,8 @@ def device_hierarchy(problem: Problem, dtype_name: str, scaled: bool,
     """The fingerprint-keyed device-resident hierarchy for ``problem``
     (+ optional :mod:`poisson_tpu.geometry` spec): host-fp64 build and
     dense coarsest factorisation paid once per domain, then cached —
-    ``mg.hierarchy_cache.{hits,misses}``."""
+    ``mg.hierarchy_cache.{hits,misses}``. A miss builds under the span
+    ``mg.hierarchy.build``."""
     from poisson_tpu import obs
 
     fp = None
@@ -294,16 +295,17 @@ def device_hierarchy(problem: Problem, dtype_name: str, scaled: bool,
         obs.inc("mg.hierarchy_cache.hits")
         return cached
     obs.inc("mg.hierarchy_cache.misses")
-    if geometry is None:
-        from poisson_tpu.solvers.pcg import host_fields64
+    with obs.span("mg.hierarchy.build"):
+        if geometry is None:
+            from poisson_tpu.solvers.pcg import host_fields64
 
-        a64, b64, _, _ = host_fields64(problem.with_(f_val=1.0), False)
-    else:
-        from poisson_tpu.geometry.canvas import build_geometry_fields
+            a64, b64, _, _ = host_fields64(problem.with_(f_val=1.0), False)
+        else:
+            from poisson_tpu.geometry.canvas import build_geometry_fields
 
-        a64, b64, _ = build_geometry_fields(problem, geometry)
-    host = build_hierarchy64(problem, a64, b64, config)
-    hier = _cast_levels(host, dtype_name, scaled)
+            a64, b64, _ = build_geometry_fields(problem, geometry)
+        host = build_hierarchy64(problem, a64, b64, config)
+        hier = _cast_levels(host, dtype_name, scaled)
     _HIERARCHIES[key] = hier
     obs.gauge("mg.levels", len(hier.levels))
     obs.gauge("mg.coarse_dense", 1 if hier.coarse_inv is not None else 0)

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from poisson_tpu import obs
 from poisson_tpu.config import Problem
 from poisson_tpu.models.fictitious_domain import build_fields
 from poisson_tpu.ops.stencil import (
@@ -725,6 +726,11 @@ def pcg_solve(problem: Problem, dtype=None, scaled=None,
     convergence-rate seam the ETA estimator reads. Same trace-time
     contract as ``stream_every``: 0 (the default) traces no callback
     and the program is byte-identical.
+
+    Runs under the span ``pcg_solve`` with the children ``.prepare``
+    (checks, set-up and hierarchy cache lookups, gate multiply),
+    ``.launch`` (the jitted call) and ``.finish`` (the ``mg.solves``
+    count): see :func:`poisson_tpu.obs.span`.
     """
     dtype_name = resolve_dtype(dtype)
     use_scaled = resolve_scaled(scaled, dtype_name)
@@ -732,43 +738,63 @@ def pcg_solve(problem: Problem, dtype=None, scaled=None,
     history_every = int(history_every)
     tol = (resolve_verify_tol(verify_tol, dtype_name)
            if verify_every > 0 else 0.0)
-    if preconditioner not in (None, "jacobi"):
-        from poisson_tpu import obs
-        from poisson_tpu.mg import (
-            DEFAULT_MG,
-            resolve_preconditioner,
-            validate_mg_problem,
-        )
-        from poisson_tpu.mg.preconditioner import _solve_mg, mg_solve_setup
+    use_mg = preconditioner not in (None, "jacobi")
+    with obs.span("pcg_solve"):
+        with obs.span("pcg_solve.prepare"):
+            if use_mg:
+                cfg, (a, b, rhs, aux, hier) = _mg_prepare(
+                    problem, dtype_name, use_scaled, geometry,
+                    preconditioner, mg_config, verify_abft, history_every)
+            else:
+                a, b, rhs, aux = solve_setup(problem, dtype_name,
+                                             use_scaled, geometry=geometry)
+            if rhs_gate is not None:
+                rhs = rhs * jnp.asarray(rhs_gate, rhs.dtype)
+        with obs.span("pcg_solve.launch"):
+            if use_mg:
+                from poisson_tpu.mg.preconditioner import _solve_mg
 
-        resolve_preconditioner(preconditioner)   # raises on unknown
-        cfg = mg_config or DEFAULT_MG
-        validate_mg_problem(problem, cfg)
-        if verify_abft:
-            raise ValueError(
-                "verify_abft is wired for the jacobi path only; drop it "
-                "or use preconditioner='jacobi'"
-            )
-        if history_every > 0:
-            raise ValueError(
-                "history_every is wired for the jacobi path only; drop "
-                "it or use preconditioner='jacobi'"
-            )
-        a, b, rhs, aux, hier = mg_solve_setup(
-            problem, dtype_name, use_scaled, geometry=geometry,
-            config=cfg)
-        if rhs_gate is not None:
-            rhs = rhs * jnp.asarray(rhs_gate, rhs.dtype)
-        obs.inc("mg.solves")
-        return _solve_mg(problem, use_scaled, cfg, int(stream_every),
-                         verify_every, tol, a, b, rhs, aux, hier)
-    a, b, rhs, aux = solve_setup(problem, dtype_name, use_scaled,
-                                 geometry=geometry)
-    if rhs_gate is not None:
-        rhs = rhs * jnp.asarray(rhs_gate, rhs.dtype)
-    return _solve(problem, use_scaled, int(stream_every), verify_every,
-                  tol, bool(verify_abft and verify_every > 0),
-                  history_every, a, b, rhs, aux)
+                result = _solve_mg(problem, use_scaled, cfg,
+                                   int(stream_every), verify_every, tol,
+                                   a, b, rhs, aux, hier)
+            else:
+                result = _solve(problem, use_scaled, int(stream_every),
+                                verify_every, tol,
+                                bool(verify_abft and verify_every > 0),
+                                history_every, a, b, rhs, aux)
+        with obs.span("pcg_solve.finish"):
+            if use_mg:
+                obs.inc("mg.solves")
+    return result
+
+
+def _mg_prepare(problem: Problem, dtype_name: str, use_scaled: bool,
+                geometry, preconditioner, mg_config, verify_abft: bool,
+                history_every: int):
+    """(cycle config, (a, b, rhs, aux, hierarchy)) of an MG solve, after
+    the checks that refuse what the MG path does not wire."""
+    from poisson_tpu.mg import (
+        DEFAULT_MG,
+        resolve_preconditioner,
+        validate_mg_problem,
+    )
+    from poisson_tpu.mg.preconditioner import mg_solve_setup
+
+    resolve_preconditioner(preconditioner)   # raises on unknown
+    cfg = mg_config or DEFAULT_MG
+    validate_mg_problem(problem, cfg)
+    if verify_abft:
+        raise ValueError(
+            "verify_abft is wired for the jacobi path only; drop it "
+            "or use preconditioner='jacobi'"
+        )
+    if history_every > 0:
+        raise ValueError(
+            "history_every is wired for the jacobi path only; drop "
+            "it or use preconditioner='jacobi'"
+        )
+    return cfg, mg_solve_setup(problem, dtype_name, use_scaled,
+                               geometry=geometry, config=cfg)
 
 
 def iteration_program(problem: Problem, dtype=None, scaled=None,

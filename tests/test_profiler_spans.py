@@ -116,15 +116,25 @@ def _batched():
     return lambda: solve_batched(PROBLEM, rhs_gates=[1.0, 0.5, 2.0])
 
 
+def _xla(preconditioner):
+    from poisson_tpu.solvers.pcg import pcg_solve
+
+    return lambda: pcg_solve(PROBLEM, dtype="float32", rhs_gate=1.0,
+                             preconditioner=preconditioner)
+
+
 ENTRIES = [
     ("pallas_cg_solve", _pallas, ("prepare", "launch", "finish")),
     ("pallas_cg_solve_sharded", _pallas_sharded, ("prepare", "launch")),
     ("solve_batched", _batched, ("prepare", "launch", "finish")),
+    ("pcg_solve", lambda: _xla("jacobi"), ("prepare", "launch", "finish")),
+    ("pcg_solve", lambda: _xla("mg"), ("prepare", "launch", "finish")),
 ]
+IDS = ["pallas_cg_solve", "pallas_cg_solve_sharded", "solve_batched",
+       "pcg_solve-jacobi", "pcg_solve-mg"]
 
 
-@pytest.mark.parametrize("entry,make,phases", ENTRIES,
-                         ids=[e[0] for e in ENTRIES])
+@pytest.mark.parametrize("entry,make,phases", ENTRIES, ids=IDS)
 def test_entry_spans_its_phases_in_order(tmp_path, entry, make, phases):
     solve = make()
     jax.block_until_ready(solve().w)       # compile outside the trace
