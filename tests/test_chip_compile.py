@@ -240,10 +240,10 @@ def _lower_mg(one_chip, M, N):
 
 
 def test_mg_solve_takes_no_gather(one_chip):
-    """The V-cycle's restriction takes every other node by strided slices:
-    jnp's step indexing would lower to gathers, which a v5e ran at under
-    1 GB/s (77% of a 6400x9600 MG solve's device time). Every level keeps
-    its ``mg_level`` tag through the TPU compiler."""
+    """The V-cycle's restriction takes every other node by a strided slice
+    and a reshape: jnp's step indexing would lower to gathers, which a v5e
+    ran at under 1 GB/s (77% of a 6400x9600 MG solve's device time). Every
+    level keeps its ``mg_level`` tag through the TPU compiler."""
     text = _compiled(("mg", 400, 600), lambda: _lower_mg(one_chip, 400, 600))
     assert " gather(" not in text
     from poisson_tpu.mg import plan_levels

@@ -19,6 +19,7 @@ The contract under test, layer by layer:
 """
 
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -201,6 +202,95 @@ def test_vcycle_apply_bit_parity_under_vmap():
     stacked = jax.jit(jax.vmap(f))(jnp.stack([rhs * g for g in gates]))
     for member, g in zip(stacked, gates):
         assert _max_ulps(member, jax.jit(f)(rhs * g)) <= 4
+
+
+# -- the transfer pair --------------------------------------------------
+
+
+def _full_weighting64(r):
+    """The 9-point 1/16·[1 2 1; 2 4 2; 1 2 1] stencil at every interior
+    coarse node (fine node (2I, 2J)), in NumPy float64, ring zero."""
+    r = np.asarray(r, np.float64)
+    m, n = (r.shape[-2] - 1) // 2, (r.shape[-1] - 1) // 2
+    out = np.zeros(r.shape[:-2] + (m + 1, n + 1))
+    weights = np.outer([1.0, 2.0, 1.0], [1.0, 2.0, 1.0]) / 16.0
+    for di in (-1, 0, 1):
+        for dj in (-1, 0, 1):
+            out[..., 1:m, 1:n] += weights[di + 1, dj + 1] * r[
+                ..., 2 + di:2 * m - 1 + di:2, 2 + dj:2 * n - 1 + dj:2]
+    return out
+
+
+def _interior_field(shape, rng, dtype=np.float64, low=-1.0):
+    """A random field on ``shape`` (…, rows, cols) with a zero ring."""
+    u = np.zeros(shape, dtype)
+    u[..., 1:-1, 1:-1] = rng.uniform(low, 1.0, shape[:-2] + (
+        shape[-2] - 2, shape[-1] - 2))
+    return u
+
+
+@pytest.mark.parametrize("m,n", [(6, 9), (13, 8)])
+def test_restriction_is_nine_point_full_weighting(m, n):
+    """``restrict_full_weighting`` on a stack of non-square grids is the
+    9-point full-weighting stencil: to 1e-14 relative in float64, and
+    within a few float32 ulps in float32 (positive data, so no sum
+    cancels)."""
+    from poisson_tpu.mg import restrict_full_weighting
+
+    rng = np.random.default_rng(m * 100 + n)
+    r = _interior_field((2, 2 * m + 1, 2 * n + 1), rng)
+    got = np.asarray(restrict_full_weighting(jnp.asarray(r)))
+    want = _full_weighting64(r)
+    assert got.shape == want.shape == (2, m + 1, n + 1)
+    assert np.abs(got - want).max() <= 1e-14 * np.abs(want).max()
+
+    r32 = _interior_field((2, 2 * m + 1, 2 * n + 1), rng, np.float32,
+                          low=0.5)
+    got32 = restrict_full_weighting(jnp.asarray(r32))
+    assert got32.dtype == jnp.float32
+    assert _max_ulps(got32, _full_weighting64(r32)) <= 3
+
+
+@pytest.mark.parametrize("m,n", [(6, 9), (13, 8)])
+def test_restriction_is_quarter_prolongation_transpose(m, n):
+    """R = ¼·Pᵀ against ``prolong_bilinear``: ⟨R r, e⟩ = ¼⟨r, P e⟩ over
+    the interiors, in float64 — the symmetry the V-cycle's SPD property
+    rests on."""
+    from poisson_tpu.mg import prolong_bilinear, restrict_full_weighting
+
+    rng = np.random.default_rng(m * 100 + n + 1)
+    for _ in range(3):
+        r = _interior_field((2, 2 * m + 1, 2 * n + 1), rng)
+        e = _interior_field((2, m + 1, n + 1), rng)
+        lhs = np.sum(np.asarray(restrict_full_weighting(jnp.asarray(r)))
+                     * e, axis=(-2, -1))
+        rhs = 0.25 * np.sum(r * np.asarray(prolong_bilinear(jnp.asarray(e))),
+                            axis=(-2, -1))
+        np.testing.assert_allclose(lhs, rhs, rtol=1e-12, atol=0)
+
+
+def test_restriction_lowers_to_two_strided_slices():
+    """The restriction is separable: the lowered program of a (2, 201,
+    301) float32 stack holds no gather (jnp step indexing; a v5e runs
+    one at 0.24 GB/s) and at most two slices with a stride (the
+    unfactored stencil took nine stride-(2, 2) slices), none of them
+    along the row axis, which XLA puts on a TPU's vector lanes."""
+    from poisson_tpu.mg import restrict_full_weighting
+
+    text = jax.jit(restrict_full_weighting).lower(
+        jax.ShapeDtypeStruct((2, 201, 301), jnp.float32)).as_text()
+    assert "gather" not in text
+    slices = [line for line in text.splitlines() if "stablehlo.slice" in line]
+    assert slices
+    strided = []         # per strided slice, the axes it strides
+    for line in slices:
+        spans = re.search(r"\[([^\]]*)\]", line).group(1).split(",")
+        axes = [i - len(spans) for i, s in enumerate(spans)
+                if s.count(":") == 2 and int(s.split(":")[2]) != 1]
+        if axes:
+            strided.append(axes)
+    assert 1 <= len(strided) <= 2, slices
+    assert all(axes == [-1] for axes in strided), strided
 
 
 def test_mg_solves_same_problem_as_jacobi():
