@@ -15,9 +15,11 @@ Three layers, one gate (``python -m poisson_tpu.contracts``):
   real entry points, canonicalized, fingerprinted, and checked
   (structure + fingerprint) against the committed ``ledger.json``.
 - :mod:`~poisson_tpu.contracts.drift` — registry drift detection:
-  bench ``detail.*`` keys must join the regress cohort key or be
-  declared attribution-only; every ``ServicePolicy``/``FleetPolicy``
-  field needs a chaos drill or a written exemption.
+  every ``ServicePolicy``/``FleetPolicy`` field needs a chaos drill or
+  a written exemption.
+
+The gate reads and lints only ``poisson_tpu/``; nothing outside the
+package is an input to it.
 
 README "Program contracts" documents the rule table, the suppression
 syntax, and the ledger-update workflow.

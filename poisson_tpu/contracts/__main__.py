@@ -21,8 +21,7 @@ Exit 0 iff no unsuppressed finding and no ledger problem. Flags:
 
 The run also stamps ``contracts.findings`` / ``contracts.suppressed`` /
 ``contracts.rules`` gauges into the metrics registry so embedding
-callers (``bench.py``, ``obs.selfcheck``) surface drift through the
-Prometheus exposition.
+callers surface drift through the Prometheus exposition.
 """
 
 from __future__ import annotations

@@ -2,12 +2,11 @@
 
 The repo's correctness discipline is a set of *program contracts* —
 flag-off paths lower to byte-identical HLO, host callbacks stay gated,
-every counter name is documented, dispatch dimensions join the regress
-cohort — but contracts enforced only by runtime byte-pin assertions
-fire *after* the drift shipped. This module is the gate that fires
-*before*: a stdlib-``ast`` pass (deliberately **no jax import** — the
-lint must run anywhere, instantly, including inside the stdlib-only
-regression sentinel) over the package source with repo-specific rules:
+every counter name is documented — but contracts enforced only by
+runtime byte-pin assertions fire *after* the drift shipped. This module
+is the gate that fires *before*: a stdlib-``ast`` pass (deliberately
+**no jax import** — the lint must run anywhere, instantly) over the
+package source (``poisson_tpu/`` only) with repo-specific rules:
 
 ====================  ==================================================
 rule id               contract
@@ -129,12 +128,12 @@ _SOLVER_SCOPE = (
 )
 
 # Purity scope (wallclock/rng): solver math modules. Exempt by path:
-# selfcheck smoke drivers (host-side harnesses), the watchdog (its whole
+# the MG smoke check (a host-side harness), the watchdog (its whole
 # job is wall-clock supervision of the solve from OUTSIDE the trace),
 # multihost init (retry backoff timing is host-side by construction),
 # and the stream sink's host half (it timestamps samples AFTER the
 # gated callback has already left the device).
-_PURITY_EXEMPT = ("selfcheck", "parallel/watchdog.py",
+_PURITY_EXEMPT = ("mg/selfcheck.py", "parallel/watchdog.py",
                   "parallel/multihost.py", "obs/stream.py")
 
 _HOST_CALLBACKS = {
@@ -693,25 +692,17 @@ RULES = (
     "fingerprint-key", "suppression-reason",
 )
 
-_SCAN_ROOTS = ("poisson_tpu", "benchmarks")
-_SCAN_FILES = ("bench.py",)
+_SCAN_ROOT = "poisson_tpu"
 _SKIP_PARTS = ("__pycache__",)
 
 
 def _iter_sources(root: str):
-    for top in _SCAN_ROOTS:
-        base = os.path.join(root, top)
-        if not os.path.isdir(base):
-            continue
-        for dirpath, dirnames, filenames in os.walk(base):
-            dirnames[:] = [d for d in dirnames if d not in _SKIP_PARTS]
-            for fname in sorted(filenames):
-                if fname.endswith(".py"):
-                    yield os.path.join(dirpath, fname)
-    for fname in _SCAN_FILES:
-        path = os.path.join(root, fname)
-        if os.path.isfile(path):
-            yield path
+    base = os.path.join(root, _SCAN_ROOT)
+    for dirpath, dirnames, filenames in os.walk(base):
+        dirnames[:] = [d for d in dirnames if d not in _SKIP_PARTS]
+        for fname in sorted(filenames):
+            if fname.endswith(".py"):
+                yield os.path.join(dirpath, fname)
 
 
 def _build_context(root: str) -> dict:

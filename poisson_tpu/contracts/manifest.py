@@ -25,9 +25,9 @@ ledger records both, and the check reports an environment mismatch
 distinctly from genuine drift so a CPU ledger is never silently
 "confirmed" by a TPU run.
 
-Also here: the registry-drift allowlists (``ATTRIBUTION_ONLY_DETAIL``,
-``POLICY_COVERAGE_EXEMPT``) — every exemption carries a reason string,
-mirroring the lint's suppression contract.
+Also here: the registry-drift allowlist ``POLICY_COVERAGE_EXEMPT`` —
+every exemption carries a reason string, mirroring the lint's
+suppression contract.
 """
 
 from __future__ import annotations
@@ -523,128 +523,7 @@ def run_ledger_check(update: bool = False,
     return report
 
 
-# -- registry-drift allowlists (reason strings required) ---------------
-
-# bench.py detail keys that are deliberately attribution/diagnosis
-# payload, NOT experiment identity — everything else a bench mode emits
-# must join benchmarks/regress.py's cohort key (see contracts.drift).
-ATTRIBUTION_ONLY_DETAIL = {
-    # measurement payload & derived readings
-    "iterations": "the measured quantity, not identity",
-    "iterations_match_sequential": "parity verdict on the measurement",
-    "converged": "outcome tally of the measurement",
-    "batch_seconds": "raw timing payload",
-    "sequential_solve_seconds": "raw timing payload",
-    "first_run_seconds": "compile-time payload",
-    "solve_seconds": "raw timing payload",
-    "warmup_seconds": "compile-time payload",
-    "makespan_seconds": "raw timing payload",
-    "p50_seconds": "latency payload (p99 is the record's own metric)",
-    "p99_seconds": "latency payload",
-    "forecast_calibration_err_pct":
-        "measured forecaster error, not identity — records_from_result "
-        "lifts it into its own obs.forecast.calibration_err_pct "
-        "sentinel record (lower-is-better), it never splits the "
-        "primary record's cohort",
-    "verify_overhead": "the A/B delta is the record's payload",
-    "preconditioner_ab": "both-arm A/B payload (cohort key carries "
-                         "detail.preconditioner)",
-    # request-mix tallies (outcomes, not offered-load identity)
-    "requests": "offered count; arrival_rate is the identity",
-    "completed": "outcome tally",
-    "errors": "outcome tally",
-    "shed": "outcome tally",
-    "lost": "invariant check (bench exits 1 when nonzero)",
-    "quarantines": "churn outcome tally",
-    "device_losses": "churn outcome tally",
-    "placement_rebinds": "churn outcome tally",
-    "kill_fired": "whether the injected fault actually fired (fault_"
-                  "load is relabeled clean when it did not)",
-    "kill_worker_at": "fault timing detail under fault_load",
-    "kill_device_at": "fault timing detail under fault_load",
-    "scheduling": "engine name is carried by the metric itself "
-                  "(sustained vs drain gauges)",
-    "batch": "solve_batched pads to detail.bucket; grid+bucket are "
-             "the executable identity",
-    "bucket": "executable width, derivable from batch; grid is the "
-              "cohort axis",
-    "geometry_fingerprints": "operand identity, never cohort identity "
-                             "(the PR 9 invariant)",
-    "geom_cache_hits": "cache telemetry snapshot",
-    "geom_cache_misses": "cache telemetry snapshot",
-    "bucket_cache_hits": "cache telemetry snapshot",
-    "bucket_cache_misses": "cache telemetry snapshot",
-    "refill_splices": "refill telemetry snapshot",
-    "warmed_buckets": "warm-up inventory",
-    "device_kind": "device_topology/devices carry the cohort "
-                   "topology; kind is diagnosis",
-    "placement": "registry snapshot payload",
-    "p99_exemplar": "flight-recorder trace id (pinned attribution-only "
-                    "by tests/test_flight.py)",
-    "slowest_requests": "flight-recorder decompositions (pinned "
-                        "attribution-only by tests/test_flight.py)",
-    # A/B second-arm payload: the record's value/cohort is the
-    # continuous arm; the drain arm rides along for the comparison.
-    "continuous_beats_drain": "A/B verdict over both arms",
-    "drain_solves_per_sec": "drain-arm payload (its own gauge exists)",
-    "drain_p50_seconds": "drain-arm latency payload",
-    "drain_p99_seconds": "drain-arm latency payload",
-    "drain_makespan_seconds": "drain-arm timing payload",
-    "idle_lane_steps": "refill telemetry snapshot",
-    # fleet-churn outcome tallies and invariant verdicts
-    "device_loss_fired": "whether the injected loss actually fired "
-                         "(fault_load relabels clean when not)",
-    "every_request_accounted": "ledger-invariant verdict (bench exits "
-                               "1 when false)",
-    "recovered_requests": "churn outcome tally",
-    "restarts": "churn outcome tally",
-    "sticky_hits": "routing telemetry snapshot",
-    # single-solve / verify-A/B measurement payload
-    "final_diff": "convergence payload of the measurement",
-    "l2_error_vs_analytic": "accuracy payload of the measurement",
-    "serial_reduce": "timing-methodology note",
-    "iterations_baseline": "unverified-arm payload of the A/B record",
-    # Krylov-memory A/B and repeat-fingerprint payload (cohort key
-    # carries detail.krylov_mode / detail.deflation /
-    # detail.repeat_fingerprint)
-    "krylov_block_ab": "both-arm A/B payload (cohort key carries "
-                       "detail.krylov_mode)",
-    "cold_requests": "arm-size tally of the one run",
-    "warm_requests": "arm-size tally of the one run",
-    "cold_p50_seconds": "cold-arm latency payload (the record's value "
-                        "is the run's sustained throughput)",
-    "cold_p99_seconds": "cold-arm latency payload",
-    "warm_p50_seconds": "warm-arm latency payload",
-    "warm_p99_seconds": "warm-arm latency payload",
-    "krylov_hit_rate": "basis-cache telemetry snapshot",
-    "krylov_harvests": "basis-cache telemetry snapshot",
-    "krylov_iterations_saved": "basis-cache telemetry snapshot",
-    "krylov_fallbacks": "basis-cache telemetry snapshot",
-    "deflated_bytes_per_iter_model": "analytic cost-model reading "
-                                     "(obs.costs.krylov_deflated_cost)",
-    # durable-session A/B payload (cohort key carries detail.session /
-    # detail.warm_start; detail.steps is run length, not identity —
-    # steps/sec already normalizes by it)
-    "steps": "run length; the per-step rate is the record's value",
-    "session_ab": "both-arm A/B payload (cohort key carries "
-                  "detail.session and detail.warm_start)",
-    # backend-router attribution (cohort split rides on
-    # detail.routed_backend, which regress.py lifts into the key)
-    "router": "decision-mix / sentinel / measured-fraction / roofline-"
-              "calibration snapshot; detail.routed_backend is the "
-              "cohort discriminator regress.py lifts",
-    # serve-mode latency/throughput payload beside the record's value
-    "p95_seconds": "latency payload",
-    "shed_rate": "outcome-rate payload (its own gauge exists)",
-    "throughput_rps": "derived reading of the same run",
-    "wall_seconds": "raw timing payload",
-    # mixed-tenant attribution (cohort split rides on
-    # detail.tenant_mix, which regress.py lifts into the key)
-    "tenants": "per-tenant p99/shed-rate/share attribution block; "
-               "detail.tenant_mix is the cohort discriminator "
-               "regress.py lifts",
-    "tenant_promotions": "fair-queue telemetry snapshot",
-}
+# -- registry-drift allowlist (reason strings required) ----------------
 
 # ServicePolicy/FleetPolicy fields a chaos scenario need not exercise —
 # each with the reason it is exempt. Everything else must appear in at

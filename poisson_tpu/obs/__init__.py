@@ -49,8 +49,7 @@ Everything degrades to near-zero-cost no-ops when unconfigured:
 ``obs.span`` is a bare profiler annotation (a check while no profiler
 session runs; it writes no file), ``obs.event`` drops the record,
 counters still count (a locked dict add), streaming is not even traced
-into the program. ``python -m poisson_tpu.obs.selfcheck`` smoke-
-tests the whole round trip.
+into the program.
 """
 
 from __future__ import annotations

@@ -160,9 +160,6 @@ Naming convention (dotted, low cardinality):
   the ledger (``ServicePolicy.dedup``): a client retry or replayed
   submit whose ``request_id`` was already seen returns the original
   outcome instead of double-admitting;
-- ``selfcheck.runs`` — ``python -m poisson_tpu.obs.selfcheck``
-  executions (one per run; the smoke command counts itself so its own
-  snapshot artifacts are never empty);
 - the ``mg`` family — the geometric multigrid preconditioner
   (:mod:`poisson_tpu.mg`, ``preconditioner="mg"``): ``mg.solves``
   counts MG-preconditioned solves dispatched (batched members count

@@ -943,8 +943,3 @@ def _finish_batch(prep: _Prepared, result: PCGResult) -> PCGResult:
         max_iterations=jnp.max(result.iterations[:batch]),
         origin=origin,
     )
-
-
-# Smoke check: ``python -m poisson_tpu.solvers.batched_selfcheck`` (its own
-# module so runpy never re-executes this one, which the package __init__
-# already imports).

@@ -273,13 +273,6 @@ def test_iterations_scalar_helper():
     assert iterations_scalar(np.array([3, 9, 5])) == 9
 
 
-def test_selfcheck_smoke(capsys):
-    from poisson_tpu.solvers.batched_selfcheck import run_selfcheck
-
-    assert run_selfcheck() == 0
-    assert "batched selfcheck OK" in capsys.readouterr().out
-
-
 def test_cli_solve_batched_json(capsys):
     from poisson_tpu.cli import main
 

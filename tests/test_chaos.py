@@ -6,7 +6,7 @@ import json
 
 import pytest
 
-from poisson_tpu.obs import metrics
+from poisson_tpu.obs import export, metrics
 from poisson_tpu.testing import chaos
 
 pytestmark = pytest.mark.serve
@@ -15,6 +15,59 @@ pytestmark = pytest.mark.serve
 # survival properties in at least one scenario.
 REQUIRED = ("breaker-trip", "deadline-mid-chunk", "poison-requeue",
             "overload-shed")
+
+# Each subsystem's headline metrics, as the scrape sees them after the
+# scenario that drills that subsystem.
+FAMILY_METRICS = {
+    "overload-shed": ("poisson_tpu_serve_admitted",
+                      'poisson_tpu_serve_latency_seconds{quantile="0.99"}'),
+    "refill-poison-splice": ("poisson_tpu_serve_refill_splices",
+                             "poisson_tpu_serve_refill_retired_lanes"),
+    "fleet-worker-kill-mid-dispatch": (
+        "poisson_tpu_serve_fleet_quarantines",
+        "poisson_tpu_serve_fleet_recovered_requests"),
+    "journal-crash-replay": ("poisson_tpu_serve_journal_records",),
+    "geometry-mixed-cobatch": ("poisson_tpu_geom_cache_hits",
+                               "poisson_tpu_geom_cache_misses"),
+    "sdc-verified-restart": (
+        "poisson_tpu_integrity_detections",
+        "poisson_tpu_integrity_verified_restarts",
+        "poisson_tpu_serve_integrity_detections",
+        "poisson_tpu_serve_integrity_suspect_cohorts"),
+    "device-loss-mid-dispatch": ("poisson_tpu_serve_fleet_device_losses",
+                                 "poisson_tpu_serve_placement_rebinds",
+                                 "poisson_tpu_serve_placement_epoch"),
+    "deflation-stale-basis": ("poisson_tpu_krylov_cache_hits",
+                              "poisson_tpu_krylov_cache_misses",
+                              "poisson_tpu_krylov_harvests",
+                              "poisson_tpu_krylov_warm_solves",
+                              "poisson_tpu_krylov_iterations_saved"),
+    "session-stale-warm-start": ("poisson_tpu_session_opens",
+                                 "poisson_tpu_session_steps",
+                                 "poisson_tpu_session_warm_hits",
+                                 "poisson_tpu_session_closes",
+                                 "poisson_tpu_session_slo_good"),
+    "forecast-predicted-shed": (
+        "poisson_tpu_obs_forecast_predictions",
+        "poisson_tpu_obs_forecast_cold_cohorts",
+        "poisson_tpu_obs_forecast_calibration_err_pct",
+        "poisson_tpu_serve_forecast_admission_checks",
+        "poisson_tpu_serve_shed_predicted_deadline"),
+    "router-mispredict-downshift": (
+        "poisson_tpu_serve_router_decisions",
+        "poisson_tpu_serve_router_cold_decisions",
+        "poisson_tpu_serve_router_chosen_xla",
+        "poisson_tpu_obs_roofline_observations",
+        "poisson_tpu_obs_roofline_fraction"),
+    "tenant-noisy-neighbor": ("poisson_tpu_serve_tenant_quota_sheds",
+                              "poisson_tpu_serve_shed_quota_exceeded",
+                              "poisson_tpu_serve_tenant_promotions",
+                              "poisson_tpu_serve_tenant_share_victim"),
+    "tenant-retry-storm": (
+        "poisson_tpu_serve_tenant_retry_exhausted",
+        "poisson_tpu_serve_tenant_dispatches_poison",
+        "poisson_tpu_serve_tenant_retry_tokens_poison"),
+}
 
 
 @pytest.fixture(autouse=True)
@@ -42,6 +95,14 @@ def test_scenario_green_with_invariant(name):
                   + snap.get("serve.shed", 0))
     assert admitted - terminated == 0
     assert report["invariant"]["lost"] == 0
+    # Every counter survives the Prometheus exposition with its value,
+    # and the drilled subsystem's headline metrics are in the scrape.
+    parsed = export.parse_text(export.render(report["metrics_snapshot"]))
+    for counter, value in snap.items():
+        assert parsed[export.metric_name(counter)] == {
+            "type": "counter", "value": float(value)}, counter
+    missing = set(FAMILY_METRICS.get(name, ())) - set(parsed)
+    assert not missing, missing
 
 
 def test_campaign_is_deterministic_under_a_seed():

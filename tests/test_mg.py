@@ -541,6 +541,22 @@ def test_hierarchy_cache_counters():
     assert metrics.get("mg.hierarchy_cache.hits") == 2
 
 
+def test_mg_counters_survive_exposition():
+    from poisson_tpu.obs import export, metrics
+
+    metrics.reset()
+    reset_hierarchy_cache()
+    p = Problem(M=40, N=40)
+    pcg_solve(p, preconditioner="mg")
+    pcg_solve(p, preconditioner="mg")      # the second build is a hit
+    parsed = export.parse_text(export.render())
+    assert parsed["poisson_tpu_mg_solves"] == {"type": "counter",
+                                               "value": 2.0}
+    assert parsed["poisson_tpu_mg_hierarchy_cache_misses"]["value"] == 1
+    assert parsed["poisson_tpu_mg_hierarchy_cache_hits"]["value"] >= 1
+    assert parsed["poisson_tpu_mg_levels"]["type"] == "gauge"
+
+
 def test_mg_vcycle_cost_model():
     from poisson_tpu.obs import metrics
     from poisson_tpu.obs.costs import mg_vcycle_cost

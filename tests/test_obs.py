@@ -358,7 +358,7 @@ def test_watchdog_diagnostics_carry_monotonic_and_recent_events(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# CLI end to end (the PR acceptance command) + selfcheck
+# CLI end to end (the PR acceptance command) + configured round trip
 # ---------------------------------------------------------------------------
 
 
@@ -423,11 +423,24 @@ def test_cli_telemetry_off_leaves_no_recorder(capsys):
     )["iterations"] == 50
 
 
-def test_selfcheck_round_trip(tmp_path, capsys):
-    from poisson_tpu.obs.selfcheck import main as selfcheck_main
+def test_configured_solve_writes_stream_file_and_counts(tmp_path):
+    """configure → a streamed solve through the report path → finalize:
+    the per-rank stream file holds samples at the configured stride and
+    the metrics snapshot counted the converged solve."""
+    from poisson_tpu.utils.timing import solve_report
 
-    assert selfcheck_main(["--dir", str(tmp_path / "sc")]) == 0
-    assert "obs selfcheck OK" in capsys.readouterr().out
+    mpath = str(tmp_path / "m.json")
+    rec = obs.configure(trace_dir=str(tmp_path), metrics_path=mpath,
+                        stream_every=5)
+    p = Problem(M=40, N=40)
+    res = pcg_solve(p, stream_every=5)
+    solve_report(p, res, 0.1, compile_seconds=0.0, dtype="float64")
+    obs.finalize()
+    with open(tmp_path / f"stream-rank{rec.rank}.jsonl") as f:
+        samples = [json.loads(line) for line in f if line.strip()]
+    assert [s["k"] for s in samples] == list(range(5, 51, 5))
+    with open(mpath) as f:
+        assert json.load(f)["counters"]["pcg.solves.converged"] == 1
 
 
 def test_forensics_report_renders(tmp_path, capsys):

@@ -152,6 +152,8 @@ def test_snapshot_roundtrip_and_torn_audibility(tmp_path):
     assert not torn.load(path)
     assert torn.backend_fraction("xla") is None
     assert metrics.get("obs.roofline.snapshot.torn") == 1
+    assert export.parse_text(export.render())[
+        "poisson_tpu_obs_roofline_snapshot_torn"]["value"] == 1
     # a missing snapshot is silent (cold start, not an incident)
     fresh = RooflineModel()
     assert not fresh.load(str(tmp_path / "absent.json"))
@@ -351,6 +353,31 @@ def test_routed_service_splits_cohort_and_serves_all():
     assert st["lost"] == 0
     assert st["router"]["decisions"] == 1
     assert st["router"]["chosen"] == {BACKEND_RESIDENT: 1}
+
+
+def test_routed_service_measures_xla_under_the_real_clock():
+    """Under the real clock every dispatch is measurable: an xla-only
+    routed service makes one decision per drain, measures a positive
+    roofline fraction, and the router/roofline metrics reach the
+    scrape."""
+    svc = SolveService(ServicePolicy(capacity=16, router=RouterPolicy()),
+                       seed=0)
+    for k in range(3):
+        assert svc.submit(SolveRequest(request_id=f"rt{k}",
+                                       problem=P40)) is None
+        assert all(o.converged for o in svc.drain())
+    st = svc.stats()
+    assert st["lost"] == 0 and st["router"]["chosen"] == {"xla": 3}
+    assert metrics.get("serve.router.decisions") == 3
+    assert metrics.get("obs.roofline.observations") >= 1
+    assert svc._roofline.backend_fraction("xla") > 0.0
+    parsed = export.parse_text(export.render())
+    for name in ("poisson_tpu_serve_router_decisions",
+                 "poisson_tpu_serve_router_cold_decisions",
+                 "poisson_tpu_serve_router_chosen_xla",
+                 "poisson_tpu_obs_roofline_observations",
+                 "poisson_tpu_obs_roofline_fraction"):
+        assert name in parsed, name
 
 
 def test_routed_mixed_run_spans_backends_zero_lost():

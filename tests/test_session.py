@@ -26,7 +26,7 @@ import pytest
 from poisson_tpu import obs
 from poisson_tpu.config import Problem
 from poisson_tpu.geometry import Ellipse, Rectangle
-from poisson_tpu.obs import flight, metrics
+from poisson_tpu.obs import export, flight, metrics
 from poisson_tpu.obs.trace import load_events
 from poisson_tpu.serve import (
     OUTCOME_RESULT,
@@ -261,6 +261,12 @@ def test_recovery_replays_to_the_committed_step_boundary(tmp_path):
     assert metrics.get("session.warm.fallbacks") == before
     summary = host2.close(s2)
     assert summary["errors"] == 0 and summary["steps"] == 4
+    # the ledger closes across the crash, read from the counters
+    done = sum(metrics.get(f"serve.{k}")
+               for k in ("completed", "errors", "shed"))
+    assert metrics.get("serve.admitted") == done
+    assert export.parse_text(export.render())[
+        "poisson_tpu_session_recovered"]["value"] == 1
 
 
 def test_second_crash_bumps_the_generation_again(tmp_path):
