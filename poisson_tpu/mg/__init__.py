@@ -11,7 +11,9 @@ Layout:
 - ``hierarchy`` — level planning, coefficient coarsening, the
   fingerprint-keyed device hierarchy cache, the dense coarsest inverse;
 - ``cycle`` — full-weighting restriction, bilinear prolongation,
-  weighted-Jacobi smoothing, the symmetric V-cycle;
+  weighted-Jacobi smoothing, the symmetric V-cycle (whose largest levels
+  smooth on the Pallas strip kernels of ``ops.pallas_mg`` in the solo
+  program on a TPU);
 - ``preconditioner`` — the ops bundle (``apply_Dinv`` = one V-cycle)
   and the jitted MG twins of every flag-off solve program;
 - ``selfcheck`` — ``python -m poisson_tpu.mg.selfcheck``: the two-grid

@@ -5,7 +5,9 @@ convention — full grids (…, M+1, N+1) with an identically-zero Dirichlet
 ring — and batch-polymorphic the same way the stencil library is:
 ellipsis indexing everywhere, so one implementation serves the solo
 solve, the leading-batch-axis stacks, and ``vmap``-ed per-member bodies
-(the batched/lane drivers) unchanged.
+(the batched/lane drivers) unchanged. The exception is the solo
+program's largest levels, which smooth on the Pallas strip kernels of
+``ops.pallas_mg`` (:func:`v_cycle`'s ``kernel_levels``).
 
 The transfer pair is chosen for symmetry, not convenience: bilinear
 prolongation P (coincident copy, ½ edges, ¼ centres) and full-weighting
@@ -23,6 +25,11 @@ from jax import lax
 from jax.experimental.xla_metadata import set_xla_metadata
 
 from poisson_tpu.mg.hierarchy import DEFAULT_MG, MGConfig, MGLevels
+from poisson_tpu.ops.pallas_mg import (
+    mg_postsmooth,
+    mg_presmooth_residual,
+    strip_grid,
+)
 from poisson_tpu.ops.stencil import apply_A, pad_interior
 
 
@@ -123,7 +130,8 @@ def coarse_solve(rhs, a, b, dinv, coarse_inv, h1: float, h2: float,
 
 
 def v_cycle(hier: MGLevels, r, h1: float, h2: float,
-            config: MGConfig = DEFAULT_MG):
+            config: MGConfig = DEFAULT_MG, kernel_levels: int = 0,
+            interpret: bool = False):
     """One V(ν₁, ν₂) cycle applied to the residual ``r``: z ≈ A⁻¹r.
 
     Python recursion over the static level tuple — the cycle unrolls at
@@ -131,6 +139,13 @@ def v_cycle(hier: MGLevels, r, h1: float, h2: float,
     are the finest spacings; each level doubles them. Symmetric by
     construction (module docstring), so the result is an SPD
     preconditioner application for the outer CG.
+
+    The first ``kernel_levels`` levels (a trace-time constant; their
+    transposed fields in ``hier.strips``) smooth and form their residual
+    on the Pallas strip kernels (``ops.pallas_mg``) instead of
+    :func:`smooth_jacobi` and ``apply_A``: the same arithmetic, two
+    passes over the level. ``interpret`` runs those kernels in the
+    Pallas interpreter (off a TPU).
 
     Every op of level l carries the frontend attribute
     ``mg_level="<l>"`` (smoothing, residual, the restriction out of l and
@@ -142,19 +157,33 @@ def v_cycle(hier: MGLevels, r, h1: float, h2: float,
     def cycle(lvl: int, rl):
         a, b, dinv = levels[lvl]
         h1l, h2l = h1 * (1 << lvl), h2 * (1 << lvl)
+        on_strips = lvl < kernel_levels
         with set_xla_metadata(mg_level=str(lvl)):
             if lvl == len(levels) - 1:
                 return coarse_solve(rl, a, b, dinv, hier.coarse_inv,
                                     h1l, h2l, config)
-            x = smooth_jacobi(None, rl, a, b, dinv, h1l, h2l,
-                              config.pre_smooth, config.omega,
-                              from_zero=True)
-            res = rl - apply_A(x, a, b, h1l, h2l)
+            if on_strips:
+                # The kernels take the grids transposed (ops.pallas_mg).
+                sg = strip_grid(rl.shape[-2] - 1, rl.shape[-1] - 1)
+                sa, sb, sdinv = hier.strips[lvl]
+                xt, res = mg_presmooth_residual(
+                    sg, rl.T, sa, sb, sdinv, h1l, h2l, config.pre_smooth,
+                    config.omega, interpret=interpret)
+                res = res.T
+            else:
+                x = smooth_jacobi(None, rl, a, b, dinv, h1l, h2l,
+                                  config.pre_smooth, config.omega,
+                                  from_zero=True)
+                res = rl - apply_A(x, a, b, h1l, h2l)
             rc = restrict_full_weighting(res)
         ec = cycle(lvl + 1, rc)
         with set_xla_metadata(mg_level=str(lvl)):
-            x = x + prolong_bilinear(ec)
-            return smooth_jacobi(x, rl, a, b, dinv, h1l, h2l,
+            e = prolong_bilinear(ec)
+            if on_strips:
+                return mg_postsmooth(
+                    sg, xt, e.T, rl.T, sa, sb, sdinv, h1l, h2l,
+                    config.post_smooth, config.omega, interpret=interpret).T
+            return smooth_jacobi(x + e, rl, a, b, dinv, h1l, h2l,
                                  config.post_smooth, config.omega)
 
     return cycle(0, r)

@@ -201,11 +201,16 @@ class MGLevels(NamedTuple):
         ``coarse_sweeps`` of the smoother instead).
     scinv: √d on the full grid (zero ring) — the w-space wrap for the
         symmetrically-scaled outer system, or None for unscaled solves.
+    strips: the (a, b, dinv) of the leading levels that the solo
+        program smooths on the Pallas strip kernels, transposed as the
+        kernels read them (``ops.pallas_mg``); empty where no level
+        takes them (:func:`kernel_levels`).
     """
 
     levels: tuple
     coarse_inv: object = None
     scinv: object = None
+    strips: tuple = ()
 
 
 def build_hierarchy64(problem: Problem, a64: np.ndarray, b64: np.ndarray,
@@ -260,6 +265,42 @@ def _cast_levels(host: dict, dtype_name: str, scaled: bool) -> MGLevels:
     return MGLevels(levels=levels, coarse_inv=coarse_inv, scinv=scinv)
 
 
+def kernel_levels(platform: str, dtype_name: str, dims: tuple,
+                  config: MGConfig = DEFAULT_MG) -> int:
+    """How many leading levels of ``dims`` the solo MG program smooths
+    on the Pallas strip kernels: on a TPU, in fp32, with sweep counts
+    the strip halo holds, each level above the coarsest whose grid is
+    large enough to be bandwidth-bound (``ops.pallas_mg.bandwidth_bound``).
+    Zero everywhere else, so the CPU runs the XLA cycle."""
+    from poisson_tpu.ops.pallas_cg import HALO
+    from poisson_tpu.ops.pallas_mg import bandwidth_bound
+
+    if (platform != "tpu" or dtype_name != "float32"
+            or max(config.pre_smooth, config.post_smooth) > HALO):
+        return 0
+    count = 0
+    while count < len(dims) - 1 and bandwidth_bound(*dims[count]):
+        count += 1
+    return count
+
+
+def with_strips(hier: MGLevels, count: int) -> MGLevels:
+    """``hier`` with its first ``count`` levels' (a, b, dinv) also laid
+    out as the strip kernels read them: transposed, each its own
+    row-major array (``ops.pallas_mg``)."""
+    import jax.numpy as jnp
+
+    strips = tuple(tuple(jnp.transpose(f) for f in fields)
+                   for fields in hier.levels[:count])
+    return hier._replace(strips=strips)
+
+
+def _platform() -> str:
+    import jax
+
+    return jax.devices()[0].platform
+
+
 # Device hierarchies this process has built, keyed like the geometry
 # canvas cache: (normalized problem, dtype, scaled, fingerprint, config).
 # The blend canvases are f_val-independent, so the key normalizes it away
@@ -280,7 +321,9 @@ def device_hierarchy(problem: Problem, dtype_name: str, scaled: bool,
     (+ optional :mod:`poisson_tpu.geometry` spec): host-fp64 build and
     dense coarsest factorisation paid once per domain, then cached —
     ``mg.hierarchy_cache.{hits,misses}``. A miss builds under the span
-    ``mg.hierarchy.build``."""
+    ``mg.hierarchy.build``, and also lays out the levels
+    :func:`kernel_levels` gives this process's platform
+    (:func:`with_strips`)."""
     from poisson_tpu import obs
 
     fp = None
@@ -305,7 +348,9 @@ def device_hierarchy(problem: Problem, dtype_name: str, scaled: bool,
 
             a64, b64, _ = build_geometry_fields(problem, geometry)
         host = build_hierarchy64(problem, a64, b64, config)
-        hier = _cast_levels(host, dtype_name, scaled)
+        hier = with_strips(_cast_levels(host, dtype_name, scaled),
+                           kernel_levels(_platform(), dtype_name,
+                                         host["dims"], config))
     _HIERARCHIES[key] = hier
     obs.gauge("mg.levels", len(hier.levels))
     obs.gauge("mg.coarse_dense", 1 if hier.coarse_inv is not None else 0)

@@ -51,12 +51,14 @@ from poisson_tpu.solvers.pcg import (
 
 
 def mg_ops(problem: Problem, a, b, aux, hier: MGLevels,
-           config: MGConfig = DEFAULT_MG, scaled: bool = True) -> PCGOps:
+           config: MGConfig = DEFAULT_MG, scaled: bool = True,
+           kernel_levels: int = 0, interpret: bool = False) -> PCGOps:
     """The MG-preconditioned ops bundle: the standard backend bundle
     with ``apply_Dinv`` replaced by one V-cycle (scaled solves get the
     √d congruence wrap — ``hier.scinv``). Everything else — operator,
     dots, norms — is untouched, so the outer CG recurrence is exactly
-    the historical one with a stronger M⁻¹."""
+    the historical one with a stronger M⁻¹. ``kernel_levels`` and
+    ``interpret`` go to :func:`~poisson_tpu.mg.cycle.v_cycle`."""
     base = (
         scaled_single_device_ops(problem, a, b, aux)
         if scaled
@@ -67,25 +69,35 @@ def mg_ops(problem: Problem, a, b, aux, hier: MGLevels,
         scinv = hier.scinv
 
         def precond(rt):
-            return scinv * v_cycle(hier, scinv * rt, h1, h2, config)
+            return scinv * v_cycle(hier, scinv * rt, h1, h2, config,
+                                   kernel_levels, interpret)
     else:
         def precond(r):
-            return v_cycle(hier, r, h1, h2, config)
+            return v_cycle(hier, r, h1, h2, config, kernel_levels,
+                           interpret)
 
     return base._replace(apply_Dinv=precond)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5),
+                   static_argnames=("interpret",))
 def _solve_mg(problem: Problem, scaled: bool, config: MGConfig,
               stream_every: int, verify_every: int, verify_tol: float,
-              a, b, rhs, aux, hier: MGLevels) -> PCGResult:
+              a, b, rhs, aux, hier: MGLevels,
+              interpret: bool = False) -> PCGResult:
     """The MG twin of ``solvers.pcg._solve``: same loop, same flags,
     same result contract — the hierarchy is an operand, the cycle
     config a static arg. ``verify_every`` arms the same in-loop
     integrity probe (drift is preconditioner-independent; the
     update-norm guards use the MG-calibrated collapse ratio —
-    ``integrity.probe.default_verify_collapse``)."""
-    ops = mg_ops(problem, a, b, aux, hier, config, scaled)
+    ``integrity.probe.default_verify_collapse``).
+
+    The one MG program whose cycle takes the Pallas strip kernels: on
+    the levels ``hier.strips`` holds (``mg.hierarchy.kernel_levels``),
+    in the interpreter when ``interpret``. Its twins below keep the XLA
+    cycle, so the V-cycle's bit parity under ``vmap`` holds there."""
+    ops = mg_ops(problem, a, b, aux, hier, config, scaled,
+                 len(hier.strips), interpret)
     s = pcg_loop(
         ops, rhs,
         delta=problem.delta, max_iter=problem.iteration_cap,

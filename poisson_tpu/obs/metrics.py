@@ -333,7 +333,9 @@ counters and numeric gauges in Prometheus text format):
   ``mg.coarse_dense`` (1 when the coarsest level solves by the dense
   inverse, 0 when it fell back to smoother sweeps — an audible
   quality bit: the dense coarse solve is what makes the cycle
-  resolution-independent);
+  resolution-independent); ``mg.pallas_levels`` (set by ``pcg_solve``:
+  how many levels of the solve's V-cycle smoothed on the Pallas strip
+  kernels, ``ops.pallas_mg``; 0 off a TPU);
 - ``cost.krylov.{block_bytes_per_iter,block_flops_per_iter,
   block_passes_per_member}`` and ``cost.krylov.{deflated_bytes_per_iter,
   deflated_flops_per_iter,deflated_passes}`` — the analytic block/

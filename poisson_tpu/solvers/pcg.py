@@ -727,6 +727,9 @@ def pcg_solve(problem: Problem, dtype=None, scaled=None,
     contract as ``stream_every``: 0 (the default) traces no callback
     and the program is byte-identical.
 
+    An MG solve sets the gauge ``mg.pallas_levels``: how many levels of
+    its cycle run on the Pallas strip kernels (``ops.pallas_mg``).
+
     Runs under the span ``pcg_solve`` with the children ``.prepare``
     (checks, set-up and hierarchy cache lookups, gate multiply),
     ``.launch`` (the jitted call) and ``.finish`` (the ``mg.solves``
@@ -745,6 +748,7 @@ def pcg_solve(problem: Problem, dtype=None, scaled=None,
                 cfg, (a, b, rhs, aux, hier) = _mg_prepare(
                     problem, dtype_name, use_scaled, geometry,
                     preconditioner, mg_config, verify_abft, history_every)
+                obs.gauge("mg.pallas_levels", len(hier.strips))
             else:
                 a, b, rhs, aux = solve_setup(problem, dtype_name,
                                              use_scaled, geometry=geometry)
@@ -754,9 +758,10 @@ def pcg_solve(problem: Problem, dtype=None, scaled=None,
             if use_mg:
                 from poisson_tpu.mg.preconditioner import _solve_mg
 
-                result = _solve_mg(problem, use_scaled, cfg,
-                                   int(stream_every), verify_every, tol,
-                                   a, b, rhs, aux, hier)
+                result = _solve_mg(
+                    problem, use_scaled, cfg, int(stream_every),
+                    verify_every, tol, a, b, rhs, aux, hier,
+                    interpret=jax.devices()[0].platform != "tpu")
             else:
                 result = _solve(problem, use_scaled, int(stream_every),
                                 verify_every, tol,

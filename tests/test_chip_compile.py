@@ -185,7 +185,8 @@ def test_ca_sharded(mesh):
 
 # Each lowering above, by the key its test compiles it under, and the
 # stable names of the kernels it holds: the fused pair serves the
-# one-chip and the sharded solve alike, the batched pair the batches.
+# one-chip and the sharded solve alike, the batched pair the batches,
+# the strip pair the finest level of the large MG solve.
 NAMED = [
     (("fused", 800, 1200, False), "one_chip",
      lambda chip: _lower_fused(chip, 800, 1200, False),
@@ -202,6 +203,9 @@ NAMED = [
      {"direction_and_stencil", "fused_update"}),
     (("ca_sharded",), "mesh", _lower_ca_sharded,
      {"basis_sweep", "pair_update"}),
+    (("mg", 6400, 9600), "one_chip",
+     lambda chip: _lower_mg(chip, 6400, 9600, _mg_kernel_levels(6400, 9600)),
+     {"mg_presmooth_residual", "mg_postsmooth"}),
 ]
 
 
@@ -217,10 +221,11 @@ def test_kernels_carry_their_names(request, key, where, lower, names):
     assert set(KERNEL_NAME.findall(text)) == names
 
 
-def _lower_mg(one_chip, M, N):
-    from poisson_tpu.mg import DEFAULT_MG, plan_levels
+def _mg_hierarchy(one_chip, M, N, strips):
+    """Shapes of the MG hierarchy of an M×N grid, with the first
+    ``strips`` levels also laid out for the strip kernels."""
+    from poisson_tpu.mg import plan_levels
     from poisson_tpu.mg.hierarchy import MGLevels
-    from poisson_tpu.mg.preconditioner import _solve_mg
 
     def grid(m, n):
         return jax.ShapeDtypeStruct((m + 1, n + 1), jnp.float32,
@@ -229,14 +234,29 @@ def _lower_mg(one_chip, M, N):
     dims = plan_levels(M, N)
     (mc, nc) = dims[-1]
     coarse = (mc - 1) * (nc - 1)
-    hier = MGLevels(
+    return MGLevels(
         levels=tuple((grid(m, n),) * 3 for m, n in dims),
         coarse_inv=jax.ShapeDtypeStruct((coarse, coarse), jnp.float32,
                                         sharding=one_chip),
-        scinv=grid(M, N))
-    g = grid(M, N)
+        scinv=grid(M, N),
+        strips=tuple((grid(n, m),) * 3 for m, n in dims[:strips]))
+
+
+def _lower_mg(one_chip, M, N, strips=0):
+    from poisson_tpu.mg import DEFAULT_MG
+    from poisson_tpu.mg.preconditioner import _solve_mg
+
+    hier = _mg_hierarchy(one_chip, M, N, strips)
+    g = hier.scinv
     return _solve_mg.lower(Problem(M=M, N=N), True, DEFAULT_MG, 0, 0, 0.0,
-                           g, g, g, g, hier)
+                           g, g, g, g, hier, interpret=False)
+
+
+def _mg_kernel_levels(M, N):
+    from poisson_tpu.mg import plan_levels
+    from poisson_tpu.mg.hierarchy import kernel_levels
+
+    return kernel_levels("tpu", "float32", plan_levels(M, N))
 
 
 def test_mg_solve_takes_no_gather(one_chip):
@@ -250,3 +270,43 @@ def test_mg_solve_takes_no_gather(one_chip):
 
     levels = {int(m) for m in re.findall(r'mg_level="(\d+)"', text)}
     assert levels == set(range(len(plan_levels(400, 600))))
+
+
+def _custom_calls(text):
+    """The text of each Mosaic custom call instruction of ``text``."""
+    starts = [text.rfind("\n", 0, m.start())
+              for m in re.finditer(f'custom_call_target="{KERNEL}"', text)]
+    return [text[s:text.find("\n  %", s + 1)] for s in starts]
+
+
+def test_mg_strip_kernels_at_the_cell_size(one_chip):
+    """At 6400x9600 the rule puts levels 0 and 1 on the strip kernels:
+    the solo program holds both, each carrying its level's ``mg_level``
+    tag beside its name, and still no gather."""
+    strips = _mg_kernel_levels(6400, 9600)
+    assert strips == 2
+    text = _compiled(("mg", 6400, 9600), lambda: _lower_mg(
+        one_chip, 6400, 9600, strips))
+    calls = _custom_calls(text)
+    assert {re.search(r'"kernel"\s*:\s*"(\w+)"', c).group(1)
+            for c in calls} == {"mg_presmooth_residual", "mg_postsmooth"}
+    levels = [re.findall(r'mg_level="(\d+)"', c) for c in calls]
+    assert all(len(found) == 1 for found in levels)
+    assert {int(found[0]) for found in levels} == set(range(strips))
+    assert " gather(" not in text
+
+
+def test_batched_mg_keeps_the_xla_cycle(one_chip):
+    """``_solve_batched_mg`` ignores the strip layout its hierarchy
+    carries: no Mosaic kernel in the vmapped twin."""
+    from poisson_tpu.mg import DEFAULT_MG
+    from poisson_tpu.mg.preconditioner import _solve_batched_mg
+
+    hier = _mg_hierarchy(one_chip, 400, 600, 1)
+    g = hier.scinv
+    stack = jax.ShapeDtypeStruct((2,) + g.shape, jnp.float32,
+                                 sharding=one_chip)
+    text = _compiled(("mg_batched", 400, 600), lambda: (
+        _solve_batched_mg.lower(Problem(M=400, N=600), True, DEFAULT_MG, 0,
+                                0.0, g, g, stack, g, hier)))
+    assert KERNEL not in text
