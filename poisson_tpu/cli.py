@@ -349,12 +349,15 @@ def _pick_backend(args) -> str:
         # single-device xla solve (the pallas/sharded paths bake the
         # reference ellipse).
         return "xla"
+    devices = jax.devices()
     if getattr(args, "preconditioner", "jacobi") == "mg":
         # --preconditioner mg likewise: the V-cycle rides the xla solve
-        # body (poisson_tpu.mg); the pallas kernels and sharded meshes
-        # have no MG program yet and reject it loudly when forced.
+        # body (poisson_tpu.mg), on one device or split over the mesh
+        # (parallel.mg_sharded); the pallas kernels have no MG program
+        # and reject it loudly when forced.
+        if len(devices) > 1 or args.mesh is not None:
+            return "sharded"
         return "xla"
-    devices = jax.devices()
     tpu = devices[0].platform == "tpu"
     # --checkpoint needs no special-casing: every JAX backend auto-pick can
     # reach (pallas, pallas-sharded, sharded, xla) has a checkpointed driver.
@@ -524,6 +527,11 @@ def _run_jax(args, problem: Problem, backend: str, watchdog=None,
                 stagnation_window=args.stagnation_window or 0,
                 watchdog=watchdog, on_chunk=on_chunk,
             )
+        elif args.preconditioner == "mg":
+            from poisson_tpu.solvers.pcg import pcg_solve
+
+            run = lambda: pcg_solve(problem, dtype=args.dtype,
+                                    preconditioner="mg", mesh=mesh)
         else:
             run = lambda: pcg_solve_sharded(
                 problem, mesh, dtype=args.dtype, setup=args.setup
@@ -2073,17 +2081,34 @@ def main(argv=None) -> int:
                 f"checkpoint-hardening flags via --checkpoint"
             )
         if args.preconditioner == "mg":
-            if backend != "xla":
+            if backend not in ("xla", "sharded"):
                 raise SystemExit(
-                    f"--preconditioner mg drives the single-device xla "
-                    f"solve body (resolved backend: {backend}); the "
-                    f"pallas kernels and sharded meshes have no MG "
-                    f"program yet — drop the flag or use --backend xla"
+                    f"--preconditioner mg drives the xla solve body, on "
+                    f"one device or over the mesh (resolved backend: "
+                    f"{backend}); the pallas kernels have no MG program "
+                    f"— drop the flag or use --backend xla or sharded"
+                )
+            if backend == "sharded" and (args.checkpoint
+                                         or args.setup == "device"):
+                raise SystemExit(
+                    "--preconditioner mg over a mesh builds its blocks "
+                    "on the host and runs in one dispatch; drop "
+                    "--checkpoint and --setup device, or use --backend "
+                    "xla"
                 )
             from poisson_tpu.mg import validate_mg_problem
 
             try:
                 validate_mg_problem(problem)
+                if backend == "sharded":
+                    import jax
+
+                    from poisson_tpu.parallel.mesh import choose_process_grid
+                    from poisson_tpu.parallel.mg_sharded import plan_mesh
+
+                    grid = args.mesh or choose_process_grid(
+                        len(jax.devices()))
+                    plan_mesh(problem, *grid)
             except ValueError as e:
                 raise SystemExit(f"--preconditioner mg: {e}")
         # The chunk-boundary hooks exist on the XLA chunked drivers; a

@@ -9,6 +9,11 @@ solve, the leading-batch-axis stacks, and ``vmap``-ed per-member bodies
 program's largest levels, which smooth on the Pallas strip kernels of
 ``ops.pallas_mg`` (:func:`v_cycle`'s ``kernel_levels``).
 
+The cycle reads its grid through a :class:`WholeGrid` (one device: the
+whole grid, nothing to exchange). The sharded cycle
+(``parallel.mg_sharded``) hands it per-shard blocks with a halo ring
+instead, and the same level operators run on them.
+
 The transfer pair is chosen for symmetry, not convenience: bilinear
 prolongation P (coincident copy, ½ edges, ¼ centres) and full-weighting
 restriction R (the 1/16·[1 2 1; 2 4 2; 1 2 1] stencil) satisfy
@@ -87,22 +92,30 @@ def prolong_bilinear(e):
     return jnp.concatenate([cols, ex[..., :, -1:]], axis=-1)
 
 
+def _unchanged(u):
+    return u
+
+
 def smooth_jacobi(x, rhs, a, b, dinv, h1: float, h2: float,
-                  sweeps: int, omega: float, from_zero: bool = False):
+                  sweeps: int, omega: float, from_zero: bool = False,
+                  exchange=_unchanged):
     """``sweeps`` damped-Jacobi sweeps x ← x + ω·D⁻¹(rhs − Ax).
 
     ``dinv`` is the zero-ring-padded inverse diagonal, so the update is
     one fused elementwise expression and the ring stays untouched.
     ``from_zero`` starts from x = 0 and folds the first sweep into the
     cheap closed form ω·D⁻¹·rhs (no stencil application against a zero
-    iterate). Unrolled: ``sweeps`` is a small static constant."""
+    iterate). Unrolled: ``sweeps`` is a small static constant.
+    ``exchange`` refreshes x's halo ring before each stencil read (a
+    shard's block; on one device x is the whole grid and it is the
+    identity)."""
     if from_zero:
         if sweeps <= 0:
             return jnp.zeros_like(rhs)
         x = omega * dinv * rhs
         sweeps -= 1
     for _ in range(sweeps):
-        x = x + omega * dinv * (rhs - apply_A(x, a, b, h1, h2))
+        x = x + omega * dinv * (rhs - apply_A(exchange(x), a, b, h1, h2))
     return x
 
 
@@ -129,9 +142,37 @@ def coarse_solve(rhs, a, b, dinv, coarse_inv, h1: float, h2: float,
     return pad_interior(e.reshape(rhs.shape[:-2] + (mc - 1, nc - 1)))
 
 
+class WholeGrid:
+    """Where the cycle's grids live on one device: each level whole, so
+    nothing to exchange, and the transfers are the plain
+    :func:`restrict_full_weighting` and :func:`prolong_bilinear`.
+
+    The sharded cycle's grid (``parallel.mg_sharded.ShardedGrid``) keeps
+    levels ``0 … replicated_from − 1`` as per-shard blocks with a halo
+    ring: ``exchange`` refreshes a block's ring, ``restrict`` and
+    ``prolong`` wrap the same transfers for blocks, and at level
+    ``replicated_from`` ``gather`` assembles the whole grid on every
+    device, where the rest of the cycle runs as here, and ``scatter``
+    cuts each device's block of the correction back out."""
+
+    replicated_from = None
+    exchange = staticmethod(_unchanged)
+
+    @staticmethod
+    def restrict(lvl: int, res):
+        return restrict_full_weighting(res)
+
+    @staticmethod
+    def prolong(lvl: int, ec):
+        return prolong_bilinear(ec)
+
+
+WHOLE = WholeGrid()
+
+
 def v_cycle(hier: MGLevels, r, h1: float, h2: float,
             config: MGConfig = DEFAULT_MG, kernel_levels: int = 0,
-            interpret: bool = False):
+            interpret: bool = False, grid=WHOLE):
     """One V(ν₁, ν₂) cycle applied to the residual ``r``: z ≈ A⁻¹r.
 
     Python recursion over the static level tuple — the cycle unrolls at
@@ -147,17 +188,30 @@ def v_cycle(hier: MGLevels, r, h1: float, h2: float,
     passes over the level. ``interpret`` runs those kernels in the
     Pallas interpreter (off a TPU).
 
+    ``grid`` says where each level lives (:class:`WholeGrid`): on one
+    device, whole; in ``shard_map``, as halo-ringed blocks down to
+    ``grid.replicated_from``, where the cycle leaves the shards and every
+    device runs the rest of it on the whole coarse grid.
+
     Every op of level l carries the frontend attribute
     ``mg_level="<l>"`` (smoothing, residual, the restriction out of l and
-    the prolongation into l; the coarsest solve carries the last
-    level's): the compiled instructions keep it, so a device trace can
-    split the cycle's time by level."""
+    the prolongation into l with their halo exchanges, the gather and
+    scatter at the replication level; the coarsest solve carries the
+    last level's): the compiled instructions keep it, so a device trace
+    can split the cycle's time by level."""
     levels = hier.levels
 
-    def cycle(lvl: int, rl):
+    def cycle(lvl: int, rl, grid):
+        if lvl == grid.replicated_from:
+            with set_xla_metadata(mg_level=str(lvl)):
+                whole = grid.gather(lvl, rl)
+            e = cycle(lvl, whole, WHOLE)
+            with set_xla_metadata(mg_level=str(lvl)):
+                return grid.scatter(lvl, e)
         a, b, dinv = levels[lvl]
         h1l, h2l = h1 * (1 << lvl), h2 * (1 << lvl)
         on_strips = lvl < kernel_levels
+        exchange = grid.exchange
         with set_xla_metadata(mg_level=str(lvl)):
             if lvl == len(levels) - 1:
                 return coarse_solve(rl, a, b, dinv, hier.coarse_inv,
@@ -173,17 +227,18 @@ def v_cycle(hier: MGLevels, r, h1: float, h2: float,
             else:
                 x = smooth_jacobi(None, rl, a, b, dinv, h1l, h2l,
                                   config.pre_smooth, config.omega,
-                                  from_zero=True)
-                res = rl - apply_A(x, a, b, h1l, h2l)
-            rc = restrict_full_weighting(res)
-        ec = cycle(lvl + 1, rc)
+                                  from_zero=True, exchange=exchange)
+                res = rl - apply_A(exchange(x), a, b, h1l, h2l)
+            rc = grid.restrict(lvl, res)
+        ec = cycle(lvl + 1, rc, grid)
         with set_xla_metadata(mg_level=str(lvl)):
-            e = prolong_bilinear(ec)
+            e = grid.prolong(lvl, ec)
             if on_strips:
                 return mg_postsmooth(
                     sg, xt, e.T, rl.T, sa, sb, sdinv, h1l, h2l,
                     config.post_smooth, config.omega, interpret=interpret).T
             return smooth_jacobi(x + e, rl, a, b, dinv, h1l, h2l,
-                                 config.post_smooth, config.omega)
+                                 config.post_smooth, config.omega,
+                                 exchange=exchange)
 
-    return cycle(0, r)
+    return cycle(0, r, grid)

@@ -223,15 +223,43 @@ def build_hierarchy64(problem: Problem, a64: np.ndarray, b64: np.ndarray,
     from poisson_tpu.ops.stencil import diag_D
 
     dims = validate_mg_problem(problem, config)
+    levels, coarse_inv = _tail64(problem, np.asarray(a64, np.float64),
+                                 np.asarray(b64, np.float64), dims, config)
+    d0 = diag_D(np.asarray(a64, np.float64), np.asarray(b64, np.float64),
+                problem.h1, problem.h2)
+    return {
+        "dims": dims,
+        "levels": levels,
+        "coarse_inv": coarse_inv,
+        "scinv": np.pad(np.sqrt(d0), 1),
+    }
+
+
+def _levels64(problem: Problem, a: np.ndarray, b: np.ndarray,
+              dims) -> list:
+    """fp64 (a, b, dinv_padded) of each level of ``dims``, the first from
+    ``a``/``b`` and each next one coarsened from the one before. Every
+    value is elementwise in the canvases it comes from, so a block of a
+    grid (odd-sized, starting on an even row and column of the level
+    below) gives the same numbers as the whole grid, save its outer
+    row and columns (``coarsen_a``'s injection filler)."""
+    from poisson_tpu.ops.stencil import diag_D
+
     levels = []
-    a, b = np.asarray(a64, np.float64), np.asarray(b64, np.float64)
-    for lvl, (m, n) in enumerate(dims):
+    for k, (m, n) in enumerate(dims):
+        if k:
+            a, b = coarsen_a(a), coarsen_b(b)
         h1 = (problem.x_max - problem.x_min) / m
         h2 = (problem.y_max - problem.y_min) / n
-        d = diag_D(a, b, h1, h2)
-        levels.append((a, b, np.pad(1.0 / d, 1)))
-        if lvl + 1 < len(dims):
-            a, b = coarsen_a(a), coarsen_b(b)
+        levels.append((a, b, np.pad(1.0 / diag_D(a, b, h1, h2), 1)))
+    return levels
+
+
+def _tail64(problem: Problem, a: np.ndarray, b: np.ndarray, dims,
+            config: MGConfig):
+    """The levels of ``dims`` from the whole-grid ``a``/``b`` of its first,
+    and the dense coarsest inverse when within the size limit."""
+    levels = _levels64(problem, a, b, dims)
     mc, nc = dims[-1]
     coarse_inv = None
     if (mc - 1) * (nc - 1) <= config.coarse_dense_limit:
@@ -241,14 +269,7 @@ def build_hierarchy64(problem: Problem, a64: np.ndarray, b64: np.ndarray,
         Ac = _dense_operator(ac, bc, h1c, h2c)
         inv = np.linalg.inv(Ac)
         coarse_inv = 0.5 * (inv + inv.T)   # exactly symmetric: SPD cycle
-    d0 = diag_D(np.asarray(a64, np.float64), np.asarray(b64, np.float64),
-                problem.h1, problem.h2)
-    return {
-        "dims": dims,
-        "levels": levels,
-        "coarse_inv": coarse_inv,
-        "scinv": np.pad(np.sqrt(d0), 1),
-    }
+    return levels, coarse_inv
 
 
 def _cast_levels(host: dict, dtype_name: str, scaled: bool) -> MGLevels:
@@ -342,7 +363,9 @@ def device_hierarchy(problem: Problem, dtype_name: str, scaled: bool,
         if geometry is None:
             from poisson_tpu.solvers.pcg import host_fields64
 
-            a64, b64, _, _ = host_fields64(problem.with_(f_val=1.0), False)
+            # The solve's own canvases (``host_fields64`` keeps them): a
+            # and b are the same for every f and either scaling.
+            a64, b64, _, _ = host_fields64(problem, bool(scaled))
         else:
             from poisson_tpu.geometry.canvas import build_geometry_fields
 
@@ -360,6 +383,210 @@ def device_hierarchy(problem: Problem, dtype_name: str, scaled: bool,
               dense_coarse=hier.coarse_inv is not None,
               fingerprint=fp)
     return hier
+
+
+# -- the hierarchy split over a device mesh --------------------------------
+
+
+class MeshPlan(NamedTuple):
+    """How an MG solve splits over a ``px × py`` mesh: every level
+    ``l < replicated_from`` as per-shard blocks of ``m_blk >> l`` ×
+    ``n_blk >> l`` owned nodes with a halo ring (the layout of
+    ``parallel.pcg_sharded``: shard (px, py) owns global rows
+    ``px·m̂_l + 1 … px·m̂_l + m̂_l`` and columns likewise), the levels from
+    ``replicated_from`` down whole on every device."""
+
+    dims: tuple
+    px: int
+    py: int
+    m_blk: int
+    n_blk: int
+    replicated_from: int
+
+
+def shard_fields64(problem: Problem, plan: MeshPlan, px: int, py: int,
+                   scaled: bool) -> dict:
+    """Host-fp64 fields of shard (px, py), built from its own rows and
+    columns only (``models.fictitious_domain`` closed forms), each a
+    (m̂_l + 2, n̂_l + 2) block with its halo ring:
+
+    - ``levels``: (a, b, dinv) of every sharded level, dinv zero off the
+      shard's owned interior (so smoothing never writes off it);
+    - ``rhs``, ``aux``, ``scinv``: level 0's right-hand side (scaled by
+      D^{-1/2} when ``scaled``; zero off the owned interior), D^{-1/2}
+      (scaled) or D, and √d (zero off the owned interior) —
+      ``solvers.pcg.host_fields64``'s derivation;
+    - ``tail``: (rows, cols, a, b), the shard's owned part of level
+      ``replicated_from``'s a and b and where it sits in the whole grid.
+
+    The block grows by a margin of 2^R lines on each side
+    (R = ``replicated_from``), clipped at the grid's edges, and is
+    coarsened as a whole grid would be: every value is elementwise in
+    the canvases it comes from and starts on an even line of the level
+    below, so each block equals the slice of :func:`build_hierarchy64`'s
+    levels (zero past the grid's far edges) save the margin that
+    ``coarsen_a``'s injection filler spoils and that the slice leaves out
+    (``tests/test_mg_sharded.py``)."""
+    from poisson_tpu.models.fictitious_domain import (
+        coefficient_fields,
+        rhs_field,
+    )
+    from poisson_tpu.ops.stencil import diag_D
+
+    R = plan.replicated_from
+    margin = 1 << R
+    spans = []
+    for p, blk, size in ((px, plan.m_blk, problem.M),
+                         (py, plan.n_blk, problem.N)):
+        spans.append((max(p * blk - margin, 0),
+                      min(p * blk + blk + margin, size)))
+    (lo_i, hi_i), (lo_j, hi_j) = spans
+    i_idx, j_idx = np.arange(lo_i, hi_i + 1), np.arange(lo_j, hi_j + 1)
+    a, b = coefficient_fields(problem, i_idx, j_idx, np.float64, np)
+    levels = _levels64(problem, a, b, plan.dims[:R + 1])
+
+    def block(u, lvl):
+        ml, nl = plan.m_blk >> lvl, plan.n_blk >> lvl
+        oi, oj = px * ml - (lo_i >> lvl), py * nl - (lo_j >> lvl)
+        part = u[oi:oi + ml + 2, oj:oj + nl + 2]
+        out = np.zeros((ml + 2, nl + 2))
+        out[:part.shape[0], :part.shape[1]] = part
+        return out
+
+    def owned(lvl):
+        ml, nl = plan.m_blk >> lvl, plan.n_blk >> lvl
+        Ml, Nl = plan.dims[lvl]
+        i, j = np.arange(ml + 2), np.arange(nl + 2)
+        rows = (i >= 1) & (i <= ml) & (px * ml + i <= Ml - 1)
+        cols = (j >= 1) & (j <= nl) & (py * nl + j <= Nl - 1)
+        return rows[:, None] & cols[None, :]
+
+    d0 = diag_D(a, b, problem.h1, problem.h2)
+    rhs = rhs_field(problem, i_idx, j_idx, np.float64, np)
+    if scaled:
+        inv_sqrt_d = 1.0 / np.sqrt(d0)
+        rhs = np.pad(rhs[1:-1, 1:-1] * inv_sqrt_d, 1)
+        aux = np.pad(inv_sqrt_d, 1)
+    else:
+        aux = np.pad(d0, 1)
+    mask0 = owned(0)
+    # Level R's a and b on the rows and columns this shard owns (and the
+    # grid's edge lines on the edge shards): together they are its whole
+    # grid.
+    mR, nR = plan.m_blk >> R, plan.n_blk >> R
+    r0, c0 = (0 if px == 0 else px * mR + 1), (0 if py == 0 else py * nR + 1)
+    r1, c1 = px * mR + mR + 1, py * nR + nR + 1
+    aR, bR, _ = levels[R]
+    cut = (slice(r0 - (lo_i >> R), r1 - (lo_i >> R)),
+           slice(c0 - (lo_j >> R), c1 - (lo_j >> R)))
+    return {
+        "levels": [(block(la, lvl), block(lb, lvl),
+                    block(ldinv, lvl) * owned(lvl))
+                   for lvl, (la, lb, ldinv) in enumerate(levels[:R])],
+        "rhs": block(rhs, 0) * mask0,
+        "aux": block(aux, 0),
+        "scinv": block(np.pad(np.sqrt(d0), 1), 0) * mask0,
+        "tail": ((r0, r1), (c0, c1), aR[cut], bR[cut]),
+    }
+
+
+def mesh_hierarchy64(problem: Problem, plan: MeshPlan, scaled: bool,
+                     config: MGConfig = DEFAULT_MG, cast=None) -> dict:
+    """Every shard's :func:`shard_fields64` (in threads, one a shard:
+    numpy's array passes release the interpreter lock), each passed
+    through ``cast`` as it is made, and the replicated tail: levels
+    ``replicated_from …`` and the dense coarsest inverse, coarsened from
+    level ``replicated_from``'s whole grid, which the shards' owned parts
+    tile. Returns ``{"shards": {(px, py): fields}, "tail": levels,
+    "coarse_inv": inv}``; the whole fine grid is never held."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    R = plan.replicated_from
+    keys = [(px, py) for px in range(plan.px) for py in range(plan.py)]
+
+    def build(key):
+        fields = shard_fields64(problem, plan, *key, scaled)
+        tail = fields.pop("tail")
+        return (fields if cast is None else cast(fields)), tail
+
+    with ThreadPoolExecutor(max_workers=len(keys)) as pool:
+        built = dict(zip(keys, pool.map(build, keys)))
+    MR, NR = plan.dims[R]
+    aR, bR = np.zeros((MR + 1, NR + 1)), np.zeros((MR + 1, NR + 1))
+    for _, ((r0, r1), (c0, c1), sa, sb) in built.values():
+        aR[r0:r1, c0:c1] = sa
+        bR[r0:r1, c0:c1] = sb
+    tail, coarse_inv = _tail64(problem, aR, bR, plan.dims[R:], config)
+    return {"shards": {k: v[0] for k, v in built.items()}, "tail": tail,
+            "coarse_inv": coarse_inv}
+
+
+def mesh_hierarchy(problem: Problem, dtype_name: str, scaled: bool, mesh,
+                   plan: MeshPlan, config: MGConfig = DEFAULT_MG):
+    """(hierarchy, rhs, aux) of an MG solve over ``mesh``, cached per
+    (problem, dtype, scaled, config, mesh, plan) like
+    :func:`device_hierarchy` (``mg.hierarchy_cache.{hits,misses}``).
+
+    Each shard's fp64 fields (:func:`mesh_hierarchy64`) are cast once and
+    placed on their own device as that device's block of a
+    ``NamedSharding(mesh, P('x', 'y'))`` array of shape
+    (px·(m̂_l + 2), py·(n̂_l + 2)); the tail levels and the coarsest
+    inverse are placed whole on every device (``P()``). The hierarchy's
+    ``levels`` mix the two: blocks down to ``plan.replicated_from``,
+    whole grids below; level 0's a and b are the operator's too."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from poisson_tpu import obs
+    from poisson_tpu.parallel.mesh import X_AXIS, Y_AXIS
+
+    key = ("mesh", problem, dtype_name, bool(scaled), config, mesh, plan)
+    cached = _HIERARCHIES.get(key)
+    if cached is not None:
+        obs.inc("mg.hierarchy_cache.hits")
+        return cached
+    obs.inc("mg.hierarchy_cache.misses")
+    dt = np.dtype(jax.numpy.dtype(dtype_name))
+    blocked = NamedSharding(mesh, PartitionSpec(X_AXIS, Y_AXIS))
+    whole = NamedSharding(mesh, PartitionSpec())
+
+    def cast(fields):
+        return {k: ([tuple(np.asarray(f, dt) for f in lv) for lv in v]
+                    if k == "levels" else np.asarray(v, dt))
+                for k, v in fields.items()}
+
+    def place(pick):
+        parts = {k: pick(f) for k, f in host["shards"].items()}
+        bm, bn = parts[(0, 0)].shape
+        return jax.make_array_from_callback(
+            (plan.px * bm, plan.py * bn), blocked,
+            lambda idx: parts[((idx[0].start or 0) // bm,
+                               (idx[1].start or 0) // bn)])
+
+    with obs.span("mg.hierarchy.build"):
+        host = mesh_hierarchy64(problem, plan, scaled, config, cast)
+        levels = tuple(
+            tuple(place(lambda f, l=lvl, k=k: f["levels"][l][k])
+                  for k in range(3))
+            for lvl in range(plan.replicated_from))
+        levels += tuple(tuple(jax.device_put(np.asarray(f, dt), whole)
+                              for f in lv) for lv in host["tail"])
+        coarse_inv = (None if host["coarse_inv"] is None else
+                      jax.device_put(np.asarray(host["coarse_inv"], dt),
+                                     whole))
+        hier = MGLevels(levels=levels, coarse_inv=coarse_inv,
+                        scinv=place(lambda f: f["scinv"]))
+        out = (hier, place(lambda f: f["rhs"]), place(lambda f: f["aux"]))
+    _HIERARCHIES[key] = out
+    obs.gauge("mg.levels", len(levels))
+    obs.gauge("mg.coarse_dense", 1 if coarse_inv is not None else 0)
+    obs.event("mg.hierarchy", grid=f"{problem.M}x{problem.N}",
+              levels=len(levels),
+              coarsest="x".join(map(str, plan.dims[-1])),
+              dense_coarse=coarse_inv is not None, fingerprint=None,
+              mesh=f"{plan.px}x{plan.py}",
+              replicated_from=plan.replicated_from)
+    return out
 
 
 def hierarchy_from_fields(problem: Problem, a64: np.ndarray,

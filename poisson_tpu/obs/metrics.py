@@ -335,7 +335,10 @@ counters and numeric gauges in Prometheus text format):
   quality bit: the dense coarse solve is what makes the cycle
   resolution-independent); ``mg.pallas_levels`` (set by ``pcg_solve``:
   how many levels of the solve's V-cycle smoothed on the Pallas strip
-  kernels, ``ops.pallas_mg``; 0 off a TPU);
+  kernels, ``ops.pallas_mg``; 0 off a TPU and over a mesh);
+  ``mg.replicated_from`` (set by ``pcg_solve`` over a mesh: the level
+  at which the sharded V-cycle gathers its right-hand side and runs the
+  rest of the cycle whole on every device, ``parallel.mg_sharded``);
 - ``cost.krylov.{block_bytes_per_iter,block_flops_per_iter,
   block_passes_per_member}`` and ``cost.krylov.{deflated_bytes_per_iter,
   deflated_flops_per_iter,deflated_passes}`` — the analytic block/

@@ -684,8 +684,10 @@ def pcg_solve(problem: Problem, dtype=None, scaled=None,
               geometry=None, verify_every: int = 0,
               verify_tol=None, verify_abft: bool = False,
               preconditioner: str = "jacobi",
-              mg_config=None, history_every: int = 0) -> PCGResult:
-    """Single-device solve (the stage0/stage1 workload, SURVEY §3.1).
+              mg_config=None, history_every: int = 0,
+              mesh=None) -> PCGResult:
+    """Single-device solve (the stage0/stage1 workload, SURVEY §3.1), or
+    the MG solve split over a device mesh.
 
     The iteration is jit-compiled end to end; setup runs on the host in fp64
     (see :func:`host_setup`). ``dtype`` selects the state precision (fp64 for
@@ -730,6 +732,15 @@ def pcg_solve(problem: Problem, dtype=None, scaled=None,
     An MG solve sets the gauge ``mg.pallas_levels``: how many levels of
     its cycle run on the Pallas strip kernels (``ops.pallas_mg``).
 
+    ``mesh`` (a ``parallel.make_solver_mesh`` mesh; MG only) splits the
+    MG solve over its devices: one ``shard_map`` program whose cycle is
+    sharded down to its replication level and whole on every device
+    below (``parallel.mg_sharded``; the gauge ``mg.replicated_from``
+    holds that level). Its set-up builds each shard's fields on the host
+    in fp64 and places them on their own device; the grid must split
+    into even blocks (``parallel.mg_sharded.plan_mesh``). Streaming,
+    verification, history and geometries are not wired there.
+
     Runs under the span ``pcg_solve`` with the children ``.prepare``
     (checks, set-up and hierarchy cache lookups, gate multiply),
     ``.launch`` (the jitted call) and ``.finish`` (the ``mg.solves``
@@ -742,9 +753,20 @@ def pcg_solve(problem: Problem, dtype=None, scaled=None,
     tol = (resolve_verify_tol(verify_tol, dtype_name)
            if verify_every > 0 else 0.0)
     use_mg = preconditioner not in (None, "jacobi")
+    if mesh is not None and not use_mg:
+        raise ValueError(
+            "pcg_solve(mesh=...) runs the MG solve over a mesh; the "
+            "Jacobi solve over a mesh is parallel.pcg_solve_sharded")
     with obs.span("pcg_solve"):
         with obs.span("pcg_solve.prepare"):
-            if use_mg:
+            if mesh is not None:
+                cfg, plan, (hier, rhs, aux) = _mg_mesh_prepare(
+                    problem, dtype_name, use_scaled, mesh, geometry,
+                    preconditioner, mg_config, stream_every, verify_every,
+                    history_every)
+                obs.gauge("mg.pallas_levels", 0)
+                obs.gauge("mg.replicated_from", plan.replicated_from)
+            elif use_mg:
                 cfg, (a, b, rhs, aux, hier) = _mg_prepare(
                     problem, dtype_name, use_scaled, geometry,
                     preconditioner, mg_config, verify_abft, history_every)
@@ -755,7 +777,14 @@ def pcg_solve(problem: Problem, dtype=None, scaled=None,
             if rhs_gate is not None:
                 rhs = rhs * jnp.asarray(rhs_gate, rhs.dtype)
         with obs.span("pcg_solve.launch"):
-            if use_mg:
+            if mesh is not None:
+                from poisson_tpu.parallel.mg_sharded import (
+                    _solve_mg_sharded,
+                )
+
+                result = _solve_mg_sharded(problem, mesh, plan, cfg,
+                                           use_scaled, hier, rhs, aux)
+            elif use_mg:
                 from poisson_tpu.mg.preconditioner import _solve_mg
 
                 result = _solve_mg(
@@ -800,6 +829,30 @@ def _mg_prepare(problem: Problem, dtype_name: str, use_scaled: bool,
         )
     return cfg, mg_solve_setup(problem, dtype_name, use_scaled,
                                geometry=geometry, config=cfg)
+
+
+def _mg_mesh_prepare(problem: Problem, dtype_name: str, use_scaled: bool,
+                     mesh, geometry, preconditioner, mg_config,
+                     stream_every: int, verify_every: int,
+                     history_every: int):
+    """(cycle config, mesh plan, (hierarchy, rhs, aux)) of an MG solve
+    over ``mesh``, after the checks that refuse what that path does not
+    wire."""
+    from poisson_tpu.mg import DEFAULT_MG, resolve_preconditioner
+    from poisson_tpu.parallel.mg_sharded import mesh_setup
+
+    resolve_preconditioner(preconditioner)   # raises on unknown
+    unwired = [name for name, on in (
+        ("geometry", geometry is not None),
+        ("stream_every", stream_every), ("verify_every", verify_every),
+        ("history_every", history_every)) if on]
+    if unwired:
+        raise ValueError(
+            f"{', '.join(unwired)} is not wired for the MG solve over a "
+            "mesh; drop it or solve on one device")
+    cfg = mg_config or DEFAULT_MG
+    plan, fields = mesh_setup(problem, dtype_name, use_scaled, mesh, cfg)
+    return cfg, plan, fields
 
 
 def iteration_program(problem: Problem, dtype=None, scaled=None,

@@ -310,3 +310,72 @@ def test_batched_mg_keeps_the_xla_cycle(one_chip):
         _solve_batched_mg.lower(Problem(M=400, N=600), True, DEFAULT_MG, 0,
                                 0.0, g, g, stack, g, hier)))
     assert KERNEL not in text
+
+
+def _mg_mesh_operands(mesh, problem, plan):
+    """Shapes of the MG solve over ``mesh``: blocks of the sharded levels
+    (a chip's (m̂_l + 2, n̂_l + 2) each), whole grids from the replication
+    level down, as ``mg.hierarchy.mesh_hierarchy`` places them."""
+    from poisson_tpu.mg.hierarchy import MGLevels
+
+    blocked = NamedSharding(mesh, P(X_AXIS, Y_AXIS))
+    whole = NamedSharding(mesh, P())
+
+    def level(lvl):
+        if lvl < plan.replicated_from:
+            shape = (plan.px * ((plan.m_blk >> lvl) + 2),
+                     plan.py * ((plan.n_blk >> lvl) + 2))
+            return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=blocked)
+        m, n = plan.dims[lvl]
+        return jax.ShapeDtypeStruct((m + 1, n + 1), jnp.float32,
+                                    sharding=whole)
+
+    mc, nc = plan.dims[-1]
+    coarse = (mc - 1) * (nc - 1)
+    block = level(0)
+    hier = MGLevels(
+        levels=tuple((level(lvl),) * 3 for lvl in range(len(plan.dims))),
+        coarse_inv=jax.ShapeDtypeStruct((coarse, coarse), jnp.float32,
+                                        sharding=whole),
+        scinv=block)
+    return hier, block, block
+
+
+def test_mg_mesh_program_at_the_cell_size(mesh):
+    """The MG solve of the ``mg-mesh2x2-12800x19200`` cell, compiled for
+    a described v5e 2x2: it fits a chip's 16 GB by XLA's own count (printed
+    per chip), runs no Mosaic kernel on its shards, and every halo permute
+    of the sharded levels 0-2 and the gather at level 3 carry their
+    ``mg_level`` tag."""
+    from poisson_tpu.mg import DEFAULT_MG
+    from poisson_tpu.parallel import mg_sharded
+
+    problem = Problem(M=12800, N=19200)
+    plan = mg_sharded.plan_mesh(problem, 2, 2)
+    assert plan.replicated_from == 3
+    compiled = mg_sharded._solve_mg_sharded.lower(
+        problem, mesh, plan, DEFAULT_MG, True,
+        *_mg_mesh_operands(mesh, problem, plan)).compile()
+    stats = compiled.memory_analysis()
+    per_chip = (stats.argument_size_in_bytes + stats.output_size_in_bytes
+                + stats.temp_size_in_bytes)
+    print(f"mg mesh 12800x19200, XLA's memory estimate a chip: "
+          f"{per_chip / 1e9:.2f} GB (operands "
+          f"{stats.argument_size_in_bytes / 1e9:.2f}, output "
+          f"{stats.output_size_in_bytes / 1e9:.2f}, temporaries "
+          f"{stats.temp_size_in_bytes / 1e9:.2f})")
+    assert per_chip < 16e9
+    text = compiled.as_text()
+    assert KERNEL not in text
+    tags = {}
+    for line in text.splitlines():
+        op = re.search(r" (collective-permute-start|all-gather)\(", line)
+        if op and line.lstrip().startswith("%"):
+            tag = re.search(r'mg_level="(\d+)"', line)
+            tags.setdefault(op.group(1), []).append(
+                None if tag is None else int(tag.group(1)))
+    # Four untagged permutes: the CG body's exchange of p.
+    assert tags["collective-permute-start"].count(None) == 4
+    assert ({t for t in tags["collective-permute-start"] if t is not None}
+            == {0, 1, 2})
+    assert 3 in tags["all-gather"]

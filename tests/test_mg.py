@@ -541,6 +541,29 @@ def test_hierarchy_cache_counters():
     assert metrics.get("mg.hierarchy_cache.hits") == 2
 
 
+def test_mg_setup_builds_the_fine_canvases_once(monkeypatch):
+    """``mg_solve_setup`` derives the solve's fields and the hierarchy
+    from one host fp64 build of the fine canvases (a grid no other test
+    solves, so no cache holds it yet), and the hierarchy is the one
+    ``build_hierarchy64`` makes from those canvases."""
+    from poisson_tpu.mg import build_hierarchy64
+    from poisson_tpu.mg.preconditioner import mg_solve_setup
+    from poisson_tpu.solvers import pcg as pcg_module
+
+    builds = []
+    real = pcg_module.build_fields
+    monkeypatch.setattr(pcg_module, "build_fields",
+                        lambda *a, **k: builds.append(a[0]) or real(*a, **k))
+    p = Problem(M=60, N=92)
+    a, b, rhs, aux, hier = mg_solve_setup(p, "float32", True)
+    assert builds == [p]
+    a64, b64, _, _ = pcg_module.host_fields64(p, True)
+    host = build_hierarchy64(p, a64, b64)
+    for dev, ref in zip(hier.levels, host["levels"], strict=True):
+        for x, y in zip(dev, ref):
+            assert np.array_equal(np.asarray(x), np.asarray(y, np.float32))
+
+
 def test_mg_counters_survive_exposition():
     from poisson_tpu.obs import export, metrics
 
