@@ -153,7 +153,12 @@ class WholeGrid:
     ``prolong`` wrap the same transfers for blocks, and at level
     ``replicated_from`` ``gather`` assembles the whole grid on every
     device, where the rest of the cycle runs as here, and ``scatter``
-    cuts each device's block of the correction back out."""
+    cuts each device's block of the correction back out.
+
+    On the strip-kernel levels, ``strip_rhs`` gives the level's r as the
+    kernels read it, ``strip_correction`` the kernels' x and e (the
+    prolongated correction) for the post-smoother, and ``strip_result``
+    what the level hands back; here the grids pass through as they are."""
 
     replicated_from = None
     exchange = staticmethod(_unchanged)
@@ -165,6 +170,18 @@ class WholeGrid:
     @staticmethod
     def prolong(lvl: int, ec):
         return prolong_bilinear(ec)
+
+    @staticmethod
+    def strip_rhs(lvl: int, rl):
+        return rl
+
+    @staticmethod
+    def strip_correction(lvl: int, xt, e):
+        return xt, e.T
+
+    @staticmethod
+    def strip_result(lvl: int, x):
+        return x
 
 
 WHOLE = WholeGrid()
@@ -191,14 +208,17 @@ def v_cycle(hier: MGLevels, r, h1: float, h2: float,
     ``grid`` says where each level lives (:class:`WholeGrid`): on one
     device, whole; in ``shard_map``, as halo-ringed blocks down to
     ``grid.replicated_from``, where the cycle leaves the shards and every
-    device runs the rest of it on the whole coarse grid.
+    device runs the rest of it on the whole coarse grid. A sharded
+    level's kernels read its blocks with the wider ring the grid's
+    ``strip_*`` hooks give them.
 
     Every op of level l carries the frontend attribute
     ``mg_level="<l>"`` (smoothing, residual, the restriction out of l and
     the prolongation into l with their halo exchanges, the gather and
-    scatter at the replication level; the coarsest solve carries the
-    last level's): the compiled instructions keep it, so a device trace
-    can split the cycle's time by level."""
+    scatter at the replication level, the strip kernels and their ring
+    exchanges; the coarsest solve carries the last level's): the
+    compiled instructions keep it, so a device trace can split the
+    cycle's time by level."""
     levels = hier.levels
 
     def cycle(lvl: int, rl, grid):
@@ -218,10 +238,11 @@ def v_cycle(hier: MGLevels, r, h1: float, h2: float,
                                     h1l, h2l, config)
             if on_strips:
                 # The kernels take the grids transposed (ops.pallas_mg).
-                sg = strip_grid(rl.shape[-2] - 1, rl.shape[-1] - 1)
+                rs = grid.strip_rhs(lvl, rl)
+                sg = strip_grid(rs.shape[-2] - 1, rs.shape[-1] - 1)
                 sa, sb, sdinv = hier.strips[lvl]
                 xt, res = mg_presmooth_residual(
-                    sg, rl.T, sa, sb, sdinv, h1l, h2l, config.pre_smooth,
+                    sg, rs.T, sa, sb, sdinv, h1l, h2l, config.pre_smooth,
                     config.omega, interpret=interpret)
                 res = res.T
             else:
@@ -234,9 +255,11 @@ def v_cycle(hier: MGLevels, r, h1: float, h2: float,
         with set_xla_metadata(mg_level=str(lvl)):
             e = grid.prolong(lvl, ec)
             if on_strips:
-                return mg_postsmooth(
-                    sg, xt, e.T, rl.T, sa, sb, sdinv, h1l, h2l,
-                    config.post_smooth, config.omega, interpret=interpret).T
+                xt, et = grid.strip_correction(lvl, xt, e)
+                return grid.strip_result(lvl, mg_postsmooth(
+                    sg, xt, et, rs.T, sa, sb, sdinv, h1l, h2l,
+                    config.post_smooth, config.omega,
+                    interpret=interpret).T)
             return smooth_jacobi(x + e, rl, a, b, dinv, h1l, h2l,
                                  config.post_smooth, config.omega,
                                  exchange=exchange)

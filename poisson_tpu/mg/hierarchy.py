@@ -305,6 +305,21 @@ def kernel_levels(platform: str, dtype_name: str, dims: tuple,
     return count
 
 
+def mesh_kernel_levels(platform: str, dtype_name: str, plan: MeshPlan,
+                       config: MGConfig = DEFAULT_MG) -> int:
+    """How many leading levels of ``plan``'s sharded levels the MG solve
+    over a mesh smooths on the strip kernels: :func:`kernel_levels`'s
+    rule read on what a chip holds, the blocks ``(m̂ >> l, n̂ >> l)`` of
+    the levels above ``plan.replicated_from``. Zero where a pass's
+    sweeps outrun the blocks' ``STRIP_RING``-line ring (each sweep and
+    the residual read one more line past the block)."""
+    if max(config.pre_smooth, config.post_smooth) > STRIP_RING:
+        return 0
+    blocks = tuple((plan.m_blk >> lvl, plan.n_blk >> lvl)
+                   for lvl in range(plan.replicated_from + 1))
+    return kernel_levels(platform, dtype_name, blocks, config)
+
+
 def with_strips(hier: MGLevels, count: int) -> MGLevels:
     """``hier`` with its first ``count`` levels' (a, b, dinv) also laid
     out as the strip kernels read them: transposed, each its own
@@ -404,8 +419,14 @@ class MeshPlan(NamedTuple):
     replicated_from: int
 
 
+# The ring of a sharded level's strip-kernel blocks: the kernels' two
+# stencil reads a pass (two sweeps, or a sweep and the residual) reach two
+# lines past the owned ones (``parallel.mg_sharded``).
+STRIP_RING = 2
+
+
 def shard_fields64(problem: Problem, plan: MeshPlan, px: int, py: int,
-                   scaled: bool) -> dict:
+                   scaled: bool, strips: int = 0) -> dict:
     """Host-fp64 fields of shard (px, py), built from its own rows and
     columns only (``models.fictitious_domain`` closed forms), each a
     (m̂_l + 2, n̂_l + 2) block with its halo ring:
@@ -417,16 +438,24 @@ def shard_fields64(problem: Problem, plan: MeshPlan, px: int, py: int,
       (scaled) or D, and √d (zero off the owned interior) —
       ``solvers.pcg.host_fields64``'s derivation;
     - ``tail``: (rows, cols, a, b), the shard's owned part of level
-      ``replicated_from``'s a and b and where it sits in the whole grid.
+      ``replicated_from``'s a and b and where it sits in the whole grid;
+    - ``strips``: of the first ``strips`` levels, (a, b, dinv) as the
+      strip kernels read them: (m̂_l + 4, n̂_l + 4) blocks with a
+      ``STRIP_RING``-line ring, transposed, dinv zero off the grid's
+      interior (not the shard's), so each ring node holds what its owner
+      computes there.
 
-    The block grows by a margin of 2^R lines on each side
+    The block grows by a margin of 2^(R+1) lines on each side
     (R = ``replicated_from``), clipped at the grid's edges, and is
     coarsened as a whole grid would be: every value is elementwise in
     the canvases it comes from and starts on an even line of the level
     below, so each block equals the slice of :func:`build_hierarchy64`'s
     levels (zero past the grid's far edges) save the margin that
     ``coarsen_a``'s injection filler spoils and that the slice leaves out
-    (``tests/test_mg_sharded.py``)."""
+    (``tests/test_mg_sharded.py``). The filler spoils a level's outer
+    line only, and a ring node's dinv reads one line further out: at
+    least four lines of margin at every sharded level keep the rings
+    clear of it."""
     from poisson_tpu.models.fictitious_domain import (
         coefficient_fields,
         rhs_field,
@@ -434,7 +463,7 @@ def shard_fields64(problem: Problem, plan: MeshPlan, px: int, py: int,
     from poisson_tpu.ops.stencil import diag_D
 
     R = plan.replicated_from
-    margin = 1 << R
+    margin = 2 << R
     spans = []
     for p, blk, size in ((px, plan.m_blk, problem.M),
                          (py, plan.n_blk, problem.N)):
@@ -460,6 +489,21 @@ def shard_fields64(problem: Problem, plan: MeshPlan, px: int, py: int,
         rows = (i >= 1) & (i <= ml) & (px * ml + i <= Ml - 1)
         cols = (j >= 1) & (j <= nl) & (py * nl + j <= Nl - 1)
         return rows[:, None] & cols[None, :]
+
+    def strip(u, lvl, interior_only=False):
+        """The transposed (m̂_l + 4, n̂_l + 4) block of level ``lvl``'s
+        ``u``, which starts one line before the block's own ring."""
+        ml, nl = plan.m_blk >> lvl, plan.n_blk >> lvl
+        ring = STRIP_RING
+        gi = px * ml + 1 - ring + np.arange(ml + 2 * ring)
+        gj = py * nl + 1 - ring + np.arange(nl + 2 * ring)
+        oi, oj = gi[0] - (lo_i >> lvl) + ring, gj[0] - (lo_j >> lvl) + ring
+        out = np.pad(u, ring)[oi:oi + ml + 2 * ring, oj:oj + nl + 2 * ring]
+        if interior_only:
+            Ml, Nl = plan.dims[lvl]
+            out = out * (((gi >= 1) & (gi <= Ml - 1))[:, None]
+                         & ((gj >= 1) & (gj <= Nl - 1))[None, :])
+        return np.ascontiguousarray(out.T)
 
     d0 = diag_D(a, b, problem.h1, problem.h2)
     rhs = rhs_field(problem, i_idx, j_idx, np.float64, np)
@@ -487,12 +531,17 @@ def shard_fields64(problem: Problem, plan: MeshPlan, px: int, py: int,
         "aux": block(aux, 0),
         "scinv": block(np.pad(np.sqrt(d0), 1), 0) * mask0,
         "tail": ((r0, r1), (c0, c1), aR[cut], bR[cut]),
+        "strips": [(strip(la, lvl), strip(lb, lvl),
+                    strip(ldinv, lvl, interior_only=True))
+                   for lvl, (la, lb, ldinv) in enumerate(levels[:strips])],
     }
 
 
 def mesh_hierarchy64(problem: Problem, plan: MeshPlan, scaled: bool,
-                     config: MGConfig = DEFAULT_MG, cast=None) -> dict:
-    """Every shard's :func:`shard_fields64` (in threads, one a shard:
+                     config: MGConfig = DEFAULT_MG, cast=None,
+                     strips: int = 0) -> dict:
+    """Every shard's :func:`shard_fields64` (with ``strips`` strip-kernel
+    levels; in threads, one a shard:
     numpy's array passes release the interpreter lock), each passed
     through ``cast`` as it is made, and the replicated tail: levels
     ``replicated_from …`` and the dense coarsest inverse, coarsened from
@@ -505,7 +554,7 @@ def mesh_hierarchy64(problem: Problem, plan: MeshPlan, scaled: bool,
     keys = [(px, py) for px in range(plan.px) for py in range(plan.py)]
 
     def build(key):
-        fields = shard_fields64(problem, plan, *key, scaled)
+        fields = shard_fields64(problem, plan, *key, scaled, strips)
         tail = fields.pop("tail")
         return (fields if cast is None else cast(fields)), tail
 
@@ -533,7 +582,11 @@ def mesh_hierarchy(problem: Problem, dtype_name: str, scaled: bool, mesh,
     (px·(m̂_l + 2), py·(n̂_l + 2)); the tail levels and the coarsest
     inverse are placed whole on every device (``P()``). The hierarchy's
     ``levels`` mix the two: blocks down to ``plan.replicated_from``,
-    whole grids below; level 0's a and b are the operator's too."""
+    whole grids below; level 0's a and b are the operator's too. Its
+    ``strips`` hold the levels :func:`mesh_kernel_levels` gives this
+    process's platform, each shard's transposed block placed as its
+    device's block of a ``P('y', 'x')`` array; those levels' blocks are
+    None where the cycle never reads them (all but level 0's a and b)."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
 
@@ -547,35 +600,60 @@ def mesh_hierarchy(problem: Problem, dtype_name: str, scaled: bool, mesh,
         return cached
     obs.inc("mg.hierarchy_cache.misses")
     dt = np.dtype(jax.numpy.dtype(dtype_name))
-    blocked = NamedSharding(mesh, PartitionSpec(X_AXIS, Y_AXIS))
     whole = NamedSharding(mesh, PartitionSpec())
+    count = mesh_kernel_levels(_platform(), dtype_name, plan, config)
+
+    def unread(lvl: int, k: int) -> bool:
+        """Whether the cycle never reads field ``k`` of sharded level
+        ``lvl``: a strip-kernel level reads its strips, and of its
+        (a, b, dinv) only level 0's a and b serve (CG's operator)."""
+        return lvl < count and (lvl > 0 or k == 2)
 
     def cast(fields):
-        return {k: ([tuple(np.asarray(f, dt) for f in lv) for lv in v]
-                    if k == "levels" else np.asarray(v, dt))
-                for k, v in fields.items()}
+        out = {k: np.asarray(v, dt) for k, v in fields.items()
+               if k not in ("levels", "strips")}
+        out["levels"] = [tuple(None if unread(lvl, k) else np.asarray(f, dt)
+                               for k, f in enumerate(lv))
+                         for lvl, lv in enumerate(fields["levels"])]
+        out["strips"] = [tuple(np.asarray(f, dt) for f in lv)
+                         for lv in fields["strips"]]
+        return out
 
-    def place(pick):
+    def place(pick, axes=(X_AXIS, Y_AXIS)):
+        """Each shard's ``pick`` as its device's block of an array split
+        over ``axes`` (the mesh's x and y, or y and x for a transposed
+        block)."""
         parts = {k: pick(f) for k, f in host["shards"].items()}
-        bm, bn = parts[(0, 0)].shape
+        rows, cols = parts[(0, 0)].shape
+
+        def part(idx):
+            at = {axes[0]: (idx[0].start or 0) // rows,
+                  axes[1]: (idx[1].start or 0) // cols}
+            return parts[(at[X_AXIS], at[Y_AXIS])]
+
         return jax.make_array_from_callback(
-            (plan.px * bm, plan.py * bn), blocked,
-            lambda idx: parts[((idx[0].start or 0) // bm,
-                               (idx[1].start or 0) // bn)])
+            (mesh.shape[axes[0]] * rows, mesh.shape[axes[1]] * cols),
+            NamedSharding(mesh, PartitionSpec(*axes)), part)
 
     with obs.span("mg.hierarchy.build"):
-        host = mesh_hierarchy64(problem, plan, scaled, config, cast)
+        host = mesh_hierarchy64(problem, plan, scaled, config, cast, count)
         levels = tuple(
-            tuple(place(lambda f, l=lvl, k=k: f["levels"][l][k])
+            tuple(None if unread(lvl, k) else
+                  place(lambda f, l=lvl, k=k: f["levels"][l][k])
                   for k in range(3))
             for lvl in range(plan.replicated_from))
+        strips = tuple(
+            tuple(place(lambda f, l=lvl, k=k: f["strips"][l][k],
+                        (Y_AXIS, X_AXIS))
+                  for k in range(3))
+            for lvl in range(count))
         levels += tuple(tuple(jax.device_put(np.asarray(f, dt), whole)
                               for f in lv) for lv in host["tail"])
         coarse_inv = (None if host["coarse_inv"] is None else
                       jax.device_put(np.asarray(host["coarse_inv"], dt),
                                      whole))
         hier = MGLevels(levels=levels, coarse_inv=coarse_inv,
-                        scinv=place(lambda f: f["scinv"]))
+                        scinv=place(lambda f: f["scinv"]), strips=strips)
         out = (hier, place(lambda f: f["rhs"]), place(lambda f: f["aux"]))
     _HIERARCHIES[key] = out
     obs.gauge("mg.levels", len(levels))

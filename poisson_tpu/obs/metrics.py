@@ -335,7 +335,8 @@ counters and numeric gauges in Prometheus text format):
   quality bit: the dense coarse solve is what makes the cycle
   resolution-independent); ``mg.pallas_levels`` (set by ``pcg_solve``:
   how many levels of the solve's V-cycle smoothed on the Pallas strip
-  kernels, ``ops.pallas_mg``; 0 off a TPU and over a mesh);
+  kernels, ``ops.pallas_mg``, on one device or on a mesh's blocks; 0
+  off a TPU);
   ``mg.replicated_from`` (set by ``pcg_solve`` over a mesh: the level
   at which the sharded V-cycle gathers its right-hand side and runs the
   rest of the cycle whole on every device, ``parallel.mg_sharded``);

@@ -218,18 +218,20 @@ def mg_postsmooth(sg: StripGrid, x, e, r, a, b, dinv, h1: float,
                   interpret: bool):
     """x + e, then ``sweeps`` damped-Jacobi sweeps on A x = r, all
     transposed: ``x`` is :func:`mg_presmooth_residual`'s, ``e`` the
-    prolongated correction."""
+    prolongated correction, or None where ``x`` already holds it."""
     _check_sweeps(sweeps)
+    fields = (x, r, a, b, dinv) if e is None else (x, e, r, a, b, dinv)
 
-    def kernel(x_ref, e_ref, r_ref, a_ref, b_ref, dinv_ref, out_ref):
+    def kernel(*refs):
+        x_ref, *e_ref, r_ref, a_ref, b_ref, dinv_ref, out_ref = refs
         A = _level_operator(sg, h1, h2, a_ref[:], b_ref[:])
         r = r_ref[:]
         wd = omega * dinv_ref[:]
-        x = x_ref[:] + e_ref[:]
+        x = x_ref[:] + e_ref[0][:] if e_ref else x_ref[:]
         for _ in range(sweeps):
             x = x + wd * (r - A(x))
         _store(sg, out_ref, x)
 
-    (out,) = _pallas_call(sg, kernel, "mg_postsmooth", 6, 1, r.dtype,
-                          interpret)(x, e, r, a, b, dinv)
+    (out,) = _pallas_call(sg, kernel, "mg_postsmooth", len(fields), 1,
+                          r.dtype, interpret)(*fields)
     return out

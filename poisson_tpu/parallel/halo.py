@@ -40,22 +40,45 @@ def _shift_up(u_slice, axis_name: str, size: int):
     )
 
 
-def exchange_halos(u, px_size: int, py_size: int):
-    """Refresh the width-1 halo ring of a local (m+2, n+2) block.
+def exchange_halos(u, px_size: int, py_size: int, depth: int = 1,
+                   owned=None):
+    """Refresh the ``depth`` halo lines on each side of a local block's
+    owned lines from the neighbours' first and last ``depth`` owned lines.
 
     Must be called inside ``shard_map`` over a mesh with axes (x, y).
-    One ``ppermute`` per direction, 4 total per call — called once per PCG
-    iteration on the search direction p, exactly like the reference
-    (``stage2:…cpp:404``).
+    ``owned`` is ((r0, r1), (c0, c1)), the block's owned rows and columns;
+    by default all but a ``depth``-line ring, so the default refreshes the
+    width-1 ring of a (m+2, n+2) block — called once per PCG iteration on
+    the search direction p, exactly like the reference
+    (``stage2:…cpp:404``). The sharded V-cycle's strip-kernel levels
+    (``parallel.mg_sharded``) refresh both lines of a 2-line ring, or
+    only its inner one; the CA solve (``parallel.pallas_ca_sharded``) the
+    2-line ring around an aligned canvas's owned band.
+
+    One ``ppermute`` per direction, 4 total per call. Rows first, then
+    columns over the whole height: the halo rows just received ride along
+    in the column slices, as in the reference's halo-inclusive messages,
+    so the corners carry the diagonal neighbours' values; past the mesh's
+    edges the halo reads zeros.
     """
-    # x-axis: rows. First/last *interior* rows travel to the neighbours'
-    # halo rows. Full width (n+2): corner values ride along, as in the
-    # reference's halo-inclusive messages (never read by the stencil).
-    top_halo = _shift_down(u[-2, :], X_AXIS, px_size)   # from x-neighbour above
-    bot_halo = _shift_up(u[1, :], X_AXIS, px_size)      # from x-neighbour below
-    u = u.at[0, :].set(top_halo).at[-1, :].set(bot_halo)
-    # y-axis: columns.
-    left_halo = _shift_down(u[:, -2], Y_AXIS, py_size)
-    right_halo = _shift_up(u[:, 1], Y_AXIS, py_size)
-    u = u.at[:, 0].set(left_halo).at[:, -1].set(right_halo)
-    return u
+    if owned is None:
+        owned = tuple((depth, n - depth) for n in u.shape)
+
+    def refresh(u, axis: int, mesh_axis: str, size: int):
+        lo, hi = owned[axis]
+
+        def send(shift, start):
+            lines = lax.slice_in_dim(u, start, start + depth, axis=axis)
+            if depth > 1:
+                return shift(lines, mesh_axis, size)
+            # One line travels as a vector: XLA fuses its reshape into the
+            # update, where an (m, 1) column would take a relayout copy.
+            return lax.expand_dims(
+                shift(lax.squeeze(lines, (axis,)), mesh_axis, size), (axis,))
+
+        down, up = send(_shift_down, hi - depth), send(_shift_up, lo)
+        u = lax.dynamic_update_slice_in_dim(u, down, lo - depth, axis)
+        return lax.dynamic_update_slice_in_dim(u, up, hi, axis)
+
+    u = refresh(u, 0, X_AXIS, px_size)
+    return refresh(u, 1, Y_AXIS, py_size)

@@ -34,9 +34,21 @@ sharded and levels 3–8 (1600×2400 down to 50×75) run whole on every
 chip; the deepest legal R there is 7 (the level-7 block, 50×75, does not
 halve).
 
-The strip kernels (``ops.pallas_mg``) stay on the one-device program:
-their two sweeps a pass need a 2-deep halo, so the sharded levels run
-XLA's level operators (``mg.pallas_levels`` reads 0 here).
+On a TPU in fp32, the sharded levels whose blocks are bandwidth-bound
+smooth on the strip kernels of ``ops.pallas_mg``, as the one-device
+program's large levels do (``mg.hierarchy.mesh_kernel_levels``: the
+one-device rule read on a chip's blocks; levels 0–1 at 12800×19200 on
+2×2, ``mg.pallas_levels`` reads that count). Their two stencil reads a
+pass reach two lines past a block, so these levels hold their
+kernel-side fields as blocks with a 2-line ring (``STRIP_RING``),
+transposed as the kernels read them, with D⁻¹ zero off the grid's
+interior rather than the shard's: a ring node holds what its owner
+computes there. r's ring is refreshed once before the pre-smoother and
+x + e's once before the post-smoother (``halo.exchange_halos``), where
+the XLA level operators refresh a 1-line ring before every stencil
+read. Treated as a grid of its own, the 2-ringed block's outermost ring
+is what the kernels' operator mask drops: x comes out right on the inner
+ring line and on the owned nodes, the residual on the owned nodes.
 """
 
 from __future__ import annotations
@@ -56,6 +68,7 @@ from poisson_tpu.mg.cycle import (
 )
 from poisson_tpu.mg.hierarchy import (
     DEFAULT_MG,
+    STRIP_RING,
     MGConfig,
     MGLevels,
     MeshPlan,
@@ -132,12 +145,27 @@ class ShardedGrid:
       zeroed.
     - ``gather``/``scatter``: at the replication level, the owned
       interiors tiled into the whole grid on every device, and this
-      device's block, ring included, cut from the whole correction."""
+      device's block, ring included, cut from the whole correction.
 
-    def __init__(self, problem: Problem, plan: MeshPlan):
+    The first ``kernel_levels`` levels run on the strip kernels, on
+    (m̂_l + 4, n̂_l + 4) blocks with a ``STRIP_RING``-line ring
+    (module docstring): the block's own ring is the inner ring line.
+
+    - ``strip_rhs``: r's 2-line ring refreshed (r comes padded to it);
+    - ``restrict`` takes the kernel's residual with its inner ring line
+      refreshed, and ``prolong`` a level's result likewise; ``restrict``
+      into a kernel level gives r padded to the ring;
+    - ``strip_correction``: x + e, its 2-line ring refreshed, as the
+      post-smoother's x (and no separate e);
+    - ``strip_result``: level 0 hands CG its block, zero off the owned
+      interior; a coarser level hands ``prolong`` the kernel's block."""
+
+    def __init__(self, problem: Problem, plan: MeshPlan,
+                 kernel_levels: int = 0):
         self.problem = problem
         self.plan = plan
         self.replicated_from = plan.replicated_from
+        self.kernel_levels = kernel_levels
 
     def _block(self, lvl: int):
         return self.plan.m_blk >> lvl, self.plan.n_blk >> lvl
@@ -151,17 +179,54 @@ class ShardedGrid:
     def exchange(self, u):
         return exchange_halos(u, self.plan.px, self.plan.py)
 
+    def _ring(self, u, depth: int):
+        """Refresh the inner ``depth`` lines of a kernel level's 2-line
+        ring."""
+        return exchange_halos(u, self.plan.px, self.plan.py, depth,
+                              tuple((STRIP_RING, n - STRIP_RING)
+                                    for n in u.shape))
+
     def restrict(self, lvl: int, res):
-        fine = jnp.pad(self.exchange(res), ((0, 1), (0, 1)))
-        coarse = restrict_full_weighting(fine)
-        return coarse * self.mask(lvl + 1, coarse.dtype)
+        mask = self.mask(lvl + 1, res.dtype)
+        if lvl + 1 < self.kernel_levels:
+            # The coarse level's r padded to its kernels' ring: the fine
+            # block padded to start two lines before the coarse ring's
+            # outer line (a TPU fuses a pad into the op that reads it).
+            # Only the coarse ring reads past the block, and the ring
+            # exchange overwrites it.
+            fine = jnp.pad(self._ring(res, 1), ((1, 2), (1, 2)))
+            mask = jnp.pad(mask, 1)
+        elif lvl < self.kernel_levels:
+            # The block and, past its far edges, the outer ring line,
+            # which only the coarse ring reads.
+            fine = self._ring(res, 1)[1:, 1:]
+        else:
+            fine = jnp.pad(self.exchange(res), ((0, 1), (0, 1)))
+        return restrict_full_weighting(fine) * mask
 
     def prolong(self, lvl: int, ec):
-        if lvl + 1 < self.replicated_from:
+        if lvl + 1 < self.kernel_levels:
+            ec = self._ring(ec, 1)[1:-1, 1:-1]
+        elif lvl + 1 < self.replicated_from:
             ec = self.exchange(ec)
         m, n = self._block(lvl)
         e = prolong_bilinear(ec)[:m + 2, :n + 2]
         return e * self.mask(lvl, e.dtype)
+
+    def strip_rhs(self, lvl: int, rl):
+        # rl arrives padded to the ring: level 0's from the
+        # preconditioner (_solve_mg_sharded), a coarser level's from
+        # ``restrict``.
+        return self._ring(rl, STRIP_RING)
+
+    def strip_correction(self, lvl: int, xt, e):
+        x = xt.T + jnp.pad(e, 1)
+        return self._ring(x, STRIP_RING).T, None
+
+    def strip_result(self, lvl: int, x):
+        if lvl:
+            return x
+        return x[1:-1, 1:-1] * self.mask(0, x.dtype)
 
     def gather(self, lvl: int, rl):
         whole = lax.all_gather(rl[1:-1, 1:-1], X_AXIS, axis=0, tiled=True)
@@ -180,38 +245,57 @@ class ShardedGrid:
 
 def _hierarchy_specs(hier: MGLevels, plan: MeshPlan) -> MGLevels:
     """``shard_map`` specs of a :func:`mesh_hierarchy`: blocks above the
-    replication level, whole grids from it down."""
+    replication level, whole grids from it down, None where it places
+    no block."""
     blocked, whole = P(X_AXIS, Y_AXIS), P()
     return MGLevels(
-        levels=tuple((blocked if lvl < plan.replicated_from else whole,) * 3
-                     for lvl in range(len(hier.levels))),
+        levels=tuple(tuple(None if f is None else
+                           blocked if lvl < plan.replicated_from else whole
+                           for f in fields)
+                     for lvl, fields in enumerate(hier.levels)),
         coarse_inv=None if hier.coarse_inv is None else whole,
-        scinv=blocked, strips=())
+        scinv=blocked,
+        strips=tuple((P(Y_AXIS, X_AXIS),) * 3 for _ in hier.strips))
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4),
+                   static_argnames=("interpret",))
 def _solve_mg_sharded(problem: Problem, mesh: Mesh, plan: MeshPlan,
                       config: MGConfig, scaled: bool, hier: MGLevels, rhs,
-                      aux) -> PCGResult:
+                      aux, interpret: bool = False) -> PCGResult:
     """The sharded MG solve: ``rhs`` and ``aux`` (D^{-1/2} scaled, D
     unscaled) are level-0 blocks as ``hier``'s; the result is
-    ``solvers.pcg._solve``'s, ``w`` on the whole (M+1, N+1) grid."""
+    ``solvers.pcg._solve``'s, ``w`` on the whole (M+1, N+1) grid. The
+    levels ``hier.strips`` holds run on the strip kernels, in the
+    interpreter when ``interpret``."""
 
     def shard_fn(hier, rhs, aux):
-        grid = ShardedGrid(problem, plan)
+        kernel_levels = len(hier.strips)
+        grid = ShardedGrid(problem, plan, kernel_levels)
         a, b, _ = hier.levels[0]
         ops = _sharded_ops(problem, a, b, aux, grid.mask(0, rhs.dtype),
                            plan.px, plan.py, scaled)
         h1, h2 = problem.h1, problem.h2
+
+        def cycle(r):
+            return v_cycle(hier, r, h1, h2, config, kernel_levels,
+                           interpret, grid)
+
+        def pad(u):
+            # Level 0 on the strip kernels takes r padded to its ring. A
+            # TPU fuses a pad into the op that reads it, not into the one
+            # that makes its operand: padding the factors of √d·r makes
+            # the padded product in one pass over the block.
+            return jnp.pad(u, 1) if kernel_levels else u
+
         if scaled:
             scinv = hier.scinv
 
             def precond(rt):
-                return scinv * v_cycle(hier, scinv * rt, h1, h2, config,
-                                       grid=grid)
+                return scinv * cycle(pad(scinv) * pad(rt))
         else:
             def precond(r):
-                return v_cycle(hier, r, h1, h2, config, grid=grid)
+                return cycle(pad(r))
 
         s = pcg_loop(
             ops._replace(apply_Dinv=precond), rhs,

@@ -70,7 +70,7 @@ from poisson_tpu.ops.pallas_cg import (
     scaled_stencil_fields,
     strip_height,
 )
-from poisson_tpu.parallel.halo import _shift_down, _shift_up
+from poisson_tpu.parallel.halo import exchange_halos
 from poisson_tpu.parallel.mesh import X_AXIS, Y_AXIS
 from poisson_tpu.solvers.pcg import PCGResult
 from jax import shard_map
@@ -187,20 +187,13 @@ def _ca_shard_canvases(problem: Problem, px: int, py: int,
 
 
 def _exchange_ring2(u, spec: CAShardSpec, px: int, py: int):
-    """Refresh the width-2 halo ring: 4 ``ppermute`` shifts of 2-wide
-    slices. Rows first, then columns over the FULL canvas height — the
-    just-received halo rows ride along in the column slices, so corner
-    blocks arrive correct via two hops (module doc). Mesh-edge shards
-    receive ppermute's zero fill = Dirichlet data."""
-    lo, hi = HALO, HALO + spec.m_blk
-    c0, c1 = _COL0, _COL0 + spec.n_blk
-    top = _shift_down(u[hi - _RING : hi, :], X_AXIS, px)
-    bot = _shift_up(u[lo : lo + _RING, :], X_AXIS, px)
-    u = u.at[lo - _RING : lo, :].set(top).at[hi : hi + _RING, :].set(bot)
-    left = _shift_down(u[:, c1 - _RING : c1], Y_AXIS, py)
-    right = _shift_up(u[:, c0 : c0 + _RING], Y_AXIS, py)
-    return u.at[:, c0 - _RING : c0].set(left) \
-            .at[:, c1 : c1 + _RING].set(right)
+    """Refresh the width-2 halo ring around the canvas's owned band
+    (``halo.exchange_halos``: rows, then columns over the FULL canvas
+    height, so corner blocks arrive correct via two hops, module doc).
+    Mesh-edge shards receive ppermute's zero fill = Dirichlet data."""
+    return exchange_halos(u, px, py, _RING,
+                          owned=((HALO, HALO + spec.m_blk),
+                                 (_COL0, _COL0 + spec.n_blk)))
 
 
 def _make_ca_shard_body(problem: Problem, spec: CAShardSpec, px: int,

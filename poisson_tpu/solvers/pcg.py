@@ -730,7 +730,8 @@ def pcg_solve(problem: Problem, dtype=None, scaled=None,
     and the program is byte-identical.
 
     An MG solve sets the gauge ``mg.pallas_levels``: how many levels of
-    its cycle run on the Pallas strip kernels (``ops.pallas_mg``).
+    its cycle run on the Pallas strip kernels (``ops.pallas_mg``), on
+    one device or, over a mesh, on the shards' blocks.
 
     ``mesh`` (a ``parallel.make_solver_mesh`` mesh; MG only) splits the
     MG solve over its devices: one ``shard_map`` program whose cycle is
@@ -764,7 +765,7 @@ def pcg_solve(problem: Problem, dtype=None, scaled=None,
                     problem, dtype_name, use_scaled, mesh, geometry,
                     preconditioner, mg_config, stream_every, verify_every,
                     history_every)
-                obs.gauge("mg.pallas_levels", 0)
+                obs.gauge("mg.pallas_levels", len(hier.strips))
                 obs.gauge("mg.replicated_from", plan.replicated_from)
             elif use_mg:
                 cfg, (a, b, rhs, aux, hier) = _mg_prepare(
@@ -782,8 +783,9 @@ def pcg_solve(problem: Problem, dtype=None, scaled=None,
                     _solve_mg_sharded,
                 )
 
-                result = _solve_mg_sharded(problem, mesh, plan, cfg,
-                                           use_scaled, hier, rhs, aux)
+                result = _solve_mg_sharded(
+                    problem, mesh, plan, cfg, use_scaled, hier, rhs, aux,
+                    interpret=jax.devices()[0].platform != "tpu")
             elif use_mg:
                 from poisson_tpu.mg.preconditioner import _solve_mg
 

@@ -312,14 +312,23 @@ def test_batched_mg_keeps_the_xla_cycle(one_chip):
     assert KERNEL not in text
 
 
-def _mg_mesh_operands(mesh, problem, plan):
+def _mg_mesh_operands(mesh, problem, plan, strips=0):
     """Shapes of the MG solve over ``mesh``: blocks of the sharded levels
     (a chip's (m̂_l + 2, n̂_l + 2) each), whole grids from the replication
-    level down, as ``mg.hierarchy.mesh_hierarchy`` places them."""
+    level down, and the first ``strips`` levels' transposed
+    (n̂_l + 4, m̂_l + 4) strip-kernel blocks, as
+    ``mg.hierarchy.mesh_hierarchy`` places them."""
     from poisson_tpu.mg.hierarchy import MGLevels
 
     blocked = NamedSharding(mesh, P(X_AXIS, Y_AXIS))
     whole = NamedSharding(mesh, P())
+
+    def strip(lvl):
+        shape = (plan.py * ((plan.n_blk >> lvl) + 4),
+                 plan.px * ((plan.m_blk >> lvl) + 4))
+        return jax.ShapeDtypeStruct(shape, jnp.float32,
+                                    sharding=NamedSharding(
+                                        mesh, P(Y_AXIS, X_AXIS)))
 
     def level(lvl):
         if lvl < plan.replicated_from:
@@ -334,28 +343,62 @@ def _mg_mesh_operands(mesh, problem, plan):
     coarse = (mc - 1) * (nc - 1)
     block = level(0)
     hier = MGLevels(
-        levels=tuple((level(lvl),) * 3 for lvl in range(len(plan.dims))),
+        # A strip level places none of its blocks but level 0's a and b.
+        levels=tuple(tuple(None if lvl < strips and (lvl or k == 2)
+                           else level(lvl) for k in range(3))
+                     for lvl in range(len(plan.dims))),
         coarse_inv=jax.ShapeDtypeStruct((coarse, coarse), jnp.float32,
                                         sharding=whole),
-        scinv=block)
+        scinv=block,
+        strips=tuple((strip(lvl),) * 3 for lvl in range(strips)))
     return hier, block, block
+
+
+# Level-0-block-sized passes of their own (copies, transposes, pads) in the
+# loop body of the mesh MG program before its levels 0-1 ran on the strip
+# kernels: a relayout copy of a 6402x9602 block and one of the restriction's
+# 6402x4800 half-width rows.
+MESH_BLOCK_PASSES_ON_XLA = 2
+
+
+def _loop_body(text):
+    """The text of the while loop's body computation in ``text``."""
+    body = re.search(r"body=%([\w.\-]+)", text).group(1)
+    start = text.index(f"\n%{body} ") + 1
+    end = re.compile(r"\n(?=%|ENTRY)").search(text, start + 1)
+    return text[start:end.start() if end else len(text)]
+
+
+def _block_passes(body, m_blk, n_blk):
+    """Copies, transposes and pads in ``body`` whose f32 result holds at
+    least half a (m_blk, n_blk) block."""
+    found = re.finditer(r"\n\s*(?:ROOT )?%\S+ = f32\[(\d+),(\d+)\]\S* "
+                        r"(copy|transpose|pad)\(", body)
+    return [m.group(0).strip() for m in found
+            if int(m.group(1)) * int(m.group(2)) * 2 >= m_blk * n_blk]
 
 
 def test_mg_mesh_program_at_the_cell_size(mesh):
     """The MG solve of the ``mg-mesh2x2-12800x19200`` cell, compiled for
     a described v5e 2x2: it fits a chip's 16 GB by XLA's own count (printed
-    per chip), runs no Mosaic kernel on its shards, and every halo permute
-    of the sharded levels 0-2 and the gather at level 3 carry their
-    ``mg_level`` tag."""
+    per chip); the rule puts the shards' levels 0 and 1 on the strip
+    kernels, each call carrying its level's ``mg_level`` tag; every halo
+    permute of the sharded levels 0-2 and the gather at level 3 carry
+    theirs; no gather op; and the loop body makes no more block-sized
+    copies than the XLA cycle's did."""
     from poisson_tpu.mg import DEFAULT_MG
+    from poisson_tpu.mg.hierarchy import mesh_kernel_levels
     from poisson_tpu.parallel import mg_sharded
 
     problem = Problem(M=12800, N=19200)
     plan = mg_sharded.plan_mesh(problem, 2, 2)
     assert plan.replicated_from == 3
+    strips = mesh_kernel_levels("tpu", "float32", plan)
+    assert strips == 2
     compiled = mg_sharded._solve_mg_sharded.lower(
         problem, mesh, plan, DEFAULT_MG, True,
-        *_mg_mesh_operands(mesh, problem, plan)).compile()
+        *_mg_mesh_operands(mesh, problem, plan, strips),
+        interpret=False).compile()
     stats = compiled.memory_analysis()
     per_chip = (stats.argument_size_in_bytes + stats.output_size_in_bytes
                 + stats.temp_size_in_bytes)
@@ -366,7 +409,13 @@ def test_mg_mesh_program_at_the_cell_size(mesh):
           f"{stats.temp_size_in_bytes / 1e9:.2f})")
     assert per_chip < 16e9
     text = compiled.as_text()
-    assert KERNEL not in text
+    calls = _custom_calls(text)
+    assert {re.search(r'"kernel"\s*:\s*"(\w+)"', c).group(1)
+            for c in calls} == {"mg_presmooth_residual", "mg_postsmooth"}
+    levels = [re.findall(r'mg_level="(\d+)"', c) for c in calls]
+    assert all(len(found) == 1 for found in levels)
+    assert {int(found[0]) for found in levels} == set(range(strips))
+    assert " gather(" not in text
     tags = {}
     for line in text.splitlines():
         op = re.search(r" (collective-permute-start|all-gather)\(", line)
@@ -379,3 +428,7 @@ def test_mg_mesh_program_at_the_cell_size(mesh):
     assert ({t for t in tags["collective-permute-start"] if t is not None}
             == {0, 1, 2})
     assert 3 in tags["all-gather"]
+    passes = _block_passes(_loop_body(text), plan.m_blk, plan.n_blk)
+    print(f"level-0-block-sized copies, transposes and pads in the loop "
+          f"body: {len(passes)}")
+    assert len(passes) <= MESH_BLOCK_PASSES_ON_XLA

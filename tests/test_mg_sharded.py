@@ -9,7 +9,12 @@
 - the rule that picks R, and its bounds;
 - each shard's host blocks equal slices of the one-device
   ``build_hierarchy64``, and the replicated tail its levels;
-- the gauges, the refusals, and the CLI's ``--preconditioner mg --mesh``.
+- the gauges, the refusals, and the CLI's ``--preconditioner mg --mesh``;
+- the shards' levels on the strip kernels (``kernels_on_blocks``: the
+  platform reads as a TPU, the size rule takes small blocks, the kernels
+  run interpreted in strips of 16 rows): the rule, the 2-line ring
+  exchange against slices of the whole grid, one cycle against the
+  sharded XLA cycle, and whole solves against the one-device program.
 """
 
 from __future__ import annotations
@@ -22,16 +27,33 @@ import subprocess
 import sys
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from poisson_tpu import obs
 from poisson_tpu.config import Problem
-from poisson_tpu.mg import DEFAULT_MG, build_hierarchy64, plan_levels
-from poisson_tpu.mg.hierarchy import mesh_hierarchy64, reset_hierarchy_cache
+from poisson_tpu.mg import (
+    DEFAULT_MG,
+    MGConfig,
+    build_hierarchy64,
+    hierarchy,
+    plan_levels,
+    v_cycle,
+)
+from poisson_tpu.mg.hierarchy import (
+    mesh_hierarchy64,
+    mesh_kernel_levels,
+    reset_hierarchy_cache,
+)
 from poisson_tpu.obs import metrics
+from poisson_tpu.ops import pallas_mg
 from poisson_tpu.parallel import make_solver_mesh
 from poisson_tpu.parallel import mg_sharded
+from poisson_tpu.parallel.halo import exchange_halos
+from poisson_tpu.parallel.mesh import X_AXIS, Y_AXIS
 from poisson_tpu.solvers.pcg import host_fields64, pcg_solve
 
 pytestmark = pytest.mark.mg
@@ -161,12 +183,18 @@ def test_shard_blocks_are_slices_of_the_whole_hierarchy(shape, grid, R):
     plan = mg_sharded.plan_mesh(problem, *grid, replicated_from=R)
     a64, b64, rhs64, aux64 = host_fields64(problem, True)
     whole = build_hierarchy64(problem, a64, b64, DEFAULT_MG)
-    built = mesh_hierarchy64(problem, plan, True, DEFAULT_MG)
+    built = mesh_hierarchy64(problem, plan, True, DEFAULT_MG, strips=R)
 
     def cut(u, lvl, px, py):
         m, n = plan.m_blk >> lvl, plan.n_blk >> lvl
         padded = np.pad(u, ((0, 1), (0, 1)))
         return padded[px * m:px * m + m + 2, py * n:py * n + n + 2]
+
+    def strip(u, lvl, px, py):
+        """The block with a 2-line ring (zeros past the grid), transposed."""
+        m, n = plan.m_blk >> lvl, plan.n_blk >> lvl
+        padded = np.pad(u, 2)
+        return padded[px * m + 1:px * m + m + 5, py * n + 1:py * n + n + 5].T
 
     def owned(lvl, px, py):
         m, n = plan.m_blk >> lvl, plan.n_blk >> lvl
@@ -184,6 +212,12 @@ def test_shard_blocks_are_slices_of_the_whole_hierarchy(shape, grid, R):
             assert np.array_equal(b, cut(wb, lvl, px, py))
             assert np.array_equal(dinv, cut(wdinv, lvl, px, py)
                                   * owned(lvl, px, py))
+            # The strip-kernel blocks: every ring node as its owner has it,
+            # dinv zero only off the grid's interior (as the whole grid's).
+            sa, sb, sdinv = fields["strips"][lvl]
+            assert np.array_equal(sa, strip(wa, lvl, px, py))
+            assert np.array_equal(sb, strip(wb, lvl, px, py))
+            assert np.array_equal(sdinv, strip(wdinv, lvl, px, py))
         mask = owned(0, px, py)
         assert np.array_equal(fields["rhs"], cut(rhs64, 0, px, py) * mask)
         assert np.array_equal(fields["aux"], cut(aux64, 0, px, py))
@@ -249,3 +283,165 @@ def test_one_device_gauge_is_untouched():
     obs.gauge("mg.replicated_from", 0)
     pcg_solve(Problem(M=40, N=60), dtype="float32", preconditioner="mg")
     assert metrics.snapshot(rank=0)["gauges"]["mg.replicated_from"] == 0
+
+
+def test_mesh_kernel_levels_rule():
+    """At 12800x19200 on 2x2 a chip holds 6400x9600 blocks: levels 0 and 1
+    (246 and 61 MB) go on the strip kernels, level 2 (15 MB) does not; on
+    the CPU, in bfloat16, or with more sweeps than the ring holds, none."""
+    plan = mg_sharded.plan_mesh(Problem(M=12800, N=19200), 2, 2)
+    assert mesh_kernel_levels("tpu", "float32", plan) == 2
+    assert mesh_kernel_levels("cpu", "float32", plan) == 0
+    assert mesh_kernel_levels("tpu", "bfloat16", plan) == 0
+    assert mesh_kernel_levels("tpu", "float32", plan,
+                              MGConfig(post_smooth=3)) == 0
+    # Never past the sharded levels.
+    small = mg_sharded.plan_mesh(Problem(M=6400, N=9600), 2, 2,
+                                 replicated_from=1)
+    assert mesh_kernel_levels("tpu", "float32", small) == 1
+
+
+def _blocks(mesh, u, lvl_blk, ring):
+    """The whole grid ``u`` cut into each shard's block with a ``ring``-line
+    ring (zeros past the grid), as one (px·(m̂+2·ring), py·(n̂+2·ring))
+    array over ``mesh``."""
+    (m, n), (px, py) = lvl_blk, mesh.devices.shape
+    padded = np.pad(u, ring)
+    parts = [[padded[i * m + 1:i * m + 1 + m + 2 * ring,
+                     j * n + 1:j * n + 1 + n + 2 * ring]
+              for j in range(py)] for i in range(px)]
+    return jax.device_put(np.block(parts),
+                          NamedSharding(mesh, P(X_AXIS, Y_AXIS)))
+
+
+def _exchanged(grid, ring, exchange):
+    """A random whole grid with a zero Dirichlet ring, cut into each
+    shard's block with a ``ring``-line ring that holds garbage, passed
+    through ``exchange`` in ``shard_map``; and the blocks it should give."""
+    M, N = 40, 60
+    mesh = _mesh(grid)
+    m, n = M // grid[0], N // grid[1]
+    rng = np.random.default_rng(7)
+    whole = np.pad(rng.standard_normal((M - 1, N - 1)), 1).astype(np.float32)
+    want = _blocks(mesh, whole, (m, n), ring)
+    owned = np.zeros((m + 2 * ring, n + 2 * ring), bool)
+    owned[ring:-ring, ring:-ring] = True
+    garbage = np.where(np.tile(owned, grid), np.asarray(want), 7.0)
+    got = shard_map(
+        exchange, mesh=mesh,
+        in_specs=P(X_AXIS, Y_AXIS), out_specs=P(X_AXIS, Y_AXIS),
+        check_vma=False)(jax.device_put(
+            garbage.astype(np.float32),
+            NamedSharding(mesh, P(X_AXIS, Y_AXIS))))
+    return np.asarray(got), np.asarray(want), (m, n)
+
+
+@pytest.mark.parametrize("grid", [(2, 2), (4, 1)])
+@pytest.mark.parametrize("depth,inset", [(2, 0), (1, 1)])
+def test_ring_exchange_equals_slices_of_the_whole_grid(grid, depth, inset):
+    """Each shard's owned lines cut from a whole grid whose Dirichlet ring
+    is zero, its 2-line ring filled with garbage: after the exchange, the
+    refreshed lines (both, or the inner one) are the whole grid's, zeros
+    past its edges, edge shards included."""
+    got, want, (m, n) = _exchanged(grid, 2, lambda u: exchange_halos(
+        u, *grid, depth, ((2, u.shape[0] - 2), (2, u.shape[1] - 2))))
+    keep = np.zeros((m + 4, n + 4), bool)
+    keep[inset:m + 4 - inset, inset:n + 4 - inset] = True
+    keep = np.tile(keep, grid)
+    np.testing.assert_array_equal(got[keep], want[keep])
+
+
+@pytest.mark.parametrize("grid", [(2, 2), (4, 1)])
+def test_default_exchange_refreshes_the_one_line_ring(grid):
+    """The CG body's call, ``exchange_halos(u, px, py)`` on (m̂+2, n̂+2)
+    blocks: the whole 1-line ring is the whole grid's, corners included,
+    zeros past its edges."""
+    got, want, _ = _exchanged(grid, 1, lambda u: exchange_halos(u, *grid))
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.fixture
+def kernels_on_blocks(monkeypatch):
+    """The hierarchies see a TPU and take level blocks of at least
+    ``min_bytes`` (the test sets it) on the strip kernels, in strips of 16
+    rows; the kernels run interpreted."""
+    monkeypatch.setattr(hierarchy, "_platform", lambda: "tpu")
+    monkeypatch.setattr(pallas_mg, "STRIP_VMEM",
+                        16 * pallas_mg.STRIP_BUFFERS * 128 * 4)
+    reset_hierarchy_cache()
+    yield lambda min_bytes: monkeypatch.setattr(
+        pallas_mg, "MIN_STRIP_LEVEL_BYTES", min_bytes)
+    reset_hierarchy_cache()
+
+
+# 80x120 on 2x2: 40x60 blocks (10,004 bytes), 20x30 at level 1 (2,604).
+KERNEL_CASES = [(5000, 1), (0, 2)]
+
+
+def _one_cycle(problem, mesh, plan, hier, r):
+    """One V-cycle of the sharded solve's preconditioner on ``r`` (level-0
+    blocks), with the kernel levels ``hier.strips`` holds."""
+    kernel_levels = len(hier.strips)
+
+    def fn(hier, r):
+        grid = mg_sharded.ShardedGrid(problem, plan, kernel_levels)
+        if kernel_levels:
+            r = jnp.pad(r, 1)   # level 0's r comes padded to the kernels
+        return v_cycle(hier, r, problem.h1, problem.h2, DEFAULT_MG,
+                       kernel_levels, True, grid)
+
+    return jax.jit(shard_map(
+        fn, mesh=mesh,
+        in_specs=(mg_sharded._hierarchy_specs(hier, plan),
+                  P(X_AXIS, Y_AXIS)),
+        out_specs=P(X_AXIS, Y_AXIS), check_vma=False))(hier, r)
+
+
+@pytest.mark.parametrize("min_bytes,strips", KERNEL_CASES)
+def test_sharded_cycle_on_the_kernels_matches_the_xla_cycle(
+        kernels_on_blocks, min_bytes, strips):
+    problem, mesh = Problem(M=80, N=120), _mesh((2, 2))
+    # The XLA cycle's hierarchy first: no block is large enough for the
+    # kernels, so it places every level's fields.
+    kernels_on_blocks(1 << 62)
+    plan, (xla, _, _) = mg_sharded.mesh_setup(
+        problem, "float32", True, mesh, DEFAULT_MG, replicated_from=2)
+    assert not xla.strips
+    reset_hierarchy_cache()
+    kernels_on_blocks(min_bytes)
+    plan, (hier, _, _) = mg_sharded.mesh_setup(
+        problem, "float32", True, mesh, DEFAULT_MG, replicated_from=2)
+    assert len(hier.strips) == strips
+    # Of the kernel levels' blocks only level 0's a and b are placed.
+    assert [[f is None for f in hier.levels[lvl]] for lvl in range(strips)] \
+        == [[False, False, True]] + [[True] * 3] * (strips - 1)
+    rng = np.random.default_rng(11)
+    whole = np.pad(rng.standard_normal((79, 119)), 1).astype(np.float32)
+    r = _blocks(mesh, whole, (40, 60), 1)
+    owned = np.tile(np.asarray(
+        [[1 <= i <= 40 and 1 <= j <= 60 for j in range(62)]
+         for i in range(42)]), (2, 2))
+    r = r * owned
+    got = np.asarray(_one_cycle(problem, mesh, plan, hier, r))
+    want = np.asarray(_one_cycle(problem, mesh, plan, xla, r))
+    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+    # Zero off the owned interior: the blocks' rings, and the grid's far
+    # edge lines that the last shards hold.
+    interior = owned & np.asarray(_blocks(mesh, np.pad(
+        np.ones((79, 119)), 1), (40, 60), 1)).astype(bool)
+    assert not got[~interior].any()
+
+
+@pytest.mark.parametrize("min_bytes,strips", KERNEL_CASES)
+def test_sharded_solve_on_the_kernels(kernels_on_blocks, min_bytes, strips):
+    """The whole solve, as ``pcg_solve`` runs it over a mesh: the gauge
+    reads the kernel levels, the count and field are the one-device
+    program's."""
+    kernels_on_blocks(min_bytes)
+    metrics.reset()
+    r = pcg_solve(Problem(M=80, N=120), dtype="float32",
+                  preconditioner="mg", mesh=_mesh((2, 2)))
+    assert metrics.snapshot(rank=0)["gauges"]["mg.pallas_levels"] == strips
+    k_solo, w_solo = _solo(80, 120, 1.0)
+    assert abs(int(r.iterations) - k_solo) <= 1
+    assert _gap(r.w, w_solo) <= SOLO_GAP
