@@ -522,9 +522,11 @@ def roofline_summary(problem, backend: Optional[str], dtype_bytes: int,
             passes * grid_points(problem.M, problem.N) * dtype_bytes
             if passes else None
         ),
-        "achieved_gbps": round(achieved, 2) if achieved else None,
+        # Four significant figures, not fixed decimals: a tiny or slow
+        # solve's few MB/s would otherwise round to a 0.0 GB/s reading.
+        "achieved_gbps": float(f"{achieved:.4g}") if achieved else None,
         "peak_gbps": peak,
-        "fraction": round(fraction, 4) if fraction else None,
+        "fraction": float(f"{fraction:.4g}") if fraction else None,
     }
     for key in ("achieved_gbps", "peak_gbps", "fraction"):
         if report[key] is not None:

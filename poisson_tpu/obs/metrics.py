@@ -60,6 +60,12 @@ Naming convention (dotted, low cardinality):
   (``ops.pallas_cg._fused_solve_batched``: TPU, fp32, scaled, no mesh/
   geometries/MG/block/verify) and the members they carried: how often
   the fused path engages beside the XLA families;
+- ``batched.mg.dispatches`` / ``batched.mg.members`` — batched
+  dispatches that ran the vmapped MG-preconditioned loop
+  (``mg.preconditioner._solve_batched_mg``, ``preconditioner="mg"``) and
+  the members they carried (padding excluded, as ``mg.solves`` counts
+  them); their level hierarchy is looked up under the span
+  ``solve_batched.prepare``;
 - ``geom.cache.hits`` / ``geom.cache.misses`` — the geometry canvas
   cache (``poisson_tpu.geometry.canvas.geometry_setup``), keyed by
   (fingerprint, grid box, f_val, dtype, scaled) the way the jit cache

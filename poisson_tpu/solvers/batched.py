@@ -467,11 +467,14 @@ def solve_batched(problems=None, *, rhs_stack=None, rhs_gates=None,
     bucket-cache key carries the cycle config); mixed per-member
     ``geometries`` do not co-batch with MG yet — each member would
     need its own level hierarchy — and are rejected loudly (the solve
-    service dispatches geometry+MG requests solo).
+    service dispatches geometry+MG requests solo). The counters
+    ``batched.mg.dispatches`` and ``batched.mg.members`` count MG
+    dispatches and their members.
 
     Each call runs under the span ``solve_batched`` with the children
     ``solve_batched.prepare`` (argument checks, gate upload, set-up
-    lookup, padding), ``solve_batched.launch`` (the dispatch) and
+    lookup and, for MG, the level hierarchy lookup, padding),
+    ``solve_batched.launch`` (the dispatch) and
     ``solve_batched.finish`` (slicing the padding off): see
     :func:`poisson_tpu.obs.span`.
     """
@@ -482,9 +485,10 @@ def solve_batched(problems=None, *, rhs_stack=None, rhs_gates=None,
                 dtype=dtype, scaled=scaled, mesh=mesh, buckets=buckets,
                 bucket=bucket, member_ids=member_ids, geometries=geometries,
                 verify_every=verify_every, verify_tol=verify_tol,
-                preconditioner=preconditioner, mode=mode)
+                preconditioner=preconditioner, mg_config=mg_config,
+                mode=mode)
         with obs.span("solve_batched.launch"):
-            result = _launch_batch(prep, mesh, mg_config)
+            result = _launch_batch(prep, mesh)
         with obs.span("solve_batched.finish"):
             return _finish_batch(prep, result)
 
@@ -497,7 +501,7 @@ class _Prepared(NamedTuple):
     dtype_name: str
     use_scaled: bool
     use_block: bool
-    use_mg: bool
+    mg: Optional[tuple]       # (cycle config, device hierarchy) for MG
     geo: Optional[list]       # parsed per-member geometries, or None
     setups: Optional[list]    # per-member (a, b, rhs, aux) on that path
     a: Any
@@ -554,10 +558,12 @@ def _fused_operands(problem: Problem, rhs_stack, gates):
 
 def _prepare_batch(problems, *, rhs_stack, rhs_gates, dtype, scaled, mesh,
                    buckets, bucket, member_ids, geometries, verify_every,
-                   verify_tol, preconditioner, mode) -> _Prepared:
+                   verify_tol, preconditioner, mg_config,
+                   mode) -> _Prepared:
     """``solve_batched``'s host preparation: every argument check, the
-    gate upload, the ``host_setup`` lookup and the padded ``rhs_stack``
-    (see :func:`solve_batched` for the arguments)."""
+    gate upload, the ``host_setup`` lookup, the MG level hierarchy lookup
+    and the padded ``rhs_stack`` (see :func:`solve_batched` for the
+    arguments)."""
     from poisson_tpu.krylov import KRYLOV_BLOCK, KRYLOV_MODES
 
     if mode not in KRYLOV_MODES:
@@ -634,8 +640,13 @@ def _prepare_batch(problems, *, rhs_stack, rhs_gates, dtype, scaled, mesh,
     # it away: batches differing only in RHS magnitude share one compiled
     # executable per bucket.
     jit_problem = problem.with_(f_val=1.0)
+    mg_cfg = None
     if preconditioner not in (None, "jacobi"):
-        from poisson_tpu.mg import resolve_preconditioner
+        from poisson_tpu.mg import (
+            DEFAULT_MG,
+            resolve_preconditioner,
+            validate_mg_problem,
+        )
 
         resolve_preconditioner(preconditioner)   # raises on unknown
         if geometries is not None:
@@ -648,12 +659,11 @@ def _prepare_batch(problems, *, rhs_stack, rhs_gates, dtype, scaled, mesh,
                     "preconditioner='mg')"
                 )
             geometries = None   # all-None entries: the default domain
-        use_mg = True
-    else:
-        use_mg = False
+        mg_cfg = mg_config or DEFAULT_MG
+        validate_mg_problem(problem, mg_cfg)
     fused = uses_fused_kernels(
         _platform(), dtype_name, use_scaled, mesh=mesh,
-        geometries=geometries, mg=use_mg, block=use_block,
+        geometries=geometries, mg=mg_cfg is not None, block=use_block,
         verify_every=int(verify_every))
     geo = setups = None
     a = b = aux = None
@@ -810,16 +820,22 @@ def _prepare_batch(problems, *, rhs_stack, rhs_gates, dtype, scaled, mesh,
     # flag-off key keeps its historical shape and counter arithmetic.
     verify_key = (("verify", verify_every, v_tol)
                   if verify_every > 0 else None)
+    mg = None
+    if mg_cfg is not None:
+        from poisson_tpu.mg.hierarchy import device_hierarchy
+
+        mg = (mg_cfg, device_hierarchy(problem, dtype_name, use_scaled,
+                                       config=mg_cfg))
     return _Prepared(problem, jit_problem, dtype_name, use_scaled, use_block,
-                     use_mg, geo, setups, a, b, aux, rhs_stack, batch, size,
+                     mg, geo, setups, a, b, aux, rhs_stack, batch, size,
                      origin, verify_every, v_tol, verify_key, canvases)
 
 
-def _launch_batch(prep: _Prepared, mesh, mg_config) -> PCGResult:
+def _launch_batch(prep: _Prepared, mesh) -> PCGResult:
     """``solve_batched``'s dispatch: the bucket-cache bookkeeping and the
-    jitted call of whichever executable family runs (the mesh and MG
-    families also look up their shard blocks or level hierarchy here)."""
-    (problem, jit_problem, dtype_name, use_scaled, use_block, use_mg, geo,
+    jitted call of whichever executable family runs (the mesh family also
+    looks up its shard blocks here)."""
+    (problem, jit_problem, dtype_name, use_scaled, use_block, mg, geo,
      setups, a, b, aux, rhs_stack, batch, size, _, verify_every, v_tol,
      verify_key, canvases) = prep
     if canvases is not None:
@@ -894,23 +910,19 @@ def _launch_batch(prep: _Prepared, mesh, mg_config) -> PCGResult:
                                     verify_every, v_tol,
                                     stack_pad(0), stack_pad(1),
                                     rhs_stack, stack_pad(3))
-    elif use_mg:
-        from poisson_tpu import obs as _obs
-        from poisson_tpu.mg import DEFAULT_MG, validate_mg_problem
-        from poisson_tpu.mg.hierarchy import device_hierarchy
+    elif mg is not None:
         from poisson_tpu.mg.preconditioner import _solve_batched_mg
 
-        cfg = mg_config or DEFAULT_MG
-        validate_mg_problem(problem, cfg)
+        cfg, hier = mg
         # MG buckets are their own executable family: the cycle config
         # is operand/static identity exactly like the verify stride.
         key = (size, jit_problem, dtype_name, use_scaled, ("mg", cfg))
         if verify_key:
             key = key + (verify_key,)
         _count_bucket(key, batch, size)
-        hier = device_hierarchy(problem, dtype_name, use_scaled,
-                                config=cfg)
-        _obs.inc("mg.solves", batch)
+        obs.inc("batched.mg.dispatches")
+        obs.inc("batched.mg.members", batch)
+        obs.inc("mg.solves", batch)
         result = _solve_batched_mg(jit_problem, use_scaled, cfg,
                                    verify_every, v_tol,
                                    a, b, rhs_stack, aux, hier)

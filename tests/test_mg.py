@@ -396,6 +396,50 @@ def test_batched_mg_members_match_solo():
     assert metrics.get("batched.bucket_cache.hits") == 1
 
 
+def test_batched_mg_counts_and_prepares_its_hierarchy(monkeypatch):
+    """``batched.mg.{dispatches,members}`` count each batched MG dispatch
+    and its members (padding left out, as ``mg.solves`` counts them); the
+    level hierarchy is looked up under ``solve_batched.prepare``; a
+    Jacobi batch neither counts there nor looks one up."""
+    import contextlib
+
+    from poisson_tpu import obs
+    from poisson_tpu.mg import hierarchy
+    from poisson_tpu.obs import metrics
+    from poisson_tpu.solvers.batched import solve_batched
+
+    open_spans, lookups = [], []
+    real_span, real_lookup = obs.span, hierarchy.device_hierarchy
+
+    @contextlib.contextmanager
+    def span(name, **args):
+        open_spans.append(name)
+        try:
+            with real_span(name, **args):
+                yield
+        finally:
+            open_spans.pop()
+
+    def lookup(*args, **kwargs):
+        lookups.append(tuple(open_spans))
+        return real_lookup(*args, **kwargs)
+
+    monkeypatch.setattr(obs, "span", span)
+    monkeypatch.setattr(hierarchy, "device_hierarchy", lookup)
+    metrics.reset()
+    p = Problem(M=64, N=64)
+    solve_batched(p, rhs_gates=[1.0, 1.3, 0.7], dtype=jnp.float32,
+                  preconditioner="mg")
+    solve_batched(p, rhs_gates=[0.9, 1.1], dtype=jnp.float32,
+                  preconditioner="mg")
+    solve_batched(p, rhs_gates=[1.0, 1.3, 0.7], dtype=jnp.float32)
+    assert metrics.get("batched.mg.dispatches") == 2
+    assert metrics.get("batched.mg.members") == 5
+    assert metrics.get("mg.solves") == 5
+    assert metrics.get("batched.padding_members") == 2
+    assert lookups == [("solve_batched", "solve_batched.prepare")] * 2
+
+
 def test_lanes_mg_splice_step_retire():
     from poisson_tpu.solvers.lanes import LaneBatch
 

@@ -9,7 +9,11 @@ traffic pool.
   configuration's limit;
 - the MG reference agrees with the Jacobi reference (the same discrete
   problem) within the error the stopping rule leaves (``_stop_error``);
-- the MG reference computed in bfloat16 fails the configuration's limits.
+- the MG reference computed in bfloat16 fails the configuration's limits;
+- each member of ``solve_batched(preconditioner="mg")`` agrees with the
+  fp32 reference of its gate within the limits of the batched cell's
+  configuration (``ellipse-1600x2400-mg``), and the bfloat16 reference in
+  the program's place does not.
 """
 
 from __future__ import annotations
@@ -32,6 +36,10 @@ CONFIG = json.loads(
 TRAFFIC = json.loads(
     (ROOT / "perf" / "traffic" / "closed-solo-mg.json").read_text())
 LIMITS = CONFIG["limits"]
+BATCH_CONFIG = json.loads(
+    (ROOT / "perf" / "configs" / "ellipse-1600x2400-mg.json").read_text())
+BATCH_TRAFFIC = json.loads(
+    (ROOT / "perf" / "traffic" / "closed-batch32-mg.json").read_text())
 GRIDS = [(200, 300), (400, 600)]
 SEEDS = [2**31 + 11, 5 * 2**32 + 3]
 
@@ -92,3 +100,41 @@ def test_mg_solve_against_the_reference(M, N, seed):
                                max_iter).solve(gate)
     assert (abs(k_bf - k_ref) > LIMITS["iters_gap"]
             or compare.field_gap(w_bf, w_ref) > LIMITS["field_gap"])
+
+
+@pytest.mark.parametrize("answer", ["solve_batched", "bfloat16"])
+def test_batched_mg_against_the_reference(answer):
+    """Three gates of the batched cell's seeded pool (both of its ends and
+    one draw), padded to a bucket of four: each member's iteration count
+    and field against the fp32 reference of its gate, within the batched
+    configuration's limits for the program and outside them for the
+    reference in bfloat16."""
+    from poisson_tpu.config import Problem
+    from poisson_tpu.obs import metrics
+    from poisson_tpu.solvers.batched import solve_batched
+    from poisson_tpu.solvers.pcg import FLAG_CONVERGED
+
+    M, N = GRIDS[0]
+    limits = BATCH_CONFIG["limits"]
+    max_iter = BATCH_CONFIG["reference_max_iter"]
+    gates = generate.pool(dict(BATCH_TRAFFIC["gates"], n=3), SEEDS[0])
+    refs = [_reference("ellipse_mgpcg", M, N, "float32", max_iter).solve(g)
+            for g in gates]
+    if answer == "solve_batched":
+        result = solve_batched(
+            Problem(**dict(BATCH_CONFIG["problem"], M=M, N=N)),
+            rhs_gates=gates, dtype="float32", preconditioner="mg")
+        assert metrics.snapshot()["gauges"]["batched.last_bucket"] == 4
+        assert np.all(np.asarray(result.flag) == FLAG_CONVERGED)
+        fields = np.asarray(result.w, np.float64)
+        iters = np.asarray(result.iterations).tolist()
+    else:
+        answers = [_reference("ellipse_mgpcg", M, N, "bfloat16",
+                              max_iter).solve(g) for g in gates]
+        fields = [w for w, _, _ in answers]
+        iters = [k for _, k, _ in answers]
+    gaps = [(abs(k - k_ref), compare.field_gap(w, w_ref))
+            for (w_ref, k_ref, _), w, k in zip(refs, fields, iters)]
+    within = all(i <= limits["iters_gap"] and f <= limits["field_gap"]
+                 for i, f in gaps)
+    assert within == (answer == "solve_batched"), gaps

@@ -1,9 +1,10 @@
 """The V-cycle's level names in the compiled program.
 
 ``mg.cycle.v_cycle`` tags every op of level l with the frontend attribute
-``mg_level="<l>"``; the compiled instructions keep it, and a TPU op event
-carries it in its name, which is how the chip benchmark splits device time
-by level (``perf/mg_trace.py``). The Jacobi programs carry no tag.
+``mg_level="<l>"``, under ``vmap`` too (``solve_batched``'s MG program); the
+compiled instructions keep it, and a TPU op event carries it in its name,
+which is how the chip benchmark splits device time by level
+(``perf/mg_trace.py``). The Jacobi programs carry no tag.
 """
 
 from __future__ import annotations
@@ -30,14 +31,28 @@ def _compiled_text(preconditioner: str) -> str:
         args = mg_solve_setup(PROBLEM, "float32", True)
         lowered = _solve_mg.lower(PROBLEM, True, DEFAULT_MG, 0, 0, 0.0,
                                   *args)
+    elif preconditioner == "mg-batched":
+        import jax.numpy as jnp
+
+        from poisson_tpu.mg.preconditioner import (
+            _solve_batched_mg,
+            mg_solve_setup,
+        )
+
+        a, b, rhs, aux, hier = mg_solve_setup(PROBLEM, "float32", True)
+        stack = jnp.stack([rhs * g for g in (0.95, 1.05)])
+        lowered = _solve_batched_mg.lower(PROBLEM, True, DEFAULT_MG, 0, 0.0,
+                                          a, b, stack, aux, hier)
     else:
         args = solve_setup(PROBLEM, "float32", True)
         lowered = _solve.lower(PROBLEM, True, 0, 0, 0.0, False, 0, *args)
     return lowered.compile().as_text()
 
 
-@pytest.mark.parametrize("preconditioner", ["mg", "jacobi"])
+@pytest.mark.parametrize("preconditioner", ["mg", "jacobi", "mg-batched"])
 def test_every_level_is_named_in_the_compiled_program(preconditioner):
+    """Every level's ops carry their tag, in the solo MG program and in
+    the batched one, whose cycle runs under ``vmap``."""
     levels = {int(m) for m in _TAG.findall(_compiled_text(preconditioner))}
     if preconditioner == "jacobi":
         assert levels == set()
